@@ -1,5 +1,6 @@
 """Shared benchmark setup (graph + engine construction, timing) plus the
-observability plumbing every BENCH producer goes through: provenance
+plumbing every BENCH producer goes through: the compile-cache placement its
+``main()`` calls first (``enable_compile_cache``), provenance
 stamping for ``BENCH_*.json`` artifacts, the ``--ledger`` append path into
 ``RUNS/ledger.jsonl`` (repro.obs.ledger), and the attribution helper that
 sets the storage peak to the EMULATED NVMe bandwidth when a bench emulates
@@ -24,6 +25,7 @@ from repro.graph import (
 )
 from repro.graph.csr import add_self_loops
 from repro.graph.synthetic import random_features, random_labels
+from repro.launch.compile_cache import enable_compile_cache  # noqa: F401
 from repro.models.gnn.layers import get_gnn
 
 

@@ -239,9 +239,10 @@ def main() -> int:
                     metavar="PORT",
                     help="serve live Prometheus metrics on this port for "
                          "the duration of the soak (0 = ephemeral)")
-    from benchmarks.common import add_obs_args
+    from benchmarks.common import add_obs_args, enable_compile_cache
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.nodes, args.parts, args.hidden = 3000, 6, 32
         args.layers, args.epochs = 2, 2

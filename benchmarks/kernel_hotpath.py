@@ -12,8 +12,9 @@ This artifact is the evidence behind the dispatch layer's ``"auto"`` rule:
 on a CPU backend Pallas runs in interpret mode (a compiled per-grid-step
 emulation) and loses to vectorized numpy on every shape, so ``"auto"``
 resolves to the reference path there — ``fallback`` in the JSON records
-that decision per shape. On a real TPU backend the same harness measures
-the win that makes ``"auto"`` pick Pallas.
+that decision per shape. On a TPU the v5e compiler refuses these kernels
+(see ``repro/kernels/dispatch.py``), so ``"auto"`` resolves to the
+reference path there too.
 
 Run:  PYTHONPATH=src python benchmarks/kernel_hotpath.py [--smoke] [--json]
 CSV:  kernel,us_per_call,detail
@@ -124,9 +125,10 @@ def main() -> int:
     ap.add_argument("--json", nargs="?", const="BENCH_kernel_hotpath.json",
                     default=None, metavar="PATH",
                     help="also write per-shape timings as JSON (CI artifact)")
-    from benchmarks.common import add_obs_args
+    from benchmarks.common import add_obs_args, enable_compile_cache
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # engine-shaped: (n_rows of staged stack, edges, dst rows, feature dim).
     # Sized for interpret mode on CPU (per-grid-step emulation scales with

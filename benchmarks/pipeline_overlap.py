@@ -83,9 +83,10 @@ def main() -> int:
                     help="write a Chrome/Perfetto trace_event timeline of "
                          "the pipelined run's timed epochs (CI artifact; "
                          "open in ui.perfetto.dev)")
-    from benchmarks.common import add_obs_args
+    from benchmarks.common import add_obs_args, enable_compile_cache
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         # cache well below the activation working set so offloading (and

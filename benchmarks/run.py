@@ -21,9 +21,10 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("only", nargs="?", default=None,
                     help="substring filter on the table/figure tags")
-    from benchmarks.common import add_obs_args
+    from benchmarks.common import add_obs_args, enable_compile_cache
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks import (
         fig9_memory, fig12_models, fig13_bandwidth, io_volume, roofline,

@@ -127,9 +127,10 @@ def main() -> int:
                     metavar="PATH",
                     help="write a Chrome/Perfetto trace_event timeline of "
                          "the inference build + serving sweep")
-    from benchmarks.common import add_obs_args
+    from benchmarks.common import add_obs_args, enable_compile_cache
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.nodes, args.parts, args.layers = 2000, 6, 2
         args.hidden, args.queries, args.warmup = 32, 60, 10

@@ -42,6 +42,9 @@ class ArchSpec:
     # dry-run compiles two reduced depths and extrapolates per-layer terms.
     # (L1, L2, L_full) or None for unscanned archs.
     layer_calib: Optional[Tuple[int, int, int]] = None
+    # family "gnn": the GNNArch (model id, widths, dataset) the SSO
+    # launchers build their workload from
+    gnn: Any = None
 
     def runnable_shapes(self):
         return [s for s, c in self.cells.items() if c.skip is None]
@@ -67,6 +70,19 @@ GNN_SHAPES: Dict[str, Dict[str, Any]] = {
         kind="batched", n_nodes=30, n_edges=64, batch=128, d_feat=32,
         classes=16,
     ),
+}
+
+# Full-graph datasets a GNN arch names (``GNNArch.dataset``): the shape the
+# SSO launchers generate their graph at. Edges are directed CSR entries.
+GNN_DATASETS: Dict[str, Dict[str, int]] = {
+    "cora": dict(n_nodes=2708, n_edges=10556, d_feat=1433, classes=7),
+    "reddit": dict(n_nodes=232965, n_edges=114615892, d_feat=602, classes=41),
+    "ogb_products": dict(
+        n_nodes=2449029, n_edges=61859140, d_feat=100, classes=47,
+    ),
+    # IGB-medium (paper Table 9)
+    "igbm": dict(n_nodes=10_000_000, n_edges=120_100_000, d_feat=1024,
+                 classes=19),
 }
 
 LM_SHAPES: Dict[str, Dict[str, Any]] = {

@@ -109,12 +109,13 @@ class GNNArch:
     model: str             # key in GNN_REGISTRY
     n_layers: int
     d_hidden: int
+    dataset: str           # key in GNN_DATASETS
     loss_kind: str = "ce"  # graphcast: "mse"
     d_out_override: Optional[int] = None   # graphcast: 227 vars
     note: str = ""
 
 
-def _gnn_dims(a: GNNArch, d_feat: int, classes: int):
+def gnn_dims(a: GNNArch, d_feat: int, classes: int):
     d_out = a.d_out_override or classes
     return [d_feat] + [a.d_hidden] * (a.n_layers - 1) + [d_out]
 
@@ -136,7 +137,7 @@ def make_gnn_arch(a: GNNArch, describe: str) -> ArchSpec:
         alone — §Perf iteration-0 diagnostic) | "halo" (partitioned-halo,
         the beyond-paper optimization)."""
         s = GNN_SHAPES[shape]
-        dims = _gnn_dims(a, s["d_feat"], s.get("classes", 16))
+        dims = gnn_dims(a, s["d_feat"], s.get("classes", 16))
         d_out = dims[-1]
         p_abs = _abstract_gnn_params(a, dims)
         o_abs = jax.eval_shape(adamw_init, p_abs)
@@ -241,7 +242,7 @@ def make_gnn_arch(a: GNNArch, describe: str) -> ArchSpec:
             finite=ok and bool(np.isfinite(float(loss))),
         )
 
-    return ArchSpec(a.name, "gnn", describe, cells, build, smoke)
+    return ArchSpec(a.name, "gnn", describe, cells, build, smoke, gnn=a)
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,7 @@
 from repro.configs.builders import GNNArch, make_gnn_arch
 
 CONFIG = GNNArch(
-    name="gcn-cora", model="gcn", n_layers=2, d_hidden=16,
+    name="gcn-cora", model="gcn", n_layers=2, d_hidden=16, dataset="cora",
     note="symmetric normalization",
 )
 

@@ -11,6 +11,7 @@ from repro.configs.builders import GNNArch, make_gnn_arch
 
 CONFIG = GNNArch(
     name="graphcast", model="graphcast", n_layers=16, d_hidden=512,
+    dataset="ogb_products",
     loss_kind="mse", d_out_override=227,
     note="encoder-processor-decoder; sum aggregation; 227 output vars",
 )

@@ -4,6 +4,7 @@ from repro.configs.builders import GNNArch, make_gnn_arch
 
 CONFIG = GNNArch(
     name="pna", model="pna", n_layers=4, d_hidden=75,
+    dataset="ogb_products",
     note="4 aggregators x 3 degree scalers",
 )
 

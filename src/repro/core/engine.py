@@ -74,6 +74,36 @@ def _snap_name(layer: int, p: int) -> str:
     return f"snap{layer}_{p}"
 
 
+@partial(jax.jit, static_argnames=("apply", "activate"))
+def layer_vjp(params_l, ga, topo, d_out, *, apply, activate):
+    """One backward layer program: differentiate the layer at ``GA``. Jitted
+    once per process and keyed on the layer function, so every engine
+    shares its executables."""
+    def g(p, a):
+        return apply(p, a, topo, activate=activate)
+
+    _, vjp = jax.vjp(g, params_l, ga)
+    return vjp(d_out)
+
+
+@jax.jit
+def loss_and_grad(logits, labels, n_total):
+    """Masked mean cross-entropy of one unit's logits (``labels < 0`` are
+    padding rows) and its gradient; partition sums compose exactly."""
+    mask = (labels >= 0).astype(logits.dtype)
+
+    def loss_fn(lg):
+        logp = jax.nn.log_softmax(lg, axis=-1)
+        ll = jnp.take_along_axis(
+            logp,
+            jnp.maximum(labels, 0)[:, None].astype(jnp.int32),
+            axis=-1,
+        )[:, 0]
+        return -(ll * mask).sum() / n_total
+
+    return jax.value_and_grad(loss_fn)(logits)
+
+
 # Reference host scatter-add (contiguous slice-add fast path, sorted
 # np.add.reduceat segments, np.add.at residual) — kept under its historical
 # name; the engine itself goes through ``self.kernels.scatter_add_rows`` so
@@ -152,46 +182,34 @@ class SSOEngine:
             pipeline, dtype=self.dtype, kernels=self.kernels,
         )
         self._prefetch_pins = self.fwd_runner.prefetch_pins
-        self._jit_bwd = {}
-        self._jit_loss = None
 
     # ------------------------------------------------------------------ jit
     def _bwd(self, activate: bool):
-        if activate not in self._jit_bwd:
-            apply = self.spec.apply_layer
+        return partial(layer_vjp, apply=self.spec.apply_layer,
+                       activate=activate)
 
-            @jax.jit
-            def f(params_l, ga, topo, d_out):
-                def g(p, a):
-                    return apply(p, a, topo, activate=activate)
+    def compile_programs(self, params: List) -> int:
+        """Compile the forward and backward layer programs of every padded
+        shape bucket of the plan, a thread per core, before the first epoch;
+        returns the number of programs. A cold epoch compiles them one after
+        another, and at published widths each takes tens of seconds on v5e.
+        Covers the reference dispatch path; the Pallas path compiles at its
+        first call."""
+        from repro.runtime.forward import compile_parallel, layer_apply
 
-                _, vjp = jax.vjp(g, params_l, ga)
-                dp, dga = vjp(d_out)
-                return dp, dga
-
-            self._jit_bwd[activate] = f
-        return self._jit_bwd[activate]
-
-    def _loss_grad(self):
-        if self._jit_loss is None:
-
-            @jax.jit
-            def f(logits, labels, n_total):
-                mask = (labels >= 0).astype(logits.dtype)
-
-                def loss_fn(lg):
-                    logp = jax.nn.log_softmax(lg, axis=-1)
-                    ll = jnp.take_along_axis(
-                        logp,
-                        jnp.maximum(labels, 0)[:, None].astype(jnp.int32),
-                        axis=-1,
-                    )[:, 0]
-                    return -(ll * mask).sum() / n_total
-
-                return jax.value_and_grad(loss_fn)(logits)
-
-            self._jit_loss = f
-        return self._jit_loss
+        if self.kernels.use_pallas:
+            return 0
+        jobs = []
+        for l in range(self.n_layers):
+            kw = dict(apply=self.spec.apply_layer,
+                      activate=l < self.n_layers - 1)
+            for u in self.fwd_runner.shape_buckets():
+                ga = jax.ShapeDtypeStruct((u.r_pad, self.dims[l]), self.dtype)
+                d_out = jax.ShapeDtypeStruct(
+                    (u.d_pad, self.dims[l + 1]), self.dtype)
+                jobs.append((layer_apply, (params[l], ga, u.topo), kw))
+                jobs.append((layer_vjp, (params[l], ga, u.topo, d_out), kw))
+        return compile_parallel(jobs)
 
     # -------------------------------------------------------------- storage
     def initialize(self, x_reordered: np.ndarray) -> None:
@@ -386,7 +404,6 @@ class SSOEngine:
         n = plan.n_nodes
         L = self.n_layers
         rt = self._rt
-        loss_fn = self._loss_grad()
         # grad files per layer (lazily zero-filled via materialization set)
         for l in range(L + 1):
             name = _grad_name(l)
@@ -442,7 +459,7 @@ class SSOEngine:
                 # count labels too, matching the transfer-stage path
                 self.counters.bump("h2d_bytes", lg.nbytes + lb.nbytes)
                 lg_dev, lb_dev = jnp.asarray(lg), jnp.asarray(lb)
-            loss_p, dlog = loss_fn(lg_dev, lb_dev, jnp.float32(n))
+            loss_p, dlog = loss_and_grad(lg_dev, lb_dev, jnp.float32(n))
             dlog_dst = dlog[: u.n_dst]
             # start the D2H copy; it lands while the loss scalar transfers
             dlog_dst.copy_to_host_async()

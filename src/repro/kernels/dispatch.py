@@ -5,14 +5,23 @@ The engine and the :class:`~repro.runtime.forward.ForwardRunner` never call
 ``pl.pallas_call`` directly — they go through a :class:`KernelDispatch`
 built from ``PipelineConfig.kernels``:
 
-- ``"auto"`` (default): Pallas on an accelerator backend, reference on CPU.
-  Interpret-mode Pallas on CPU is an emulation (a compiled per-grid-step
-  loop) and loses to vectorized numpy on every shape —
-  ``benchmarks/kernel_hotpath.py`` measures exactly this fallback decision.
+- ``"auto"`` (default): the reference path on every backend. On CPU,
+  interpret-mode Pallas is an emulation (a compiled per-grid-step loop) and
+  loses to vectorized numpy on every shape (``benchmarks/kernel_hotpath.py``).
+  On a TPU the v5e compiler refuses all three gather/scatter kernels: their
+  single-row ``(1, 128)`` blocks (and ``gather_aggregate``'s ``(1, 1)``
+  edge-weight block) fail Mosaic lowering with "the last two dimensions of
+  your block shape are divisible by 8 and 128", and a scalar-prefetched index
+  vector of 262,144 rows overflows the 1 MiB SMEM. So the layers run as the
+  plain XLA programs on the chip until the kernels are redesigned with
+  8-row-aligned tiles and SMEM-sized index chunks
+  (``tests/test_v5e_compile.py`` xfails each refusal strictly).
 - ``"reference"``: always the numpy/jnp path (the seed engine's math).
 - ``"pallas"``: force the Pallas kernels, with ``interpret=True`` on CPU —
   how CI runs every bit-identity test through the fused path. Bit-identical
-  to ``"reference"`` for every schedule and depth.
+  to ``"reference"`` for every schedule and depth. On a TPU the first call
+  raises the compiler's refusal above; it never reroutes to ``"reference"``
+  and never runs in interpret mode off the CPU.
 - ``"pallas-fused"``: additionally route the GCN forward through the
   one-kernel gather+aggregate. Its per-edge accumulate is a fused
   multiply-add — deterministic (pipelined == serial bitwise) and one
@@ -114,7 +123,9 @@ class KernelDispatch:
         # interpret-mode emulation is the only way to run Pallas on CPU
         self.interpret = backend == "cpu"
         if mode == "auto":
-            mode = "reference" if backend == "cpu" else "pallas"
+            # see the module docstring: interpret mode is slower than numpy
+            # on CPU, and v5e refuses the kernels' row blocks
+            mode = "reference"
         self.mode = mode
         self.counters = counters
         self._jit_fwd = {}

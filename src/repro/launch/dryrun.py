@@ -1,7 +1,10 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init). Placeholder devices exist ONLY for the dry-run.
+# The lines above MUST run before any other import (jax locks the backend and
+# the device count at first init). Placeholder devices exist ONLY for the
+# dry-run, which lowers on the CPU backend: it never takes a chip, so it and
+# its --all children can run beside a process that holds one.
 
 """Multi-pod dry-run: lower + compile every (arch × shape) on the production
 meshes and extract the roofline terms from the compiled artifact.

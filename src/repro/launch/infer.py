@@ -1,8 +1,9 @@
 """Inference launcher: ``python -m repro.launch.infer --arch <id> [...]``.
 
 The serving-side sibling of ``repro.launch.train --offload``: runs
-storage-offloaded layer-wise inference (repro/infer/) for a GNN arch on a
-small synthetic graph, checks the pipelined engine against the serial one
+storage-offloaded layer-wise inference (repro/infer/) for a GNN arch on the
+workload ``repro.launch.train.gnn_workload`` builds from it (``--nodes``
+cuts the node count), checks the pipelined engine against the serial one
 (bit-identical embedding table) and the served lookups against a dense
 whole-graph forward, then reports the EmbeddingServer's hit/latency stats.
 
@@ -15,9 +16,9 @@ import sys
 
 
 def _infer_smoke(
-    model: str,
+    wl,
     depth: int,
-    cache_mb: int = 4,
+    cache_bytes: int = None,
     serve_cache_kb: int = 256,
     queries: int = 8,
     batch: int = 64,
@@ -28,7 +29,9 @@ def _infer_smoke(
     ledger: str = None,
 ) -> dict:
     """Drive OffloadedInference (serial + pipelined) and the
-    EmbeddingServer for a GNN arch; returns the check/stat dict.
+    EmbeddingServer over a :class:`repro.launch.train.GNNWorkload`;
+    returns the check/stat dict. ``cache_bytes`` defaults to a quarter of
+    the layer-0 activations.
 
     ``telemetry_port`` serves live Prometheus metrics over the pipelined
     run's counters (the serve-side gauges included); ``ledger`` appends a
@@ -39,26 +42,15 @@ def _infer_smoke(
     import jax
     import numpy as np
 
-    from repro.core import Counters, HostCache, StorageTier, build_plan
-    from repro.graph import (
-        gcn_norm_coeffs, kronecker_graph, switching_aware_partition,
-    )
-    from repro.graph.csr import add_self_loops
-    from repro.graph.synthetic import random_features
+    from repro.core import Counters, HostCache, StorageTier
     from repro.infer import EmbeddingServer, OffloadedInference
-    from repro.models.gnn.layers import (
-        full_graph_forward, full_graph_topo, get_gnn,
-    )
+    from repro.models.gnn.layers import full_graph_forward, full_graph_topo
     from repro.runtime import PipelineConfig
 
-    g = add_self_loops(kronecker_graph(2000, 7, seed=0))
-    n_parts = 6
-    res = switching_aware_partition(g, n_parts, max_iters=8, seed=0)
-    plan = build_plan(g, res.parts, n_parts, edge_weight=gcn_norm_coeffs(g))
-    dims = [24, 32, 8]
-    spec = get_gnn(model)
-    params = spec.init(jax.random.PRNGKey(0), 24, 32, 8, 2)
-    X = random_features(g.n_nodes, 24, 0)[plan.ro.perm]
+    plan, dims, spec, params, X = wl.plan, wl.dims, wl.spec, wl.params, wl.X
+    n_nodes = wl.n_nodes
+    if cache_bytes is None:
+        cache_bytes = wl.act0_bytes // 4
     store_dtype = np.float16 if fp16 else None
 
     tables = {}
@@ -67,8 +59,9 @@ def _infer_smoke(
     c = None
     for d in sorted({0, depth}):
         c = Counters()
-        st_ = StorageTier(tempfile.mkdtemp(), counters=c)
-        cache = HostCache(cache_mb << 20, st_, c)
+        root = tempfile.TemporaryDirectory(prefix="sso-infer-")
+        st_ = StorageTier(root.name, counters=c)
+        cache = HostCache(cache_bytes, st_, c)
         inf = OffloadedInference(
             spec, plan, dims, st_, cache, c,
             pipeline=PipelineConfig(
@@ -88,10 +81,11 @@ def _infer_smoke(
         name = inf.run(params)
         if d == depth:
             wall = time.perf_counter() - t0
-        tables[d] = st_.read_rows(name, 0, g.n_nodes)
+        tables[d] = st_.read_rows(name, 0, n_nodes)
         inf.close()
         if d != depth:
             st_.close()
+            root.cleanup()
             continue
         # serve the pipelined run's table and check against a dense forward
         # (sharing the run's counters, so lookups land in the same metrics
@@ -107,7 +101,7 @@ def _infer_smoke(
         tol = 5e-2 if fp16 else 1e-3
         serve_ok = True
         for _ in range(queries):
-            ids = rng.integers(0, g.n_nodes, batch)
+            ids = rng.integers(0, n_nodes, batch)
             got = srv.lookup(ids).astype(np.float32)
             want = ref[plan.ro.inv_perm[ids]]
             if not np.allclose(got, want, rtol=tol, atol=tol):
@@ -122,12 +116,14 @@ def _infer_smoke(
         if server is not None:
             server.stop()
         st_.close()
+        root.cleanup()
 
     if ledger:
         from repro.obs.ledger import RunLedger, make_record
         RunLedger(ledger).append(make_record(
             "infer_smoke",
-            dict(model=model, depth=depth, cache_mb=cache_mb,
+            dict(arch=wl.arch.name, n_nodes=n_nodes, depth=depth,
+                 cache_bytes=cache_bytes,
                  serve_cache_kb=serve_cache_kb, queries=queries,
                  batch=batch, fp16=fp16, gather_workers=gather_workers),
             dict(wall_s=wall,
@@ -154,13 +150,16 @@ def _infer_smoke(
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
-                    help="a GNN arch id (e.g. gcn-cora); the model family "
-                         "is recovered from the config naming convention")
+                    help="a GNN arch id (e.g. gcn-cora); the model and "
+                         "widths come from its GNNArch")
+    ap.add_argument("--nodes", type=int, default=None,
+                    help="graph nodes (default: the dataset's)")
     ap.add_argument("--pipeline-depth", type=int, default=2,
                     help="async pipeline lookahead (0 = serial engine)")
     ap.add_argument("--gather-workers", type=int, default=1)
-    ap.add_argument("--cache-mb", type=int, default=4,
-                    help="host-cache budget for the inference engine")
+    ap.add_argument("--cache-mb", type=int, default=None,
+                    help="host-cache budget for the inference engine "
+                         "(default: a quarter of the layer-0 activations)")
     ap.add_argument("--serve-cache-kb", type=int, default=256,
                     help="dedicated host-cache budget for the "
                          "EmbeddingServer")
@@ -189,15 +188,20 @@ def main():
                             format="%(name)s %(message)s")
 
     from repro.configs import REGISTRY
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.train import gnn_workload
 
     arch = REGISTRY[args.arch]
     if arch.family != "gnn":
         print(f"{args.arch}: inference requires a GNN arch "
               f"(family={arch.family})")
         sys.exit(2)
-    model = args.arch.split("-")[0]
+    enable_compile_cache()
+    wl = gnn_workload(arch.gnn, args.nodes)
     r = _infer_smoke(
-        model, args.pipeline_depth, cache_mb=args.cache_mb,
+        wl, args.pipeline_depth,
+        cache_bytes=(args.cache_mb << 20
+                     if args.cache_mb is not None else None),
         serve_cache_kb=args.serve_cache_kb, queries=args.queries,
         batch=args.batch, fp16=args.fp16,
         gather_workers=args.gather_workers, trace=args.trace,

@@ -65,13 +65,14 @@ class PipelineConfig:
         footprint forever.
     kernels
         Hot-loop kernel dispatch (``repro.kernels.dispatch``): ``"auto"``
-        picks the fused Pallas gather/aggregate + scatter-grad kernels on an
-        accelerator backend and the numpy/jnp reference path on CPU;
-        ``"pallas"`` / ``"reference"`` force one side (Pallas runs under
-        ``interpret=True`` on CPU — how CI exercises the fused path). Both
-        paths are bit-identical for the engine's schedules; the Pallas path
-        additionally skips the host-side gathered copy by staging whole
-        partition blocks and indexing rows on device.
+        is the numpy/jnp reference path on every backend (interpret-mode
+        Pallas loses on CPU; v5e refuses the kernels' single-row blocks);
+        ``"pallas"`` / ``"pallas-fused"`` force the fused gather/aggregate
+        + scatter-grad kernels (``interpret=True`` on CPU — how CI
+        exercises the fused path). Both paths are bit-identical for the
+        engine's schedules; the Pallas path additionally skips the
+        host-side gathered copy by staging whole partition blocks and
+        indexing rows on device.
     zero_copy_h2d
         Stage host buffers onto the device with a zero-copy
         ``jax.device_put`` (the :class:`BufferPool`'s 64-byte-aligned

@@ -50,6 +50,39 @@ def act_file(layer: int) -> str:
     return f"act{layer}"
 
 
+@partial(jax.jit, static_argnames=("apply", "activate"))
+def layer_apply(params_l, ga, topo, *, apply, activate):
+    """One forward layer program. Jitted once per process and keyed on the
+    layer function, so every engine and runner shares its executables."""
+    return apply(params_l, ga, topo, activate=activate)
+
+
+def compile_parallel(jobs) -> int:
+    """Compile ``(jitted_fn, args, static_kwargs)`` jobs on a thread per
+    core; returns the number of jobs. XLA compiles each program on one core
+    and releases the GIL, so the per-shape compiles of a cold run overlap
+    instead of queueing through the warm-up epoch; a later call with
+    arguments of the same shapes finds the executable in memory. The
+    caller's ``jax.default_matmul_precision`` (a thread-local setting that
+    keys the executables) is carried into the workers."""
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    precision = jax.config.jax_default_matmul_precision
+
+    def compile_one(job):
+        fn, args, kw = job
+        with jax.default_matmul_precision(precision):
+            fn.lower(*args, **kw).compile()
+
+    jobs = list(jobs)
+    workers = max(1, min(len(jobs), os.cpu_count() or 1))
+    with ThreadPoolExecutor(workers, thread_name_prefix="sso-compile") as ex:
+        for f in [ex.submit(compile_one, j) for j in jobs]:
+            f.result()
+    return len(jobs)
+
+
 class StackedGather(NamedTuple):
     """Pallas-path host staging product: whole cached partition blocks
     memcpy'd back to back (``stack``, a pooled buffer with one zeroed pad
@@ -102,7 +135,6 @@ class ForwardRunner:
         # unit; the gather stage pops and releases exactly these (prefetch
         # of a unit strictly precedes its gather via the stage queues)
         self.prefetch_pins: Dict = {}
-        self._jit_fwd = {}
         # Pallas path: per-unit (idx, sizes, total) row maps and their
         # device-resident copies — layer-independent (plan-derived), so one
         # H2D per unit for the whole run
@@ -111,15 +143,16 @@ class ForwardRunner:
 
     # ------------------------------------------------------------------ jit
     def fwd_fn(self, activate: bool):
-        if activate not in self._jit_fwd:
-            apply = self.spec.apply_layer
+        return partial(layer_apply, apply=self.spec.apply_layer,
+                       activate=activate)
 
-            @jax.jit
-            def f(params_l, ga, topo):
-                return apply(params_l, ga, topo, activate=activate)
-
-            self._jit_fwd[activate] = f
-        return self._jit_fwd[activate]
+    def shape_buckets(self):
+        """One unit per padded ``(r_pad, e_pad, d_pad)`` bucket of the plan:
+        the units that compile a distinct layer program."""
+        reps = {}
+        for u in self.plan.units:
+            reps.setdefault((u.r_pad, u.e_pad, u.d_pad), u)
+        return list(reps.values())
 
     # --------------------------------------------------------------- gather
     def load_part_block(self, layer: int, q: int) -> np.ndarray:

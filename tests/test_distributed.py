@@ -5,14 +5,13 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
 
 def _run(code: str, timeout=420):
     proc = subprocess.run(
         [sys.executable, "-c", code],
         capture_output=True, text=True, timeout=timeout,
         env={
+            "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
             "PYTHONPATH": "src",
             "PATH": "/usr/bin:/bin",
@@ -21,16 +20,6 @@ def _run(code: str, timeout=420):
     assert proc.returncode == 0, proc.stdout + proc.stderr
     return proc.stdout
 
-
-# these subprocess tests target the explicit-mesh API (jax.set_mesh /
-# sharding.AxisType, jax >= 0.6); on older jax they can neither import nor
-# emulate it (the legacy mesh context lowers differently and hangs), so the
-# whole module is version-gated rather than left to fail
-jax = pytest.importorskip("jax")
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="requires jax explicit-mesh API (jax.set_mesh, sharding.AxisType)",
-)
 
 PREAMBLE = """
 import jax, jax.numpy as jnp, numpy as np

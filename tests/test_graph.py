@@ -1,7 +1,7 @@
 """Graph substrate: CSR, generators, partitioner, reorder, sampler."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.graph import (
     CSRGraph, coo_to_csr, expansion_ratio, kronecker_graph,

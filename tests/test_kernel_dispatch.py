@@ -253,10 +253,22 @@ class TestKernelDispatch:
 
     def test_auto_resolves_reference_on_cpu(self):
         d = KernelDispatch("auto")
-        if d.backend == "cpu":
-            assert d.mode == "reference" and not d.use_pallas
-        else:                                           # pragma: no cover
-            assert d.use_pallas
+        assert d.mode == "reference" and not d.use_pallas
+
+    def test_tpu_auto_is_reference_and_pallas_never_interprets(
+        self, monkeypatch
+    ):
+        # v5e refuses the kernels' row blocks (tests/test_v5e_compile.py):
+        # auto must not pick them, and a forced mode must compile for real
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        d = KernelDispatch("auto")
+        assert d.backend == "tpu"
+        assert d.mode == "reference" and not d.use_pallas
+        for m in ("pallas", "pallas-fused"):
+            d = KernelDispatch(m)
+            assert d.mode == m and d.use_pallas and not d.interpret
 
     def test_forced_pallas_interprets_on_cpu(self):
         d = KernelDispatch("pallas")

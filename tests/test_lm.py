@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.lm.attention import chunked_attention
 from repro.models.lm.moe import MoEConfig, init_moe_params, moe_ffn

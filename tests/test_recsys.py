@@ -2,7 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.recsys.two_tower import (
     TwoTowerConfig, embedding_bag, init_two_tower, item_embedding,

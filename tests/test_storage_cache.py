@@ -3,7 +3,7 @@ import tempfile
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.cache import HostCache
 from repro.core.counters import Counters
