@@ -1,0 +1,8 @@
+"""Bytes staged host to device per epoch in the window (the
+pipeline's transfer stage, ``runtime/``)."""
+
+
+def read(r):
+    if r["job"] != "train":
+        return None
+    return r["counters"].get("h2d_bytes", 0) / r["iters"]

@@ -1,0 +1,8 @@
+"""Bytes read from the storage tier per epoch in the window
+(``core/storage.py``)."""
+
+
+def read(r):
+    if r["job"] != "train":
+        return None
+    return r["counters"].get("storage_read_bytes", 0) / r["iters"]
