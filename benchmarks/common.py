@@ -2,12 +2,9 @@
 plumbing every BENCH producer goes through: the compile-cache placement its
 ``main()`` calls first (``enable_compile_cache``), provenance
 stamping for ``BENCH_*.json`` artifacts, the ``--ledger`` append path into
-``RUNS/ledger.jsonl`` (repro.obs.ledger), and the attribution helper that
-sets the storage peak to the EMULATED NVMe bandwidth when a bench emulates
-one."""
+``RUNS/ledger.jsonl`` (repro.obs.ledger)."""
 from __future__ import annotations
 
-import dataclasses
 import json
 import tempfile
 import time
@@ -209,8 +206,7 @@ def write_bench_json(path: str, payload: Dict, run_kind: str) -> Dict:
 
 
 def ledger_append(path: str, run_kind: str, config: Dict, headline: Dict,
-                  *, counters=None, watch=None, attribution=None,
-                  extra=None) -> Dict:
+                  *, counters=None, watch=None, extra=None) -> Dict:
     """Build + append one run record to the JSONL ledger. The backend
     string is resolved here (the obs layer is stdlib-only and must not
     import jax)."""
@@ -218,18 +214,10 @@ def ledger_append(path: str, run_kind: str, config: Dict, headline: Dict,
 
     rec = make_record(
         run_kind, config, headline, counters=counters, watch=watch,
-        attribution=attribution, backend=jax.default_backend(), extra=extra,
+        backend=jax.default_backend(), extra=extra,
     )
     RunLedger(path).append(rec)
     print(f"ledger,{path},appended run_kind={run_kind} "
           f"fingerprint={rec['fingerprint']}")
     return rec
 
-
-def bench_bandwidths(storage_gbps: float = 0.0):
-    """Tier peaks for attribution: when the bench emulates an NVMe lane,
-    utilization must be judged against the EMULATED bandwidth (the peak the
-    run could actually have reached), not the paper's 12 GB/s device."""
-    if storage_gbps and storage_gbps > 0:
-        return dataclasses.replace(PAPER_WORKSTATION, ssd=storage_gbps * 1e9)
-    return PAPER_WORKSTATION

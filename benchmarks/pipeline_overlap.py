@@ -36,8 +36,7 @@ def run_pair(wl, depth, epochs, cache_mb, mode, latency_us, gbps, workers,
         )
         # min-of-epochs: robust to noisy-neighbour CPU spikes on shared boxes
         out[d] = dict(
-            wall=min(walls), mean_wall=sum(walls) / len(walls),
-            total_wall=sum(walls), loss=loss,
+            wall=min(walls), mean_wall=sum(walls) / len(walls), loss=loss,
             counters=c, overlap=c.overlap_summary(sum(walls)),
         )
     return out
@@ -151,20 +150,6 @@ def main() -> int:
     print(f"prefetch_working_set,{sum(ws) / len(ws):.1f},"
           f"mean source partitions staged ahead at depth {args.depth}")
 
-    # achieved-vs-peak utilization of the pipelined run: bytes + busy time
-    # from the counters joined against the tier peaks (the emulated NVMe
-    # bandwidth when emulating — utilization vs what the run COULD reach)
-    from benchmarks.common import bench_bandwidths, gnn_epoch_flops
-    from repro.obs.attribution import attribution_report, format_attribution
-
-    flops = args.epochs * gnn_epoch_flops(
-        wl["g"].n_nodes, wl["g"].n_edges, wl["dims"])
-    attr = attribution_report(
-        c.snapshot(), bench_bandwidths(args.storage_gbps),
-        pipe["total_wall"], flops=flops, metrics=c.metrics.snapshot(),
-    )
-    print(format_attribution(attr))
-
     config = dict(
         nodes=args.nodes, parts=args.parts, layers=args.layers,
         hidden=args.hidden, depth=args.depth,
@@ -206,7 +191,6 @@ def main() -> int:
                 stage_busy_s=dict(sorted(c.stage_busy_seconds.items())),
                 stage_stall_s=dict(sorted(c.stage_stall_seconds.items())),
             ),
-            attribution=attr,
             speedup=speedup,
             read_ops_ratio=(pipe_ops / ser_ops) if ser_ops else None,
         )
@@ -215,7 +199,7 @@ def main() -> int:
         from benchmarks.common import ledger_append
 
         ledger_append(args.ledger, "pipeline_overlap", config, headline,
-                      counters=c, watch=watch, attribution=attr)
+                      counters=c, watch=watch)
 
     ok = True
     if ov["overlapped_frac"] <= 0.0:

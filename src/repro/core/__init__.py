@@ -1,5 +1,5 @@
 """GriNNder core: structured storage offloading (cache/(re)gather/bypass)."""
-from repro.core.counters import Counters, PhaseTimer
+from repro.core.counters import Counters
 from repro.core.storage import (
     RetryPolicy, StorageCorruptionError, StorageDeadlineError, StorageError,
     StorageFullError, StorageIOQueue, StorageTier, TransientIOError,
@@ -15,7 +15,7 @@ from repro.core.costmodel import (
 from repro.core.microbatch import microbatch_grads, build_full_mfg
 
 __all__ = [
-    "Counters", "PhaseTimer", "StorageTier", "StorageIOQueue", "HostCache",
+    "Counters", "StorageTier", "StorageIOQueue", "HostCache",
     "StorageError", "TransientIOError", "StorageCorruptionError",
     "StorageDeadlineError", "StorageFullError", "RetryPolicy",
     "FaultPolicy", "FaultyTier",

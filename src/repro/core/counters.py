@@ -9,8 +9,11 @@ The pipeline runtime (repro/runtime/) additionally records per-stage busy
 time (work done on pipeline worker threads) and per-stage stall time (time a
 stage spent blocked on a queue or on write backpressure), from which the
 achieved I/O-compute overlap can be derived (paper Fig. 13 bandwidth study).
-All mutators are thread-safe: stage workers and the write-behind thread
-report into the same instance as the main compute loop.
+Both are recorded where the work happens, by one context manager each —
+:meth:`Counters.stage` and :meth:`Counters.wait` — which also span the block
+on the counters' tracer (and through it in the JAX profiler) when tracing
+is on. All mutators are thread-safe: stage workers and the write-behind
+thread report into the same instance as the main compute loop.
 """
 from __future__ import annotations
 
@@ -23,9 +26,29 @@ from typing import Dict
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NULL_TRACER
 
-# stalls shorter than this are pure queue-poll noise — not worth a trace
-# event each (they'd dominate the ring without adding timeline signal)
-_TRACE_STALL_MIN_S = 50e-6
+
+class _Timed:
+    """A block timed into a busy or stall map, inside ``span`` (the
+    tracer's span, or the shared no-op one while tracing is off); ``with``
+    hands back that span, so the block may ``set`` arguments on it."""
+
+    __slots__ = ("_record", "_key", "_span", "_t0")
+
+    def __init__(self, record, key: str, span):
+        self._record = record
+        self._key = key
+        self._span = span
+
+    def __enter__(self):
+        sp = self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return sp
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self._record(self._key, dt)
+        return False
 
 
 @dataclasses.dataclass
@@ -69,7 +92,6 @@ class Counters:
     MEM_TIMELINE_CAP = 65536
 
     def __post_init__(self):
-        self.phase_seconds: Dict[str, float] = defaultdict(float)
         # pipeline runtime accounting (repro/runtime/): stage -> seconds
         self.stage_busy_seconds: Dict[str, float] = defaultdict(float)
         self.stage_stall_seconds: Dict[str, float] = defaultdict(float)
@@ -92,13 +114,6 @@ class Counters:
         self.metrics.gauge("trace.ring_occupancy",
                            fn=lambda: self.tracer.ring_occupancy)
 
-    def record_phase(self, name: str, seconds: float) -> None:
-        with self._lock:
-            self.phase_seconds[name] += seconds
-        # bridge to the timeline OUTSIDE the counters lock (tracer has its
-        # own); span ends "now" because callers report on interval exit
-        self.tracer.complete(name, seconds)
-
     def bump(self, field: str, amount: int = 1) -> None:
         """Thread-safe increment of a scalar counter field. Pipeline gather
         workers (possibly several) share this instance with the main loop,
@@ -115,25 +130,34 @@ class Counters:
             for field, amount in fields.items():
                 setattr(self, field, getattr(self, field) + amount)
 
-    def record_busy(self, stage: str, seconds: float, args=None) -> None:
-        """Work executed on a pipeline worker thread (overlappable).
+    def stage(self, name: str, **args):
+        """``with counters.stage("gather", stream=s, seq=k, part=p):`` —
+        work of a pipeline stage, done in the block on a worker thread
+        (overlappable): its seconds go to ``stage_busy_seconds[name]``, and
+        an enabled tracer spans it as ``name`` with ``args``. Recording the
+        span where the work happens is what guarantees any stage with
+        nonzero busy time shows up on an exported timeline."""
+        return _Timed(self.record_busy, name, self.tracer.span(name, **args))
 
-        Every busy interval is also bridged to ``self.tracer`` as a
-        completed span named after the stage — which is what guarantees any
-        stage with nonzero ``stage_busy_seconds`` shows up on an exported
-        timeline. ``args`` (partition id, bytes, file) annotate the span;
-        callers guard the dict allocation behind ``tracer.enabled``.
-        """
+    def wait(self, name: str, **args):
+        """``with counters.wait("compute_wait_xfer_fwd", stream=s, seq=k):``
+        — time the block spends blocked (queue full/empty, backpressure):
+        its seconds go to ``stage_stall_seconds[name]``, and an enabled
+        tracer spans it as ``stall:<name>`` with ``args`` (the awaited
+        unit)."""
+        return _Timed(self.record_stall, name,
+                      self.tracer.span("stall:" + name, **args))
+
+    def record_busy(self, stage: str, seconds: float) -> None:
+        """Add busy seconds measured elsewhere (``stage`` measures its
+        block and lands here)."""
         with self._lock:
             self.stage_busy_seconds[stage] += seconds
-        self.tracer.complete(stage, seconds, args=args)
 
     def record_stall(self, stage: str, seconds: float) -> None:
-        """Time a stage spent blocked (queue full/empty, backpressure)."""
+        """Add stall seconds measured elsewhere (``wait`` lands here)."""
         with self._lock:
             self.stage_stall_seconds[stage] += seconds
-        if seconds >= _TRACE_STALL_MIN_S:
-            self.tracer.complete("stall:" + stage, seconds)
 
     def sample_memory(self, cache_bytes: int) -> None:
         with self._lock:
@@ -253,7 +277,6 @@ class Counters:
                 f.name: getattr(self, f.name)
                 for f in dataclasses.fields(self)
             }
-            d.update({f"t_{k}": v for k, v in self.phase_seconds.items()})
             d.update(
                 {f"busy_{k}": v for k, v in self.stage_busy_seconds.items()}
             )
@@ -266,7 +289,6 @@ class Counters:
         with self._lock:
             for f in dataclasses.fields(self):
                 setattr(self, f.name, 0)
-            self.phase_seconds.clear()
             self.stage_busy_seconds.clear()
             self.stage_stall_seconds.clear()
             self._mem_timeline.clear()
@@ -277,16 +299,3 @@ class Counters:
         self.metrics.reset()
         self.tracer.clear()
 
-
-class PhaseTimer:
-    def __init__(self, counters: Counters, name: str):
-        self.counters = counters
-        self.name = name
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.counters.record_phase(self.name, time.perf_counter() - self.t0)
-        return False

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cache import HostCache
-from repro.core.counters import Counters, PhaseTimer
+from repro.core.counters import Counters
 from repro.core.plan import PartitionPlan, WorkUnit
 from repro.core.storage import StorageTier
 from repro.kernels.dispatch import scatter_add_rows_ref
@@ -174,7 +174,7 @@ class SSOEngine:
         # hot-loop kernel dispatch (Pallas vs numpy reference), shared with
         # the runner so both halves of the pass pick the same path
         from repro.kernels.dispatch import KernelDispatch
-        self.kernels = KernelDispatch(pipeline.kernels, self.counters)
+        self.kernels = KernelDispatch(pipeline.kernels)
         # the shared forward layer pass (also the backward's regather path);
         # snapshot-mode backward pins live in the runner's pin table too
         self.fwd_runner = ForwardRunner(
@@ -240,9 +240,6 @@ class SSOEngine:
     # delegates (same cache keys and pin protocol as the forward).
     def _gather(self, layer: int, u: WorkUnit, pad_rows: int) -> np.ndarray:
         return self.fwd_runner.gather(layer, u, pad_rows)
-
-    def _gather_padded(self, layer: int, u: WorkUnit, phase: str) -> np.ndarray:
-        return self.fwd_runner.gather_padded(layer, u, phase)
 
     def _prefetch_unit(self, layer: int, u: WorkUnit) -> None:
         self.fwd_runner.prefetch_unit(layer, u)
@@ -333,11 +330,12 @@ class SSOEngine:
 
     def _grad_accumulate(
         self, layer: int, q: int, rows_local: np.ndarray, values: np.ndarray
-    ) -> None:
+    ) -> str:
         """Scatter-accumulate ∇A^{layer} rows for source partition q (the
         paper's host write-back buffer with storage spill). The buffer is
         pinned for the duration of the update so a concurrent pipeline-worker
-        eviction cannot flush it mid-accumulate."""
+        eviction cannot flush it mid-accumulate. Returns the scatter's
+        dispatch path (``"ref"`` or ``"pallas"``)."""
         key = ("grad", layer, q)
         a0, a1 = self.plan.ro.partition_slice(q)
         name = _grad_name(layer)
@@ -367,13 +365,14 @@ class SSOEngine:
                 # touched again); later fetches of this region go through
                 # the same FIFO, so they see it without blocking here.
                 # bump(): accumulates may race pipeline workers' counters
-                self.kernels.scatter_add_rows(buf, rows_local, values)
+                path = self.kernels.scatter_add_rows(buf, rows_local, values)
                 self._rt.write_rows(name, a0, buf)
                 self.counters.bump("host_scatter_bytes", values.nbytes)
-                return
-        self.kernels.scatter_add_rows(buf, rows_local, values)
+                return path
+        path = self.kernels.scatter_add_rows(buf, rows_local, values)
         self.cache.release(key)
         self.counters.bump("host_scatter_bytes", values.nbytes)
+        return path
 
     def _grad_fetch(self, layer: int, p: int) -> np.ndarray:
         """Read ∇A^{layer} for destination partition p (padded to topo rows).
@@ -382,20 +381,19 @@ class SSOEngine:
         grad-file read behind the previous unit's compute. The padded output
         comes from the runtime pool — the caller releases it via
         ``self._rt.pool.release`` once the device has consumed it."""
-        with PhaseTimer(self.counters, "grad_fetch"):
-            u = self.plan.unit(p)
-            key = ("grad", layer, p)
-            a0, a1 = u.v0, u.v1
-            buf = self.cache.peek(key)
-            if buf is None and ("gradmat", layer, p) in self._materialized_grads:
-                buf = self._io_read(_grad_name(layer), a0, a1)
-            out = self._rt.pool.acquire((u.d_pad, self.dims[layer]), self.dtype)
-            if buf is None:       # never materialized: ∇A rows are zero
-                out[:] = 0
-            else:
-                out[: u.n_dst] = buf
-                out[u.n_dst :] = 0
-            return out
+        u = self.plan.unit(p)
+        key = ("grad", layer, p)
+        a0, a1 = u.v0, u.v1
+        buf = self.cache.peek(key)
+        if buf is None and ("gradmat", layer, p) in self._materialized_grads:
+            buf = self._io_read(_grad_name(layer), a0, a1)
+        out = self._rt.pool.acquire((u.d_pad, self.dims[layer]), self.dtype)
+        if buf is None:       # never materialized: ∇A rows are zero
+            out[:] = 0
+        else:
+            out[: u.n_dst] = buf
+            out[u.n_dst :] = 0
+        return out
 
     # ------------------------------------------------------------- backward
     def backward(self, params: List, labels_reordered: np.ndarray):
@@ -420,7 +418,6 @@ class SSOEngine:
         units = [plan.unit(p) for p in plan.schedule]
         use_xfer = self._use_xfer
         tracer = self.counters.tracer
-        t_loss = time.perf_counter()
 
         def loss_fetch(u: WorkUnit) -> np.ndarray:
             logits = st.read_rows(_act_name(L), u.v0, u.v1)
@@ -442,37 +439,39 @@ class SSOEngine:
             self.counters.bump("h2d_bytes", lb.nbytes)
             return (lg_dev, lb_dev), None
 
-        for u, lg, _ in rt.run_stream(
-            units, loss_fetch,
-            transfer_fn=loss_transfer if use_xfer else None,
-            cleanup_fn=self.fwd_runner._cleanup_stream,
-            gather_stage="loss_fetch", wait_stage="compute_wait_loss",
-            xfer_wait_stage="compute_wait_xfer_loss",
-            xfer_up_stage="xfer_wait_up_loss",
-        ):
-            if use_xfer:
-                lg_dev, lb_dev = lg
-                lg_host = None
-            else:
-                lg_host = lg
-                lb = _pad_labels(u)
-                # count labels too, matching the transfer-stage path
-                self.counters.bump("h2d_bytes", lg.nbytes + lb.nbytes)
-                lg_dev, lb_dev = jnp.asarray(lg), jnp.asarray(lb)
-            loss_p, dlog = loss_and_grad(lg_dev, lb_dev, jnp.float32(n))
-            dlog_dst = dlog[: u.n_dst]
-            # start the D2H copy; it lands while the loss scalar transfers
-            dlog_dst.copy_to_host_async()
-            total_loss += float(loss_p)
-            dlog_np = np.asarray(dlog_dst)
-            self.counters.bump("d2h_bytes", dlog_np.nbytes)
-            if lg_host is not None:
-                rt.pool.release(lg_host)
-            with PhaseTimer(self.counters, "scatter"):
-                self._grad_accumulate(L, u.p, np.arange(u.n_dst), dlog_np)
-        if tracer.enabled:
-            tracer.complete("loss_layer", time.perf_counter() - t_loss,
-                            args={"units": len(units)})
+        with tracer.span("loss_layer", units=len(units)):
+            for u, lg, _ in rt.run_stream(
+                units, loss_fetch,
+                transfer_fn=loss_transfer if use_xfer else None,
+                cleanup_fn=self.fwd_runner._cleanup_stream,
+                gather_stage="loss_fetch", wait_stage="compute_wait_loss",
+                xfer_wait_stage="compute_wait_xfer_loss",
+                xfer_up_stage="xfer_wait_up_loss",
+                layer=L, pass_name="loss",
+            ):
+                if use_xfer:
+                    lg_dev, lb_dev = lg
+                    lg_host = None
+                else:
+                    lg_host = lg
+                    lb = _pad_labels(u)
+                    # count labels too, matching the transfer-stage path
+                    self.counters.bump("h2d_bytes", lg.nbytes + lb.nbytes)
+                    lg_dev, lb_dev = jnp.asarray(lg), jnp.asarray(lb)
+                loss_p, dlog = loss_and_grad(lg_dev, lb_dev, jnp.float32(n))
+                dlog_dst = dlog[: u.n_dst]
+                # start the D2H copy; it lands while the loss scalar
+                # transfers
+                dlog_dst.copy_to_host_async()
+                with tracer.span("d2h_wait"):
+                    total_loss += float(loss_p)
+                    dlog_np = np.asarray(dlog_dst)
+                self.counters.bump("d2h_bytes", dlog_np.nbytes)
+                if lg_host is not None:
+                    rt.pool.release(lg_host)
+                with tracer.span("scatter") as sp:
+                    sp.set(path=self._grad_accumulate(
+                        L, u.p, np.arange(u.n_dst), dlog_np))
 
         # ---- layers L..1
         grads: List = [None] * L
@@ -482,143 +481,145 @@ class SSOEngine:
         # stays on the reference path (a documented dispatch rule).
         use_stacked = self.kernels.use_pallas and self.mode == "regather"
         for l in range(L - 1, -1, -1):
-            t_layer = time.perf_counter()
-            if use_stacked:
-                bwd = self.kernels.fused_backward_fn(
-                    self.spec, activate=(l < L - 1)
-                )
-            else:
-                bwd = self._bwd(activate=(l < L - 1))
-            dW_acc = None
-            units = [plan.unit(p) for p in plan.schedule]
-            if self.mode == "regather":
-                if use_stacked:
-                    gather_fn = lambda u, _l=l: (
-                        self.fwd_runner.stacked_gather_timed(
-                            _l, u, "regather"
-                        )
-                    )
-                else:
-                    gather_fn = lambda u, _l=l: self._gather_padded(
-                        _l, u, "regather"
-                    )
-                prefetch_fn = (
-                    (lambda u, _l=l: self._prefetch_unit(_l, u))
-                    if self.pipeline.enabled else None
-                )
-                gather_stage, prefetch_stage = "regather", "prefetch_bwd"
-            else:
-                gather_fn = lambda u, _l=l: self._snapshot_get(_l, u.p, u)
-                prefetch_fn = (
-                    (lambda u, _l=l: self._snapshot_prefetch(_l, u))
-                    if self.pipeline.enabled else None
-                )
-                gather_stage, prefetch_stage = "snap_fetch", "snap_prefetch"
-            # aux stage: fetch ∇A^{l+1} on the gather workers. Safe to run
-            # ahead — grad layer l+1 was fully accumulated before this
-            # stream started, and this stream only scatters into layer l.
-            aux_fn = (
-                (lambda u, _l=l: self._grad_fetch(_l + 1, u.p))
-                if (self.pipeline.enabled and self.pipeline.aux_fetch)
-                else None
-            )
-            use_xfer = self._use_xfer
-
-            def bwd_transfer(u, ga, d_out, _l=l):
-                # stage GA (or the Pallas partition stack) and ∇A^{l+1} on
-                # the transfer thread; when the aux stage is off, its fetch
-                # also lands here (still off the compute thread)
-                if d_out is None:
-                    d_out = self._grad_fetch(_l + 1, u.p)
-                do_dev = self.fwd_runner.stage_h2d(d_out)
-                if use_stacked:
-                    stack_dev = self.fwd_runner.stage_h2d(ga.stack)
-                    return (stack_dev, self.fwd_runner.idx_dev(u)), do_dev
-                return self.fwd_runner.stage_h2d(ga), do_dev
-
-            for u, ga, d_out in rt.run_stream(
-                units, gather_fn, prefetch_fn, aux_fn=aux_fn,
-                transfer_fn=bwd_transfer if use_xfer else None,
-                cleanup_fn=self.fwd_runner._cleanup_stream,
-                prefetch_stage=prefetch_stage, gather_stage=gather_stage,
-                aux_stage="grad_fetch", wait_stage="compute_wait_bwd",
-                xfer_wait_stage="compute_wait_xfer_bwd",
-                xfer_up_stage="xfer_wait_up_bwd",
-            ):
-                if not use_xfer and d_out is None:
-                    # aux stage disabled: fetch inline
-                    d_out = self._grad_fetch(l + 1, u.p)
-                with PhaseTimer(self.counters, "compute_bwd"):
-                    if use_xfer:
-                        dev_in, do_dev = ga, d_out
-                        ga = d_out = None
-                    elif use_stacked:
-                        self.counters.bump(
-                            "h2d_bytes", ga.stack.nbytes + d_out.nbytes
-                        )
-                        # aligned pool buffers: asarray aliases; safe — the
-                        # dga materialization below blocks before release
-                        dev_in = (
-                            jnp.asarray(ga.stack),
-                            self.fwd_runner.idx_dev(u),
-                        )
-                        do_dev = jnp.asarray(d_out)
-                    else:
-                        self.counters.bump(
-                            "h2d_bytes", ga.nbytes + d_out.nbytes
-                        )
-                        dev_in, do_dev = jnp.asarray(ga), jnp.asarray(d_out)
-                    if use_stacked:
-                        dp, dga = bwd(
-                            params[l], dev_in[0], dev_in[1], u.topo, do_dev
-                        )
-                    else:
-                        dp, dga = bwd(params[l], dev_in, u.topo, do_dev)
-                    dga_req = dga[: u.n_req]
-                    # start the D2H copy; it lands under the dW accumulate
-                    dga_req.copy_to_host_async()
-                    dW_acc = (
-                        dp
-                        if dW_acc is None
-                        else jax.tree.map(jnp.add, dW_acc, dp)
-                    )
-                    dga_np = np.asarray(dga_req)
-                    self.counters.bump("d2h_bytes", dga_np.nbytes)
-                if ga is not None:
-                    rt.pool.release(ga.stack if use_stacked else ga)
-                if d_out is not None:
-                    rt.pool.release(d_out)
-                if l > 0:
-                    # scatter ∇GA rows back to their source partitions
-                    with PhaseTimer(self.counters, "scatter"):
-                        ptr = u.req_part_ptr
-                        for q in u.req_parts:
-                            a0, _ = plan.ro.partition_slice(int(q))
-                            rows = u.req_global[ptr[q] : ptr[q + 1]] - a0
-                            self._grad_accumulate(
-                                l, int(q), rows, dga_np[ptr[q] : ptr[q + 1]]
-                            )
-            grads[l] = jax.tree.map(np.asarray, dW_acc)
-            # drop consumed grad layer l+1 from cache & storage; barrier
-            # first so no queued degraded spill targets the freed file
-            self.cache.drop_layer("grad", l + 1, flush=False)
-            rt.drain_writes()
-            st.free(_grad_name(l + 1))
-            if self.mode == "snapshot":
-                self.cache.drop_layer("snap", l, flush=False)
-            if tracer.enabled:
-                tracer.complete("bwd_layer", time.perf_counter() - t_layer,
-                                args={"layer": l, "units": len(units)})
+            with tracer.span("bwd_layer", layer=l,
+                             units=len(plan.schedule)):
+                grads[l] = self._backward_layer(l, params, use_stacked)
         self.cache.drop_layer("grad", 0, flush=False)
         rt.drain_writes()
         st.free(_grad_name(0))
         return total_loss, grads
 
+    def _backward_layer(self, l: int, params: List, use_stacked: bool):
+        """One backward layer pass: regather (or snapshot-fetch) GA^l and
+        ∇A^{l+1} per unit, differentiate the layer, scatter ∇GA rows into
+        ∇A^l; returns the layer's parameter gradient. Ends with the
+        consumed ∇A^{l+1} dropped behind a write barrier."""
+        plan, st, rt = self.plan, self.storage, self._rt
+        L = self.n_layers
+        tracer = self.counters.tracer
+        if use_stacked:
+            bwd = self.kernels.fused_backward_fn(
+                self.spec, activate=(l < L - 1)
+            )
+        else:
+            bwd = self._bwd(activate=(l < L - 1))
+        dW_acc = None
+        units = [plan.unit(p) for p in plan.schedule]
+        if self.mode == "regather":
+            if use_stacked:
+                gather_fn = lambda u, _l=l: self.fwd_runner.stacked_gather(
+                    _l, u
+                )
+            else:
+                gather_fn = lambda u, _l=l: self._gather(_l, u, u.r_pad)
+            prefetch_fn = (
+                (lambda u, _l=l: self._prefetch_unit(_l, u))
+                if self.pipeline.enabled else None
+            )
+            gather_stage, prefetch_stage = "regather", "prefetch_bwd"
+        else:
+            gather_fn = lambda u, _l=l: self._snapshot_get(_l, u.p, u)
+            prefetch_fn = (
+                (lambda u, _l=l: self._snapshot_prefetch(_l, u))
+                if self.pipeline.enabled else None
+            )
+            gather_stage, prefetch_stage = "snap_fetch", "snap_prefetch"
+        # aux stage: fetch ∇A^{l+1} on the gather workers. Safe to run
+        # ahead — grad layer l+1 was fully accumulated before this
+        # stream started, and this stream only scatters into layer l.
+        aux_fn = (
+            (lambda u, _l=l: self._grad_fetch(_l + 1, u.p))
+            if (self.pipeline.enabled and self.pipeline.aux_fetch)
+            else None
+        )
+        use_xfer = self._use_xfer
+
+        def bwd_transfer(u, ga, d_out, _l=l):
+            # stage GA (or the Pallas partition stack) and ∇A^{l+1} on
+            # the transfer thread; when the aux stage is off, its fetch
+            # also lands here (still off the compute thread)
+            if d_out is None:
+                d_out = self._grad_fetch(_l + 1, u.p)
+            do_dev = self.fwd_runner.stage_h2d(d_out)
+            if use_stacked:
+                stack_dev = self.fwd_runner.stage_h2d(ga.stack)
+                return (stack_dev, self.fwd_runner.idx_dev(u)), do_dev
+            return self.fwd_runner.stage_h2d(ga), do_dev
+
+        for u, ga, d_out in rt.run_stream(
+            units, gather_fn, prefetch_fn, aux_fn=aux_fn,
+            transfer_fn=bwd_transfer if use_xfer else None,
+            cleanup_fn=self.fwd_runner._cleanup_stream,
+            prefetch_stage=prefetch_stage, gather_stage=gather_stage,
+            aux_stage="grad_fetch", wait_stage="compute_wait_bwd",
+            xfer_wait_stage="compute_wait_xfer_bwd",
+            xfer_up_stage="xfer_wait_up_bwd",
+            layer=l, pass_name="bwd",
+        ):
+            if not use_xfer and d_out is None:
+                # aux stage disabled: fetch inline
+                d_out = self._grad_fetch(l + 1, u.p)
+            if use_xfer:
+                dev_in, do_dev = ga, d_out
+                ga = d_out = None
+            elif use_stacked:
+                self.counters.bump(
+                    "h2d_bytes", ga.stack.nbytes + d_out.nbytes
+                )
+                # aligned pool buffers: asarray aliases; safe — the dga
+                # materialization below blocks before release
+                dev_in = (
+                    jnp.asarray(ga.stack),
+                    self.fwd_runner.idx_dev(u),
+                )
+                do_dev = jnp.asarray(d_out)
+            else:
+                self.counters.bump("h2d_bytes", ga.nbytes + d_out.nbytes)
+                dev_in, do_dev = jnp.asarray(ga), jnp.asarray(d_out)
+            if use_stacked:
+                dp, dga = bwd(params[l], dev_in[0], dev_in[1], u.topo, do_dev)
+            else:
+                dp, dga = bwd(params[l], dev_in, u.topo, do_dev)
+            dga_req = dga[: u.n_req]
+            # start the D2H copy; it lands under the dW accumulate
+            dga_req.copy_to_host_async()
+            dW_acc = dp if dW_acc is None else jax.tree.map(jnp.add, dW_acc, dp)
+            with tracer.span("d2h_wait"):
+                dga_np = np.asarray(dga_req)
+            self.counters.bump("d2h_bytes", dga_np.nbytes)
+            if ga is not None:
+                rt.pool.release(ga.stack if use_stacked else ga)
+            if d_out is not None:
+                rt.pool.release(d_out)
+            if l > 0:
+                # scatter ∇GA rows back to their source partitions
+                with tracer.span("scatter") as sp:
+                    path = "ref"
+                    ptr = u.req_part_ptr
+                    for q in u.req_parts:
+                        a0, _ = plan.ro.partition_slice(int(q))
+                        rows = u.req_global[ptr[q] : ptr[q + 1]] - a0
+                        if self._grad_accumulate(
+                            l, int(q), rows, dga_np[ptr[q] : ptr[q + 1]]
+                        ) == "pallas":
+                            path = "pallas"
+                    sp.set(path=path)
+        with tracer.span("d2h_wait"):
+            grads_l = jax.tree.map(np.asarray, dW_acc)
+        # drop consumed grad layer l+1 from cache & storage; barrier
+        # first so no queued degraded spill targets the freed file
+        self.cache.drop_layer("grad", l + 1, flush=False)
+        rt.drain_writes()
+        st.free(_grad_name(l + 1))
+        if self.mode == "snapshot":
+            self.cache.drop_layer("snap", l, flush=False)
+        return grads_l
+
     # ----------------------------------------------------------------- step
     def run_epoch(self, params: List, labels_reordered: np.ndarray):
         t0 = time.perf_counter()
         try:
-            with PhaseTimer(self.counters, "epoch"):
+            with self.counters.tracer.span("epoch"):
                 self.forward(params)
                 loss, grads = self.backward(params, labels_reordered)
         except BaseException:

@@ -331,12 +331,17 @@ class StorageTier:
         )
 
     def read_rows(self, name: str, row0: int, row1: int) -> np.ndarray:
+        """Rows ``[row0, row1)`` of ``name``. Like every read below, spanned
+        as ``storage_read`` (with its ``bytes``) for the thread's current
+        unit."""
         verify = None
         if self.verify_reads:
             verify = lambda a: self._verify_rows(name, range(row0, row1), a)
-        out = self._reliable(
-            "read", lambda: self._read_rows_once(name, row0, row1), verify
-        )
+        with self.counters.tracer.span("storage_read", file=name) as sp:
+            out = self._reliable(
+                "read", lambda: self._read_rows_once(name, row0, row1), verify
+            )
+            sp.set(bytes=int(out.nbytes))
         nb = out.nbytes
         self.counters.bump_many(
             storage_read_bytes=nb,
@@ -364,14 +369,17 @@ class StorageTier:
             def verify(outs):
                 for (name, row0, row1), out in zip(requests, outs):
                     self._verify_rows(name, range(row0, row1), out)
-        outs = self._reliable(
-            "read_batch", lambda: self._read_rows_batched_once(requests),
-            verify,
-        )
-        nb = paged = 0
-        for out in outs:
-            nb += out.nbytes
-            paged += self._paged(out.nbytes)
+        with self.counters.tracer.span("storage_read",
+                                       ranges=len(requests)) as sp:
+            outs = self._reliable(
+                "read_batch", lambda: self._read_rows_batched_once(requests),
+                verify,
+            )
+            nb = paged = 0
+            for out in outs:
+                nb += out.nbytes
+                paged += self._paged(out.nbytes)
+            sp.set(bytes=int(nb))
         self.counters.bump_many(
             storage_read_bytes=nb,
             storage_read_paged_bytes=paged,
@@ -389,10 +397,12 @@ class StorageTier:
         verify = None
         if self.verify_reads:
             verify = lambda a: self._verify_rows(name, rows, a)
-        out = self._reliable(
-            "read_scattered",
-            lambda: self._read_rows_scattered_once(name, rows), verify,
-        )
+        with self.counters.tracer.span("storage_read", file=name) as sp:
+            out = self._reliable(
+                "read_scattered",
+                lambda: self._read_rows_scattered_once(name, rows), verify,
+            )
+            sp.set(bytes=int(out.nbytes))
         if len(rows) == 0:
             # nothing was touched on the device: no ops, no paged bytes
             return out
@@ -523,6 +533,11 @@ class StorageIOQueue:
         with self._cond:
             return id(arr) in self._inflight_write_ids
 
+    def _over_inflight(self, nb: int) -> bool:
+        # caller holds self._cond; a lone over-sized write is admitted
+        return (self._inflight_bytes > 0
+                and self._inflight_bytes + nb > self.max_inflight)
+
     def submit_write(self, name: str, row0: int, arr: np.ndarray,
                      wait: bool = True) -> cf.Future:
         """Queue a ranged write. The caller must not mutate ``arr`` after
@@ -533,20 +548,19 @@ class StorageIOQueue:
         if wait:
             self._check_guard("submit_write")
         nb = int(arr.nbytes)
-        t0 = time.perf_counter()
+        unit = self.counters.tracer.current_unit()
         with self._cond:
             if self._closed:
                 raise RuntimeError("StorageIOQueue is closed")
-            while wait and (
-                self._inflight_bytes > 0
-                and self._inflight_bytes + nb > self.max_inflight
-            ):
-                self._cond.wait(0.05)
-                if self._exc is not None:
-                    raise self._exc
+            if wait and self._over_inflight(nb):
+                with self.counters.wait("write_submit"):
+                    while self._over_inflight(nb):
+                        self._cond.wait(0.05)
+                        if self._exc is not None:
+                            raise self._exc
             fut: cf.Future = cf.Future()
             self._q.append(("w", (name, row0, arr), fut,
-                            time.perf_counter()))
+                            time.perf_counter(), unit))
             self._inflight_bytes += nb
             self._inflight_ops += 1
             self._inflight_write_ids.add(id(arr))
@@ -554,9 +568,6 @@ class StorageIOQueue:
                 self.max_inflight_observed, self._inflight_bytes
             )
             self._cond.notify_all()
-        stall = time.perf_counter() - t0
-        if stall > 0:
-            self.counters.record_stall("write_submit", stall)
         return fut
 
     def submit_read(self, name: str, row0: int, row1: int) -> cf.Future:
@@ -576,7 +587,8 @@ class StorageIOQueue:
                 raise self._exc
             fut: cf.Future = cf.Future()
             self._q.append(("r", (name, row0, row1), fut,
-                            time.perf_counter()))
+                            time.perf_counter(),
+                            self.counters.tracer.current_unit()))
             self._inflight_ops += 1
             self._cond.notify_all()
         return fut
@@ -593,7 +605,8 @@ class StorageIOQueue:
                 raise self._exc
             fut: cf.Future = cf.Future()
             self._q.append(("rb", list(requests), fut,
-                            time.perf_counter()))
+                            time.perf_counter(),
+                            self.counters.tracer.current_unit()))
             self._inflight_ops += 1
             self._cond.notify_all()
         return fut
@@ -607,16 +620,31 @@ class StorageIOQueue:
                 item = self._q.popleft()
             if item is StorageIOQueue._CLOSE:
                 return
-            kind, payload, fut, t_submit = item
+            kind, payload, fut, t_submit, unit = item
+            # spanned for the submitter's unit: a read's storage_read span
+            # nests inside and inherits it
+            args = unit or {}
+            if self.counters.tracer.enabled:
+                if kind == "w":
+                    args = dict(args, file=payload[0],
+                                bytes=int(payload[2].nbytes))
+                elif kind == "rb":
+                    args = dict(args, ranges=len(payload))
+                else:
+                    args = dict(args, file=payload[0],
+                                rows=int(payload[2] - payload[1]))
             t0 = time.perf_counter()
             try:
-                if kind == "w":
-                    self.tier.write_rows(*payload)
-                    res = None
-                elif kind == "rb":
-                    res = self.tier.read_rows_batched(payload)
-                else:
-                    res = self.tier.read_rows(*payload)
+                with self.counters.stage(
+                    "write_behind" if kind == "w" else "async_read", **args
+                ):
+                    if kind == "w":
+                        self.tier.write_rows(*payload)
+                        res = None
+                    elif kind == "rb":
+                        res = self.tier.read_rows_batched(payload)
+                    else:
+                        res = self.tier.read_rows(*payload)
             except BaseException as e:  # surface on drain() and futures
                 with self._cond:
                     self._exc = e
@@ -640,20 +668,8 @@ class StorageIOQueue:
                         )
             if kind == "w":
                 self._write_lat.observe(dt)
-                args = None
-                if self.counters.tracer.enabled:
-                    args = {"file": payload[0], "bytes": int(payload[2].nbytes)}
-                self.counters.record_busy("write_behind", dt, args=args)
             else:
                 self._read_lat.observe(dt)
-                args = None
-                if self.counters.tracer.enabled:
-                    if kind == "rb":
-                        args = {"ranges": len(payload)}
-                    else:
-                        args = {"file": payload[0],
-                                "rows": int(payload[2] - payload[1])}
-                self.counters.record_busy("async_read", dt, args=args)
             with self._cond:
                 if kind == "w":
                     self._inflight_bytes -= int(payload[2].nbytes)
@@ -696,16 +712,14 @@ class StorageIOQueue:
     # -- barriers -----------------------------------------------------------
     def drain(self) -> None:
         """Block until every submitted request has been serviced."""
-        t0 = time.perf_counter()
         with self._cond:
-            while self._q or self._inflight_ops > 0:
-                self._cond.wait(0.05)
+            if self._q or self._inflight_ops > 0:
+                with self.counters.wait("write_drain"):
+                    while self._q or self._inflight_ops > 0:
+                        self._cond.wait(0.05)
             if self._exc is not None:
                 exc, self._exc = self._exc, None
                 raise exc
-        stall = time.perf_counter() - t0
-        if stall > 0:
-            self.counters.record_stall("write_drain", stall)
 
     def close(self) -> None:
         """Flush all pending writes, then stop the I/O thread.

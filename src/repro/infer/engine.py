@@ -38,7 +38,7 @@ from typing import List, Optional, Union
 import numpy as np
 
 from repro.core.cache import HostCache
-from repro.core.counters import Counters, PhaseTimer
+from repro.core.counters import Counters
 from repro.core.plan import PartitionPlan
 from repro.core.storage import StorageTier
 from repro.models.gnn.layers import GNNSpec
@@ -139,7 +139,7 @@ class OffloadedInference:
         st = self.storage
         L = self.n_layers
         t0 = time.perf_counter()
-        with PhaseTimer(self.counters, "infer"):
+        with self.counters.tracer.span("infer"):
             for l in range(L):
                 last = l == L - 1
                 name_out = self.final_name if last else act_file(l + 1)

@@ -159,17 +159,20 @@ class EmbeddingServer:
                 f"node ids must be in [0, {self.n_rows}); got range "
                 f"[{ids.min()}, {ids.max()}]"
             )
-        rows = self._inv_perm[ids]
-        blocks = rows // self.block_rows
-        resident, missed = self._fetch_blocks(np.unique(blocks))
-        out = np.empty((ids.size, self.dim), self.table_dtype)
-        n_miss_rows = 0
-        for b in resident:
-            sel = blocks == b
-            r0, _ = self._block_range(b)
-            out[sel] = resident[b][rows[sel] - r0]
-            if b in missed:
-                n_miss_rows += int(sel.sum())
+        with self.counters.tracer.span("serve_lookup",
+                                       rows=int(ids.size)) as sp:
+            rows = self._inv_perm[ids]
+            blocks = rows // self.block_rows
+            resident, missed = self._fetch_blocks(np.unique(blocks))
+            sp.set(missed_blocks=len(missed))
+            out = np.empty((ids.size, self.dim), self.table_dtype)
+            n_miss_rows = 0
+            for b in resident:
+                sel = blocks == b
+                r0, _ = self._block_range(b)
+                out[sel] = resident[b][rows[sel] - r0]
+                if b in missed:
+                    n_miss_rows += int(sel.sum())
         dt = time.perf_counter() - t0
         self._lat.observe(dt)
         with self._stats_lock:
@@ -177,11 +180,6 @@ class EmbeddingServer:
             self.rows_served += int(ids.size)
             self.misses += n_miss_rows
             self.hits += int(ids.size) - n_miss_rows
-        tracer = self.counters.tracer
-        if tracer.enabled:
-            tracer.complete("serve_lookup", dt, args={
-                "rows": int(ids.size), "missed_blocks": len(missed),
-            })
         return out
 
     def warm(self, node_ids) -> None:

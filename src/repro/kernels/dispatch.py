@@ -51,14 +51,11 @@ Dispatch rules beyond the mode knob (documented in ``kernels/README.md``):
   (contiguous slice-add fast path, sorted ``np.add.reduceat`` segments for
   non-contiguous rows, ``np.add.at`` residual).
 
-Every dispatched call records a per-kernel span (``kernel:<name>.<path>``)
-through ``Counters.record_phase`` — phases land on the exported trace
-timeline but stay out of the stage busy/stall maps, so
-``overlap_summary``'s stage classification is untouched.
+:meth:`KernelDispatch.scatter_add_rows` returns the path it took
+(``"ref"`` or ``"pallas"``); the engine puts it on its ``scatter`` span.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import numpy as np
@@ -110,7 +107,7 @@ class KernelDispatch:
     builders). One instance per engine; jit caches live on the instance so
     retraces are shared across layers."""
 
-    def __init__(self, mode: str = "auto", counters=None):
+    def __init__(self, mode: str = "auto"):
         if mode not in VALID_MODES:
             raise ValueError(
                 f"kernels={mode!r} not in {VALID_MODES}"
@@ -127,7 +124,6 @@ class KernelDispatch:
             # on CPU, and v5e refuses the kernels' row blocks
             mode = "reference"
         self.mode = mode
-        self.counters = counters
         self._jit_fwd = {}
         self._jit_bwd = {}
         self._jit_gather = None
@@ -142,24 +138,19 @@ class KernelDispatch:
         docstring). Deterministic but ~1 ulp off the reference order."""
         return self.mode == "pallas-fused"
 
-    def _span(self, name: str, t0: float) -> None:
-        if self.counters is not None:
-            self.counters.record_phase(
-                f"kernel:{name}", time.perf_counter() - t0
-            )
-
     # ------------------------------------------------------- host scatter
     def scatter_add_rows(
         self, buf: np.ndarray, rows: np.ndarray, values: np.ndarray
-    ) -> None:
-        """In-place ``buf[rows] += values`` — the backward's ∇A write-back.
+    ) -> str:
+        """In-place ``buf[rows] += values`` — the backward's ∇A write-back;
+        returns the path taken, ``"ref"`` or ``"pallas"``.
         Pallas path: deterministic sorted scatter-grad kernel (device round
         trip; unsorted rows are stable-sorted first, so duplicate rows still
         accumulate in their input order). Both paths are bit-identical for
         the engine's sorted-unique row sets."""
         n = rows.size
         if n == 0:
-            return
+            return "ref"
         r0 = int(rows[0])
         contiguous = int(rows[n - 1]) - r0 + 1 == n and (
             n == 1 or bool(np.all(np.diff(rows) == 1))
@@ -168,15 +159,12 @@ class KernelDispatch:
             # contiguous unique run (the loss layer's arange scatter, dense
             # regather runs): a slice add is bit-identical on every path
             # and beats any kernel launch — shape-based dispatch
-            t0 = time.perf_counter()
             scatter_add_rows_ref(buf, rows, values)
-            self._span("scatter_add.ref", t0)
-            return
+            return "ref"
         import jax.numpy as jnp
 
         from repro.kernels.gather_scatter import ops
 
-        t0 = time.perf_counter()
         if rows.size > 1 and not bool(np.all(rows[1:] >= rows[:-1])):
             order = np.argsort(rows, kind="stable")
             rows = rows[order]
@@ -186,7 +174,7 @@ class KernelDispatch:
             jnp.asarray(values), interpret=self.interpret,
         )
         np.copyto(buf, np.asarray(out))
-        self._span("scatter_add.pallas", t0)
+        return "pallas"
 
     # ---------------------------------------------- fused layer functions
     def gather_rows_fn(self):

@@ -5,7 +5,6 @@
 - :mod:`repro.obs.summary` — per-epoch one-line structured summaries
 - :mod:`repro.obs.ledger` — append-only cross-run performance ledger
 - :mod:`repro.obs.live` — live sampler, Prometheus exporter, HTTP endpoint
-- :mod:`repro.obs.attribution` — achieved-vs-peak utilization per stage
 - :mod:`repro.obs.regress` — noise-aware perf-regression sentinel stats
 
 Deliberately dependency-free (stdlib only) and imported by
@@ -13,7 +12,6 @@ Deliberately dependency-free (stdlib only) and imported by
 ``repro.runtime`` at module scope (``live`` reaches
 ``repro.core.threads.spawn`` lazily at thread-start time).
 """
-from repro.obs.attribution import attribution_report, format_attribution
 from repro.obs.ledger import (
     LedgerSchemaError, RunLedger, config_fingerprint, make_record,
 )
@@ -31,5 +29,4 @@ __all__ = [
     "RunLedger", "LedgerSchemaError", "make_record", "config_fingerprint",
     "LiveSampler", "TelemetryServer",
     "to_prometheus_text", "parse_prometheus_text",
-    "attribution_report", "format_attribution",
 ]

@@ -16,9 +16,7 @@ later reader needs to compare runs without re-running them:
   ledger is self-describing, the sentinel carries no per-bench tables;
 - ``counters`` / ``metrics`` — the full :meth:`Counters.snapshot` and
   :meth:`MetricsRegistry.snapshot` dumps, so any number that later turns
-  out to matter is already in the history;
-- ``attribution`` — the achieved-vs-peak utilization report
-  (:mod:`repro.obs.attribution`), when the producer computed one.
+  out to matter is already in the history.
 
 Writes are one ``write()`` of one ``\\n``-terminated line on an append-mode
 handle under a lock — concurrent appenders (two benches, or a bench racing
@@ -45,7 +43,7 @@ LEDGER_SCHEMA_VERSION = 1
 LEDGER_KIND = "repro-run"
 
 #: Fields every record must carry to be appendable. ``counters`` /
-#: ``metrics`` / ``attribution`` / ``watch`` are optional payload.
+#: ``metrics`` / ``watch`` are optional payload.
 REQUIRED_FIELDS = (
     "kind", "schema_version", "run_kind", "fingerprint", "config",
     "written_at", "headline",
@@ -92,7 +90,6 @@ def make_record(
     *,
     counters=None,
     watch: Optional[Dict[str, str]] = None,
-    attribution: Optional[Dict] = None,
     backend: Optional[str] = None,
     extra: Optional[Dict] = None,
 ) -> Dict:
@@ -101,8 +98,7 @@ def make_record(
     ``counters`` (a :class:`repro.core.counters.Counters`) contributes both
     its scalar snapshot and its metrics-registry snapshot; ``watch`` maps
     headline metric names to ``"lower"``/``"higher"`` (which direction is
-    better — consumed by the regression sentinel); ``attribution`` is the
-    achieved-vs-peak report from :mod:`repro.obs.attribution`.
+    better — consumed by the regression sentinel).
     """
     rec = dict(
         kind=LEDGER_KIND,
@@ -122,8 +118,6 @@ def make_record(
             k: _as_jsonable(v) for k, v in counters.snapshot().items()
         }
         rec["metrics"] = counters.metrics.snapshot()
-    if attribution is not None:
-        rec["attribution"] = attribution
     if extra:
         rec.update(extra)
     return rec

@@ -8,14 +8,14 @@ in-memory ring, and :meth:`Tracer.export_chrome_trace` renders the whole
 pipelined epoch as a zoomable timeline in ``ui.perfetto.dev`` (or
 ``chrome://tracing``).
 
-Three recording shapes:
+Recording shapes:
 
 - :meth:`Tracer.span` — a ``with``-scoped span on the current thread
-  (Chrome ``"X"`` complete event);
-- :meth:`Tracer.complete` — an after-the-fact span for code that already
-  timed itself (``Counters.record_busy`` bridges every pipeline stage's
-  busy interval through this, so any stage that reports busy time
-  automatically appears on the timeline);
+  (Chrome ``"X"`` complete event). An enabled tracer also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name and arguments, so the
+  span lands in a running profiler session's host plane on the device
+  trace's clock (a no-op when no session runs). ``Counters.stage`` /
+  ``Counters.wait`` wrap it to also count busy / stall seconds;
 - :meth:`Tracer.begin` / :meth:`Tracer.end` — an async span that may START
   on one thread and END on another (Chrome ``"b"``/``"e"`` events keyed by
   an id): the runtime uses these for per-unit lifetimes, prefetch-start →
@@ -24,12 +24,21 @@ Three recording shapes:
 Plus :meth:`Tracer.instant` (point events, e.g. cache evictions) and
 :meth:`Tracer.counter` (counter tracks, e.g. the host-cache byte timeline).
 
+Units: a span whose arguments carry ``stream`` (the layer pass) and ``seq``
+(the unit's place in it) makes that unit the thread's current one while it
+runs; spans opened inside it without a ``stream`` of their own (a storage
+read under a gather) inherit ``stream``/``seq``/``layer``/``pass``.
+:meth:`Tracer.bind_unit` sets the current unit directly (the compute loop,
+while it consumes a unit; the I/O threads, for a request's submitter).
+
 Hot-path discipline: the ring is a ``deque(maxlen=...)`` — appending drops
 the oldest event instead of growing (``dropped`` counts the evictions) — and
 the DISABLED tracer does no work at all: ``span()`` returns a shared no-op
 singleton (no allocation) and every other recorder early-returns after one
 attribute check (pinned by tests). Components reach the tracer through
 ``Counters.tracer``, which defaults to the module-level :data:`NULL_TRACER`.
+This module stays stdlib-only at import time: ``jax.profiler`` is imported
+when an enabled tracer is built.
 """
 from __future__ import annotations
 
@@ -39,6 +48,10 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+
+# the arguments that name a unit of work; nested spans inherit them
+UNIT_KEYS = ("stream", "seq", "layer", "pass")
 
 
 class _NullSpan:
@@ -54,26 +67,68 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:  # pragma: no cover - jax is a dependency
+        return None
+    return TraceAnnotation
 
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
+
+class _Span:
+    """One live span: a ring event when the block exits, and a profiler
+    annotation while a session runs."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann", "_prev")
+
+    def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
 
     def __enter__(self):
+        tracer = self._tracer
+        local = tracer._local
+        unit = getattr(local, "unit", None)
+        args = self._args
+        if "stream" in args:
+            local.unit = args
+        elif unit is not None:
+            inherited = {k: unit[k] for k in UNIT_KEYS if k in unit}
+            inherited.update(args)
+            args = self._args = inherited
+        self._prev = unit
+        ann = tracer._annotation
+        if ann is not None and ann.is_enabled():   # a profiler session runs
+            self._ann = ann(self._name, **{k: v for k, v in args.items()
+                                            if v is not None})
+            self._ann.__enter__()
+        else:
+            self._ann = None
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **args) -> None:
+        """Add arguments known only inside the block (bytes read)."""
+        self._args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._tracer._emit("X", self._name, self._t0, t1 - self._t0,
-                           self._args)
+        dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer._local.unit = self._prev
+        self._tracer._emit("X", self._name, self._t0, dur,
+                           self._args or None)
         return False
 
 
@@ -92,6 +147,9 @@ class Tracer:
         self._t0 = time.perf_counter()
         self._thread_names: dict = {}   # tid -> name at first event
         self.dropped = 0                # events evicted from the full ring
+        self._local = threading.local()  # .unit: the thread's current unit
+        # every enabled span is mirrored into the JAX profiler
+        self._annotation = _profiler_annotation() if self.enabled else None
 
     # ------------------------------------------------------------- recording
     def _emit(self, ph: str, name: str, t_start: float, dur_s: float = 0.0,
@@ -99,30 +157,42 @@ class Tracer:
         if not self.enabled:
             return
         tid = threading.get_ident()
-        ts = (t_start - self._t0) * 1e6
-        with self._lock:
-            if tid not in self._thread_names:
+        if tid not in self._thread_names:
+            with self._lock:
                 self._thread_names[tid] = threading.current_thread().name
-            if len(self._ring) == self._ring.maxlen:
+        ring = self._ring
+        # the append itself needs no lock (deque appends are atomic); a full
+        # ring evicts one event per append. Exact but for appends racing at
+        # the moment the ring first fills.
+        if len(ring) >= ring.maxlen:
+            with self._lock:
                 self.dropped += 1
-            self._ring.append((ph, name, ts, dur_s * 1e6, tid, args, uid))
+        ring.append((ph, name, t_start, dur_s, tid, args, uid))
 
     def span(self, name: str, **args):
-        """``with tracer.span("gather", part=3):`` — an ``"X"`` span on the
-        current thread, emitted when the block exits."""
+        """``with tracer.span("gather", stream=4, seq=2, part=3) as sp:`` —
+        an ``"X"`` span on the current thread, emitted when the block exits
+        and mirrored into the JAX profiler (arguments that are None left
+        out); ``sp.set(bytes=n)`` adds an argument from inside the block."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, args or None)
+        return _Span(self, name, args)
 
-    def complete(self, name: str, dur_s: float, t_end: Optional[float] = None,
-                 args: Optional[dict] = None) -> None:
-        """Record an already-measured span that ENDED at ``t_end`` (now, if
-        omitted) and lasted ``dur_s`` seconds — the bridge for code that
-        times itself (``Counters.record_busy`` / ``record_phase``)."""
+    def bind_unit(self, unit: Optional[dict]) -> Optional[dict]:
+        """Make ``unit`` (``stream``/``seq``/``layer``/``pass``) the
+        current thread's unit, which spans without their own ``stream``
+        inherit; returns the binding it replaced."""
         if not self.enabled:
-            return
-        t1 = time.perf_counter() if t_end is None else t_end
-        self._emit("X", name, t1 - dur_s, dur_s, args)
+            return None
+        prev = getattr(self._local, "unit", None)
+        self._local.unit = unit
+        return prev
+
+    def current_unit(self) -> Optional[dict]:
+        """The current thread's unit (see :meth:`bind_unit`), or None."""
+        if not self.enabled:
+            return None
+        return getattr(self._local, "unit", None)
 
     def begin(self, name: str, uid, **args) -> None:
         """Open an async span keyed by ``(name, uid)``; :meth:`end` may run
@@ -169,11 +239,11 @@ class Tracer:
     def events(self) -> list:
         """Snapshot of the ring as dicts (test/introspection helper; the
         canonical output is :meth:`export_chrome_trace`)."""
-        with self._lock:
-            ring = list(self._ring)
+        ring = list(self._ring)
+        t0 = self._t0
         return [
-            dict(ph=ph, name=name, ts=ts, dur=dur, tid=tid,
-                 args=args, id=uid)
+            dict(ph=ph, name=name, ts=(ts - t0) * 1e6, dur=dur * 1e6,
+                 tid=tid, args=args, id=uid)
             for ph, name, ts, dur, tid, args, uid in ring
         ]
 
@@ -196,8 +266,8 @@ class Tracer:
         ``sso-h2d``, ``sso-d2h``, ``sso-io``, main) label their tracks.
         """
         pid = os.getpid()
+        ring = self.events()
         with self._lock:
-            ring = list(self._ring)
             tnames = dict(self._thread_names)
             dropped = self.dropped
         evs = [dict(ph="M", name="process_name", pid=pid, tid=0,
@@ -213,11 +283,12 @@ class Tracer:
         for tid in sorted(tnames):
             evs.append(dict(ph="M", name="thread_name", pid=pid, tid=tid,
                             args=dict(name=tnames[tid])))
-        for ph, name, ts, dur, tid, args, uid in ring:
-            ev = dict(ph=ph, name=name, cat="sso", pid=pid, tid=tid,
-                      ts=round(ts, 3))
+        for e in ring:
+            ph, tid, uid, args = e["ph"], e["tid"], e["id"], e["args"]
+            ev = dict(ph=ph, name=e["name"], cat="sso", pid=pid, tid=tid,
+                      ts=round(e["ts"], 3))
             if ph == "X":
-                ev["dur"] = round(dur, 3)
+                ev["dur"] = round(e["dur"], 3)
             elif ph in ("b", "e"):
                 ev["id"] = str(uid)
             elif ph == "i":

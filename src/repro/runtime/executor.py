@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import logging
 import threading
-import time
 import weakref
 from collections import OrderedDict, deque
 from typing import Callable, Iterable, List, Optional
@@ -52,10 +51,20 @@ from repro.core.storage import StorageIOQueue, StorageTier
 from repro.core.threads import join_bounded, spawn
 from repro.runtime.config import PipelineConfig
 from repro.runtime.queues import (
-    DONE, PipelineAbort, ReassemblyBuffer, StageQueue,
+    DONE, NO_UNIT, PipelineAbort, ReassemblyBuffer, StageQueue,
 )
 
 _log = logging.getLogger("repro.runtime")
+
+
+def _host_bytes(obj) -> int:
+    """Bytes of the host arrays in a stage product (an array, a tuple of
+    them such as ``StackedGather``, or None)."""
+    if isinstance(obj, np.ndarray):
+        return int(obj.nbytes)
+    if isinstance(obj, tuple):
+        return sum(_host_bytes(o) for o in obj)
+    return 0
 
 
 class BufferPool:
@@ -300,18 +309,17 @@ class DeviceSlotPool:
         self._cond = threading.Condition()
         self.peak_in_use = 0
 
-    def acquire(self, stall_name: str = "h2d_wait_slot") -> int:
-        t0 = time.perf_counter()
+    def acquire(self, stall_name: str = "h2d_wait_slot",
+                unit: dict = NO_UNIT) -> int:
         with self._cond:
-            while not self._free:
-                if self.abort.is_set():
-                    raise PipelineAbort("device_slots")
-                self._cond.wait(0.02)
+            if not self._free:
+                with self.counters.wait(stall_name, **unit):
+                    while not self._free:
+                        if self.abort.is_set():
+                            raise PipelineAbort("device_slots")
+                        self._cond.wait(0.02)
             slot = self._free.pop()
             self.peak_in_use = max(self.peak_in_use, self.n - len(self._free))
-        stall = time.perf_counter() - t0
-        if stall > 0:
-            self.counters.record_stall(stall_name, stall)
         return slot
 
     def release(self, slot: int) -> None:
@@ -383,9 +391,9 @@ class PipelineExecutor:
         ``np.asarray`` (which completes the ``copy_to_host_async`` the
         caller already started) and the bypass write both run on the retire
         thread, so the compute loop never blocks on the D2H copy. Counted as
-        ``d2h`` stage busy + ``d2h_bytes``. Falls back to a synchronous
-        copy-and-write when ``async_d2h`` is off or the pipeline is
-        disabled."""
+        ``d2h`` stage busy + ``d2h_bytes``, spanned for the caller's current
+        unit. Falls back to a synchronous copy-and-write when ``async_d2h``
+        is off or the pipeline is disabled."""
         if not (self.cfg.enabled and self.cfg.async_d2h):
             arr = np.asarray(dev)
             self.counters.bump("d2h_bytes", arr.nbytes)
@@ -394,7 +402,7 @@ class PipelineExecutor:
         # backpressure: each pending retire holds a device result alive, so
         # bound them like staging slots rather than queueing without limit
         cap = max(2, 2 * int(self.cfg.device_slots))
-        t0 = time.perf_counter()
+        unit = self.counters.tracer.current_unit()
         with self._retire_cond:
             if self._closed:
                 raise RuntimeError("PipelineExecutor is closed")
@@ -402,16 +410,15 @@ class PipelineExecutor:
                 raise self._retire_exc
             if self._retire_thread is None:
                 self._retire_thread = spawn("sso-d2h", self._retire_worker)
-            while self._retire_inflight >= cap:
-                self._retire_cond.wait(0.02)
-                if self._retire_exc is not None:
-                    raise self._retire_exc
-            self._retire_q.append((name, row0, dev))
+            if self._retire_inflight >= cap:
+                with self.counters.wait("d2h_submit"):
+                    while self._retire_inflight >= cap:
+                        self._retire_cond.wait(0.02)
+                        if self._retire_exc is not None:
+                            raise self._retire_exc
+            self._retire_q.append((name, row0, dev, unit))
             self._retire_inflight += 1
             self._retire_cond.notify_all()
-        stall = time.perf_counter() - t0
-        if stall > 0:
-            self.counters.record_stall("d2h_submit", stall)
 
     def _retire_worker(self) -> None:
         while True:
@@ -420,23 +427,19 @@ class PipelineExecutor:
                     if self._closed:
                         return
                     self._retire_cond.wait(0.05)
-                name, row0, dev = self._retire_q.popleft()
-            t0 = time.perf_counter()
+                name, row0, dev, unit = self._retire_q.popleft()
             try:
-                arr = np.asarray(dev)   # completes the async D2H copy
-                self.counters.bump("d2h_bytes", arr.nbytes)
-                self.write_rows(name, row0, arr)
+                with self.counters.stage("d2h", **(unit or NO_UNIT), file=name,
+                                         bytes=int(dev.nbytes)):
+                    arr = np.asarray(dev)   # completes the async D2H copy
+                    self.counters.bump("d2h_bytes", arr.nbytes)
+                    self.write_rows(name, row0, arr)
             except BaseException as e:  # surfaced on the next drain/retire
                 with self._retire_cond:
                     self._retire_exc = e
                     self._retire_inflight -= 1
                     self._retire_cond.notify_all()
                 continue
-            args = None
-            if self.counters.tracer.enabled:
-                args = {"file": name, "bytes": int(arr.nbytes)}
-            self.counters.record_busy("d2h", time.perf_counter() - t0,
-                                      args=args)
             with self._retire_cond:
                 self._retire_inflight -= 1
                 self._retire_cond.notify_all()
@@ -451,11 +454,13 @@ class PipelineExecutor:
 
     def drain_writes(self) -> None:
         """Barrier: all submitted bypass writes are on storage. Called at
-        layer boundaries, before anything reads the freshly written file.
-        Retiring D2H copies are drained first — they feed the write queue."""
-        self._drain_retires()
-        if self._writer is not None:
-            self._writer.drain()
+        layer boundaries, before anything reads the freshly written file
+        (spanned as ``drain`` on the caller's thread). Retiring D2H copies
+        are drained first — they feed the write queue."""
+        with self.counters.tracer.span("drain"):
+            self._drain_retires()
+            if self._writer is not None:
+                self._writer.drain()
 
     # -------------------------------------------------------------- pipeline
     def run_stream(
@@ -472,6 +477,8 @@ class PipelineExecutor:
         wait_stage: str = "compute_wait",
         xfer_wait_stage: str = "compute_wait_xfer",
         xfer_up_stage: str = "xfer_wait_up",
+        layer: Optional[int] = None,
+        pass_name: Optional[str] = None,
     ):
         """Yield ``(item, buf, aux)`` in input order, where
         ``buf, aux = gather_fn(item), aux_fn(item)`` — or, when
@@ -499,6 +506,13 @@ class PipelineExecutor:
         :meth:`Counters.overlap_summary` split forward from backward
         overlap and report the transfer stage's own overlapped fraction.
 
+        Tracing: every stage and wait is spanned for its unit, named by
+        integer ``stream`` (this call) and ``seq`` (the unit's place in
+        ``items``) plus ``layer``, ``pass`` (``pass_name``) and ``part``;
+        the compute loop's wait names the unit it awaits, and while the
+        caller consumes a unit that unit is the compute thread's current
+        one, so the caller's own spans carry it too.
+
         Failure semantics (runtime/README.md): an exception in any worker
         stage sets the shared abort event — every queue/buffer wait is
         abort-aware, so all stages unwind instead of deadlocking — and the
@@ -513,45 +527,62 @@ class PipelineExecutor:
         """
         items = list(items)
         use_xfer = transfer_fn is not None and self.cfg.transfer_stage
-        if not self.cfg.enabled or len(items) <= 1:
-            for it in items:
-                buf = gather_fn(it)
-                aux = aux_fn(it) if aux_fn is not None else None
-                if use_xfer:   # same gating as the pipelined path, so the
-                    # yielded shape never depends on the item count
-                    buf, aux = transfer_fn(it, buf, aux)
-                yield it, buf, aux
-            return
-
         c = self.counters
         tracer = c.tracer
-        # per-unit async spans (prefetch-start -> compute-consumed) need ids
-        # unique across the layer passes of one trace; seq restarts per call
+        # stream ids tell the layer passes of one trace apart (seq restarts
+        # at 0 every call): the per-unit spans and async pairs key on both
         self._stream_seq += 1
         sid = self._stream_seq
+
+        def _unit(seq, it):
+            """The unit's span arguments (none when tracing is off)."""
+            if not tracer.enabled:
+                return NO_UNIT
+            p = getattr(it, "p", None)
+            return {"stream": sid, "seq": seq, "layer": layer,
+                    "pass": pass_name,
+                    "part": int(p) if p is not None else None}
+
+        if not self.cfg.enabled or len(items) <= 1:
+            # serial: the stages run inline on the caller thread, spanned
+            # but not counted as busy (nothing overlaps them)
+            for seq, it in enumerate(items):
+                ua = _unit(seq, it)
+                with tracer.span(gather_stage, **ua):
+                    buf = gather_fn(it)
+                if aux_fn is not None:
+                    with tracer.span(aux_stage, **ua):
+                        aux = aux_fn(it)
+                else:
+                    aux = None
+                if use_xfer:   # same gating as the pipelined path, so the
+                    # yielded shape never depends on the item count
+                    with tracer.span("h2d", **ua):
+                        buf, aux = transfer_fn(it, buf, aux)
+                prev = tracer.bind_unit(ua or None)
+                try:
+                    yield it, buf, aux
+                finally:
+                    tracer.bind_unit(prev)
+            return
+
         nworkers = max(1, int(self.cfg.gather_workers))
         abort = threading.Event()
         q_ready = StageQueue("prefetch_out", self.cfg.capacity, c, abort)
         reasm = ReassemblyBuffer("gather_out", self.cfg.capacity, c, abort)
         errors: List[BaseException] = []
 
-        def _part(it):
-            p = getattr(it, "p", None)
-            return int(p) if p is not None else None
-
         def _prefetch_worker():
             try:
                 for seq, it in enumerate(items):
+                    ua = _unit(seq, it)
                     if tracer.enabled:
-                        tracer.begin(f"unit:{gather_stage}",
-                                     f"{sid}.{seq}", part=_part(it))
+                        tracer.begin(f"unit:{gather_stage}", f"{sid}.{seq}",
+                                     **ua)
                     if prefetch_fn is not None:
-                        t0 = time.perf_counter()
-                        prefetch_fn(it)
-                        dt = time.perf_counter() - t0
-                        args = {"part": _part(it)} if tracer.enabled else None
-                        c.record_busy(prefetch_stage, dt, args=args)
-                    q_ready.put((seq, it))
+                        with c.stage(prefetch_stage, **ua):
+                            prefetch_fn(it)
+                    q_ready.put((seq, it), unit=ua)
                 for _ in range(nworkers):
                     q_ready.put(DONE)
             except PipelineAbort:
@@ -578,20 +609,16 @@ class PipelineExecutor:
                     if x is DONE:
                         return
                     seq, it = x
-                    t0 = time.perf_counter()
-                    buf = gather_fn(it)
+                    ua = _unit(seq, it)
+                    with c.stage(gather_stage, **ua):
+                        buf = gather_fn(it)
                     inhand = (it, buf, None)
-                    dt = time.perf_counter() - t0
-                    args = {"part": _part(it)} if tracer.enabled else None
-                    c.record_busy(gather_stage, dt, args=args)
                     aux = None
                     if aux_fn is not None:
-                        t0 = time.perf_counter()
-                        aux = aux_fn(it)
+                        with c.stage(aux_stage, **ua):
+                            aux = aux_fn(it)
                         inhand = (it, buf, aux)
-                        c.record_busy(aux_stage, time.perf_counter() - t0,
-                                      args=args)
-                    reasm.put(seq, (it, buf, aux))
+                    reasm.put(seq, (it, buf, aux), unit=ua)
                     # ownership handed downstream; drop the stale bindings
                     # too — a retained traceback must not pin a buffer the
                     # pool has since reissued
@@ -619,19 +646,21 @@ class PipelineExecutor:
             def _transfer_worker():
                 inhand = None
                 try:
-                    for seq in range(len(items)):
-                        it, buf, aux = reasm.get(seq, stall_name=xfer_up_stage)
+                    for seq, it in enumerate(items):
+                        ua = _unit(seq, it)
+                        it, buf, aux = reasm.get(seq, stall_name=xfer_up_stage,
+                                                 unit=ua)
                         inhand = (it, buf, aux)
-                        slot = slots.acquire()
-                        t0 = time.perf_counter()
-                        buf, aux = transfer_fn(it, buf, aux)
+                        slot = slots.acquire(unit=ua)
+                        with c.stage("h2d", **ua) as sp:
+                            if tracer.enabled:
+                                sp.set(bytes=_host_bytes(buf)
+                                       + _host_bytes(aux))
+                            buf, aux = transfer_fn(it, buf, aux)
                         # transfer_fn took ownership of the host buffers;
                         # from here the unit is the staged replacement pair
                         inhand = (it, buf, aux)
-                        dt = time.perf_counter() - t0
-                        args = {"part": _part(it)} if tracer.enabled else None
-                        c.record_busy("h2d", dt, args=args)
-                        q_dev.put((it, buf, aux, slot))
+                        q_dev.put((it, buf, aux, slot), unit=ua)
                         inhand = buf = aux = None  # handed downstream
                 except PipelineAbort:
                     pass
@@ -645,15 +674,19 @@ class PipelineExecutor:
 
         for t in threads:
             t.start()
+        prev_unit = tracer.current_unit()
         try:
-            for seq in range(len(items)):
+            for seq, it in enumerate(items):
+                # the compute loop's wait names the unit it awaits
+                ua = _unit(seq, it)
                 if use_xfer:
                     try:
                         it, buf, aux, slot = q_dev.get(
-                            stall_name=xfer_wait_stage
+                            stall_name=xfer_wait_stage, unit=ua, always=True
                         )
                     except PipelineAbort:
                         break
+                    tracer.bind_unit(ua or None)
                     yield it, buf, aux
                     # the unit's device inputs are consumed: free its slot so
                     # the transfer thread can stage the next-but-one unit
@@ -661,15 +694,19 @@ class PipelineExecutor:
                     buf = aux = None  # consumer owns it; drop stale bindings
                 else:
                     try:
-                        it, buf, aux = reasm.get(seq, stall_name=wait_stage)
+                        it, buf, aux = reasm.get(seq, stall_name=wait_stage,
+                                                 unit=ua, always=True)
                     except PipelineAbort:
                         break
+                    tracer.bind_unit(ua or None)
                     yield it, buf, aux
                     buf = aux = None
+                tracer.bind_unit(prev_unit)
                 if tracer.enabled:
                     # unit consumed: close its prefetch->compute span
                     tracer.end(f"unit:{gather_stage}", f"{sid}.{seq}")
         finally:
+            tracer.bind_unit(prev_unit)
             abort.set()
             join_bounded(threads, self.cfg.thread_join_timeout_s, c,
                          what="pipeline stage thread")

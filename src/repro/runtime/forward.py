@@ -14,7 +14,7 @@ Two drivers share it:
 
 - ``SSOEngine`` (training): runs every layer through :meth:`run_layer` and
   hooks ``after_compute`` in snapshot mode to persist ``GA_p^{l-1}``; the
-  backward's regather reuses :meth:`gather_padded`/:meth:`prefetch_unit`
+  backward's regather reuses :meth:`gather`/:meth:`prefetch_unit`
   (same cache keys, same pin protocol).
 - ``OffloadedInference`` (serving): forward-only, so it adds the
   inference-only wins on top — per-layer storage truncation (layer ``l-1``'s
@@ -29,7 +29,6 @@ cache, whose entries are raw storage blocks); compute always happens in
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Callable, Dict, NamedTuple, Optional
 
@@ -38,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cache import HostCache
-from repro.core.counters import Counters, PhaseTimer
+from repro.core.counters import Counters
 from repro.core.plan import PartitionPlan, WorkUnit
 from repro.core.storage import StorageTier
 from repro.kernels.dispatch import KernelDispatch
@@ -129,7 +128,7 @@ class ForwardRunner:
         self.kernels = (
             kernels
             if kernels is not None
-            else KernelDispatch(pipeline.kernels, counters)
+            else KernelDispatch(pipeline.kernels)
         )
         # (layer, p) -> keys the prefetch stage actually pinned for that
         # unit; the gather stage pops and releases exactly these (prefetch
@@ -201,10 +200,6 @@ class ForwardRunner:
             "host_gather_bytes", u.n_req * d * self.dtype.itemsize
         )
         return buf
-
-    def gather_padded(self, layer: int, u: WorkUnit, phase: str) -> np.ndarray:
-        with PhaseTimer(self.counters, phase):
-            return self.gather(layer, u, u.r_pad)
 
     # ------------------------------------------------- stacked gather (Pallas)
     def _unit_idx(self, u: WorkUnit):
@@ -278,12 +273,6 @@ class ForwardRunner:
             "host_gather_bytes", total * d * self.dtype.itemsize
         )
         return StackedGather(buf, idx)
-
-    def stacked_gather_timed(
-        self, layer: int, u: WorkUnit, phase: str
-    ) -> StackedGather:
-        with PhaseTimer(self.counters, phase):
-            return self.stacked_gather(layer, u)
 
     def prefetch_unit(self, layer: int, u: WorkUnit) -> None:
         """Stage-1: make (and keep) the unit's source partitions resident.
@@ -463,46 +452,42 @@ class ForwardRunner:
         # exactly the copy the fused path eliminates — so it stays on the
         # reference host gather (a documented dispatch rule).
         use_stacked = self.kernels.use_pallas and not keep_host
-        t_layer = time.perf_counter()
         name_out = out_name if out_name is not None else self.act_name(l + 1)
         cast = self.store_dtype != self.dtype
         if use_stacked:
             fwd = self.kernels.fused_forward_fn(self.spec, activate)
-            gather_fn = lambda u, _l=l: self.stacked_gather_timed(
-                _l, u, "gather"
-            )
+            gather_fn = lambda u, _l=l: self.stacked_gather(_l, u)
             transfer_fn = self._make_stacked_transfer_fn()
         else:
             fwd = self.fwd_fn(activate)
-            gather_fn = lambda u, _l=l: self.gather_padded(_l, u, "gather")
+            gather_fn = lambda u, _l=l: self.gather(_l, u, u.r_pad)
             transfer_fn = self._make_transfer_fn(keep_host)
         units = [self.plan.unit(p) for p in self.plan.schedule]
         prefetch_fn = (
             (lambda u, _l=l: self.prefetch_unit(_l, u))
             if self.pipeline.enabled else None
         )
-        try:
-            self._run_layer_stream(
-                l, params_l, fwd, activate, after_compute, name_out, cast,
-                units, gather_fn, prefetch_fn, transfer_fn, use_xfer,
-                use_stacked, keep_host,
-            )
-        except BaseException:
-            # faulted epoch: pins taken by prefetches whose gather never ran
-            # must not outlive the stream (HostCache pins return to zero —
-            # the deadlock regression suite's contract)
-            self.release_pins()
-            raise
-        # barrier: the next layer reads name_out — all writes must be down
-        # (drain_writes retires pending D2H copies first)
-        rt.drain_writes()
-        # the output layer was just rewritten: cached blocks of it (loaded
-        # by a previous epoch's gathers) are stale — drop before any reader
-        self.cache.drop_layer(self.act_kind, l + 1, flush=False)
-        tracer = self.counters.tracer
-        if tracer.enabled:
-            tracer.complete("fwd_layer", time.perf_counter() - t_layer,
-                            args={"layer": l, "units": len(units)})
+        with self.counters.tracer.span("fwd_layer", layer=l,
+                                       units=len(units)):
+            try:
+                self._run_layer_stream(
+                    l, params_l, fwd, activate, after_compute, name_out, cast,
+                    units, gather_fn, prefetch_fn, transfer_fn, use_xfer,
+                    use_stacked, keep_host,
+                )
+            except BaseException:
+                # faulted epoch: pins taken by prefetches whose gather never
+                # ran must not outlive the stream (HostCache pins return to
+                # zero — the deadlock regression suite's contract)
+                self.release_pins()
+                raise
+            # barrier: the next layer reads name_out — all writes must be
+            # down (drain_writes retires pending D2H copies first)
+            rt.drain_writes()
+            # the output layer was just rewritten: cached blocks of it
+            # (loaded by a previous epoch's gathers) are stale — drop before
+            # any reader
+            self.cache.drop_layer(self.act_kind, l + 1, flush=False)
 
     def _run_layer_stream(
         self, l, params_l, fwd, activate, after_compute, name_out, cast,
@@ -510,6 +495,7 @@ class ForwardRunner:
         keep_host,
     ) -> None:
         rt = self._rt
+        tracer = self.counters.tracer
         for u, ga, _ in rt.run_stream(
             units, gather_fn, prefetch_fn,
             transfer_fn=transfer_fn if use_xfer else None,
@@ -517,42 +503,43 @@ class ForwardRunner:
             wait_stage="compute_wait_fwd",
             xfer_wait_stage="compute_wait_xfer_fwd",
             xfer_up_stage="xfer_wait_up_fwd",
+            layer=l, pass_name="fwd",
         ):
-            with PhaseTimer(self.counters, "compute_fwd"):
-                if use_stacked:
-                    ga_host = None
-                    if use_xfer:
-                        stack_dev, idx_dev = ga
-                        stack_host = None
-                    else:
-                        stack_host = ga.stack
-                        # aligned pool buffer: asarray aliases; safe because
-                        # the serial path blocks on out before releasing
-                        stack_dev = jnp.asarray(stack_host)
-                        idx_dev = self.idx_dev(u)
-                        self.counters.bump("h2d_bytes", stack_host.nbytes)
-                    out = fwd(params_l, stack_dev, idx_dev, u.topo)
-                elif use_xfer:
-                    ga_dev, ga_host = ga
-                    out = fwd(params_l, ga_dev, u.topo)
+            if use_stacked:
+                ga_host = None
+                if use_xfer:
+                    stack_dev, idx_dev = ga
+                    stack_host = None
                 else:
-                    ga_host = ga
-                    ga_dev = jnp.asarray(ga)
-                    self.counters.bump("h2d_bytes", ga.nbytes)
-                    out = fwd(params_l, ga_dev, u.topo)
-                out_dst = out[: u.n_dst]
-                if use_xfer and self.pipeline.async_d2h and not cast:
-                    # start the D2H copy now; the retire thread runs the
-                    # deferred np.asarray + bypass write
-                    out_dst.copy_to_host_async()
-                    out_np = None
-                else:
+                    stack_host = ga.stack
+                    # aligned pool buffer: asarray aliases; safe because
+                    # the serial path blocks on out before releasing
+                    stack_dev = jnp.asarray(stack_host)
+                    idx_dev = self.idx_dev(u)
+                    self.counters.bump("h2d_bytes", stack_host.nbytes)
+                out = fwd(params_l, stack_dev, idx_dev, u.topo)
+            elif use_xfer:
+                ga_dev, ga_host = ga
+                out = fwd(params_l, ga_dev, u.topo)
+            else:
+                ga_host = ga
+                ga_dev = jnp.asarray(ga)
+                self.counters.bump("h2d_bytes", ga.nbytes)
+                out = fwd(params_l, ga_dev, u.topo)
+            out_dst = out[: u.n_dst]
+            if use_xfer and self.pipeline.async_d2h and not cast:
+                # start the D2H copy now; the retire thread runs the
+                # deferred np.asarray + bypass write
+                out_dst.copy_to_host_async()
+                out_np = None
+            else:
+                with tracer.span("d2h_wait"):
                     out_np = np.asarray(out_dst)
-                    self.counters.bump("d2h_bytes", out_np.nbytes)
-                    if cast:
-                        # reduced-precision storage: downcast before the
-                        # bypass write (out_np is freshly owned)
-                        out_np = out_np.astype(self.store_dtype)
+                self.counters.bump("d2h_bytes", out_np.nbytes)
+                if cast:
+                    # reduced-precision storage: downcast before the
+                    # bypass write (out_np is freshly owned)
+                    out_np = out_np.astype(self.store_dtype)
             if after_compute is not None:
                 after_compute(u, ga_host)
             if use_stacked and not use_xfer and stack_host is not None:
@@ -563,7 +550,7 @@ class ForwardRunner:
                 # the transfer thread recycled the host buffer already
                 # unless it was told to keep it for after_compute
                 rt.pool.release(ga_host)
-            with PhaseTimer(self.counters, "bypass_write"):
+            with tracer.span("write_submit"):
                 # bypass: output activations go straight to storage
                 # (write-behind when pipelined; out_np is freshly owned)
                 if out_np is None:

@@ -1,10 +1,14 @@
 """Bounded stage queues with stall accounting for the pipeline runtime.
 
 Each queue sits between two pipeline stages. ``put``/``get`` block when the
-queue is full/empty — that blocked time IS the pipeline's stall signal, so
-both are timed and charged to the owning :class:`~repro.core.counters.Counters`
-under ``<name>.put`` / ``<name>.get`` (the executor maps the main loop's
-``get`` onto the ``compute_wait`` stall instead).
+queue is full/empty — that blocked time IS the pipeline's stall signal, so a
+``put``/``get`` that has to block does so inside
+:meth:`~repro.core.counters.Counters.wait` of the owning counters, under
+``<name>.put`` / ``<name>.get`` (the executor maps the main loop's ``get``
+onto the ``compute_wait`` stall instead); one that need not block records
+nothing, except a ``get(..., always=True)`` (the compute loop's wait for its
+next unit, which the idle split keys on). The ``unit`` argument (span
+arguments) names the unit on the wait's span.
 
 An abort event (set when any stage raises, or when the consumer abandons the
 stream) wakes every blocked producer/consumer so a failing pipeline tears
@@ -14,12 +18,12 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Optional
 
 from repro.core.counters import Counters
 
 DONE = object()  # end-of-stream sentinel flowing through every stage
+NO_UNIT: dict = {}  # span arguments of a wait with no unit (never mutated)
 
 
 class PipelineAbort(Exception):
@@ -56,34 +60,34 @@ class ReassemblyBuffer:
         self._next = 0
         self._cond = threading.Condition()
 
-    def put(self, seq: int, value, stall_name: Optional[str] = None) -> None:
-        t0 = time.perf_counter()
+    def put(self, seq: int, value, stall_name: Optional[str] = None,
+            unit: dict = NO_UNIT) -> None:
         with self._cond:
-            while seq - self._next >= self._cap:
-                if self.abort.is_set():
-                    raise PipelineAbort(self.name)
-                self._cond.wait(0.02)
+            if seq - self._next >= self._cap:
+                with self.counters.wait(stall_name or f"{self.name}.put",
+                                        **unit):
+                    while seq - self._next >= self._cap:
+                        if self.abort.is_set():
+                            raise PipelineAbort(self.name)
+                        self._cond.wait(0.02)
             if self.abort.is_set():
                 raise PipelineAbort(self.name)
             self._slots[seq] = value
             self._cond.notify_all()
-        stall = time.perf_counter() - t0
-        if stall > 0:
-            self.counters.record_stall(stall_name or f"{self.name}.put", stall)
 
-    def get(self, seq: int, stall_name: Optional[str] = None):
-        t0 = time.perf_counter()
+    def get(self, seq: int, stall_name: Optional[str] = None,
+            unit: dict = NO_UNIT, always: bool = False):
         with self._cond:
-            while seq not in self._slots:
-                if self.abort.is_set():
-                    raise PipelineAbort(self.name)
-                self._cond.wait(0.02)
+            if always or seq not in self._slots:
+                with self.counters.wait(stall_name or f"{self.name}.get",
+                                        **unit):
+                    while seq not in self._slots:
+                        if self.abort.is_set():
+                            raise PipelineAbort(self.name)
+                        self._cond.wait(0.02)
             value = self._slots.pop(seq)
             self._next = seq + 1
             self._cond.notify_all()
-        stall = time.perf_counter() - t0
-        if stall > 0:
-            self.counters.record_stall(stall_name or f"{self.name}.get", stall)
         return value
 
     def drain_remaining(self) -> list:
@@ -110,34 +114,39 @@ class StageQueue:
         self.abort = abort
         self._q: queue.Queue = queue.Queue(maxsize=max(1, capacity))
 
-    def put(self, item, stall_name: Optional[str] = None) -> None:
-        t0 = time.perf_counter()
-        while True:
-            if self.abort.is_set():
-                raise PipelineAbort(self.name)
-            try:
-                self._q.put(item, timeout=0.02)
-                break
-            except queue.Full:
-                continue
-        stall = time.perf_counter() - t0
-        if stall > 0:
-            self.counters.record_stall(stall_name or f"{self.name}.put", stall)
-
-    def get(self, stall_name: Optional[str] = None):
-        t0 = time.perf_counter()
-        while True:
-            try:
-                item = self._q.get(timeout=0.02)
-                break
-            except queue.Empty:
+    def put(self, item, stall_name: Optional[str] = None,
+            unit: dict = NO_UNIT) -> None:
+        if self.abort.is_set():
+            raise PipelineAbort(self.name)
+        try:
+            self._q.put_nowait(item)
+            return
+        except queue.Full:
+            pass
+        with self.counters.wait(stall_name or f"{self.name}.put", **unit):
+            while True:
                 if self.abort.is_set():
                     raise PipelineAbort(self.name)
-                continue
-        stall = time.perf_counter() - t0
-        if stall > 0:
-            self.counters.record_stall(stall_name or f"{self.name}.get", stall)
-        return item
+                try:
+                    self._q.put(item, timeout=0.02)
+                    return
+                except queue.Full:
+                    continue
+
+    def get(self, stall_name: Optional[str] = None, unit: dict = NO_UNIT,
+            always: bool = False):
+        if not always:
+            try:
+                return self._q.get_nowait()
+            except queue.Empty:
+                pass
+        with self.counters.wait(stall_name or f"{self.name}.get", **unit):
+            while True:
+                try:
+                    return self._q.get(timeout=0.02)
+                except queue.Empty:
+                    if self.abort.is_set():
+                        raise PipelineAbort(self.name)
 
     def drain_remaining(self) -> list:
         """Teardown-only: pop everything still queued (sentinels excluded)."""

@@ -97,15 +97,38 @@ def test_overlap_summary_never_negative_and_frac_capped():
 # --------------------------------------------------------------- snapshot lock
 def test_snapshot_contains_flattened_maps():
     c = Counters()
-    c.record_phase("fwd", 1.0)
+    with c.stage("h2d"):
+        pass
     c.record_busy("gather", 2.0)
     c.record_stall("compute_wait_fwd", 0.5)
     c.bump("storage_read_bytes", 123)
     snap = c.snapshot()
-    assert snap["t_fwd"] == 1.0
+    # the busy/stall maps are the only flattened timings (no phase map)
+    assert snap["busy_h2d"] >= 0.0
+    assert not any(k.startswith("t_") for k in snap)
     assert snap["busy_gather"] == 2.0
     assert snap["stall_compute_wait_fwd"] == 0.5
     assert snap["storage_read_bytes"] == 123
+
+
+def test_stage_and_wait_count_a_block_that_raises():
+    """A faulted stage still counts its seconds and closes its span: the
+    unwind of a failed epoch shows where the time went."""
+    from repro.obs import Tracer
+
+    c = Counters()
+    c.tracer = Tracer()
+    with pytest.raises(RuntimeError):
+        with c.stage("gather", stream=1, seq=0):
+            raise RuntimeError("storage fault")
+    with pytest.raises(RuntimeError):
+        with c.wait("compute_wait_fwd", stream=1, seq=0):
+            raise RuntimeError("abort")
+    assert c.stage_busy_seconds["gather"] >= 0.0
+    assert "compute_wait_fwd" in c.stage_stall_seconds
+    assert [e["name"] for e in c.tracer.events()] == [
+        "gather", "stall:compute_wait_fwd"]
+    assert c.tracer.current_unit() is None
 
 
 def test_bump_is_atomic_under_contention():

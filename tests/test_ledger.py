@@ -1,4 +1,4 @@
-"""Run ledger + bandwidth attribution + regression sentinel tests.
+"""Run ledger + regression sentinel tests.
 
 Load-bearing properties:
 
@@ -8,10 +8,6 @@ Load-bearing properties:
   never appended — the ledger cannot accumulate unattributable lines;
 - two threads appending concurrently interleave whole lines, never torn
   ones (every line parses and validates afterwards);
-- attribution math: known bytes over known busy time against a known peak
-  produces the expected utilization, denominator preference is
-  measured-service > stage-busy > wall (recorded in ``basis``), and the
-  limiting stage is the one with the largest modeled time;
 - sentinel statistics: a 30% step regression on a quiet baseline is caught
   immediately, 200 seeded gaussian-noise trials produce ZERO false
   positives at the default band, and fewer than ``min_samples`` baselines
@@ -25,12 +21,10 @@ import os
 import subprocess
 import sys
 import threading
-import types
 
 import numpy as np
 import pytest
 
-from repro.obs.attribution import attribution_report, format_attribution
 from repro.obs.ledger import (
     LEDGER_KIND, LEDGER_SCHEMA_VERSION, LedgerSchemaError, RunLedger,
     config_fingerprint, make_record, resolve_path, validate_record,
@@ -160,69 +154,6 @@ def test_two_thread_append_no_torn_lines(tmp_path):
     # every (tid, i) pair landed exactly once
     seen = {(r["headline"]["tid"], r["headline"]["i"]) for r in recs}
     assert len(seen) == 2 * n_per_thread
-
-
-# -------------------------------------------------------------- attribution
-def _bw(ssd=1e9, host_mem=10e9, host_link=5e9, peak_flops=1e12):
-    return types.SimpleNamespace(ssd=ssd, host_mem=host_mem,
-                                 host_link=host_link, peak_flops=peak_flops)
-
-
-def test_attribution_known_utilization_stage_busy_basis():
-    snap = {"storage_read_paged_bytes": 1e9, "busy_prefetch": 2.0}
-    rep = attribution_report(snap, _bw(ssd=1e9), wall_s=4.0)
-    sr = rep["stages"]["storage_read"]
-    assert sr["basis"] == "stage_busy_s"
-    assert sr["achieved_bps"] == pytest.approx(0.5e9)   # 1GB over 2s busy
-    assert sr["utilization"] == pytest.approx(0.5)
-    assert rep["modeled_s"]["storage_read"] == pytest.approx(1.0)
-    assert rep["limiting_stage"] == "storage_read"
-
-
-def test_attribution_prefers_measured_service_time():
-    snap = {"storage_read_paged_bytes": 1e9, "busy_prefetch": 2.0}
-    metrics = {"storage.read_seconds": {"sum": 1.0, "count": 16}}
-    rep = attribution_report(snap, _bw(ssd=1e9), wall_s=4.0, metrics=metrics)
-    sr = rep["stages"]["storage_read"]
-    assert sr["basis"] == "measured_service_s"
-    assert sr["achieved_bps"] == pytest.approx(1e9)
-    assert sr["utilization"] == pytest.approx(1.0)
-
-
-def test_attribution_falls_back_to_wall_and_picks_limiting_stage():
-    snap = {
-        "h2d_bytes": 4e9, "d2h_bytes": 1e9,       # 5GB over 5GB/s -> 1.0s
-        "host_gather_bytes": 1e9,                 # 1GB over 10GB/s -> 0.1s
-    }
-    rep = attribution_report(snap, _bw(), wall_s=2.0, flops=1e11)
-    dl = rep["stages"]["device_link"]
-    assert dl["basis"] == "wall_s"                # no busy counters present
-    assert dl["achieved_bps"] == pytest.approx(5e9 / 2.0)
-    assert rep["modeled_s"]["device_link"] == pytest.approx(1.0)
-    assert rep["modeled_s"]["compute"] == pytest.approx(0.1)
-    assert rep["limiting_stage"] == "device_link"
-    # compute stage reports FLOP/s against peak
-    comp = rep["stages"]["compute"]
-    assert comp["achieved_flops"] == pytest.approx(5e10)
-    assert comp["utilization"] == pytest.approx(0.05)
-
-
-def test_attribution_degenerate_inputs_zeroed_not_raised():
-    rep = attribution_report({}, _bw(), wall_s=0.0)
-    assert rep["limiting_stage"] is None
-    for s in rep["stages"].values():
-        assert s["utilization"] == 0.0
-    text = format_attribution(rep)
-    assert "attribution.limiting_stage,0,None" in text
-    assert "attribution.storage_read" in text
-
-
-def test_attribution_format_lines_parse_as_csv():
-    snap = {"storage_read_paged_bytes": 1e9, "busy_prefetch": 2.0}
-    text = format_attribution(attribution_report(snap, _bw(), wall_s=4.0))
-    for line in text.splitlines():
-        assert line.startswith("attribution.")
-        assert len(line.split(",")) == 3
 
 
 # --------------------------------------------------------- sentinel: series

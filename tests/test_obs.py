@@ -11,7 +11,12 @@ Load-bearing properties:
   exact, bimodal quantiles within the ±20% consistency budget, p50 <= p99;
 - a pipelined training epoch run with ``PipelineConfig(trace=...)`` exports
   a timeline containing >= 1 complete span for EVERY stage that reported
-  nonzero ``stage_busy_seconds`` (the record_busy -> tracer bridge);
+  nonzero ``stage_busy_seconds`` (``Counters.stage`` counts and spans in
+  one place); under ``jax.profiler`` every stage span also lands in the
+  ``/host:CPU`` plane with integer ``stream``/``seq`` of its unit, and each
+  unit's prefetch, gather, H2D and compute wait end in that order;
+- each stage is recorded once per unit, and no span times an asynchronous
+  dispatch (``compute_fwd``/``compute_bwd``/``kernel:*`` are gone);
 - ``EmbeddingServer.stats()`` p50/p99 from the shared histogram agree with
   externally-timed ``np.percentile`` numbers within ±20% (the sliding
   window it replaced);
@@ -24,8 +29,10 @@ Load-bearing properties:
   ``trace.ring_occupancy`` gauges track a live tracer, and the exported
   timeline self-describes truncation via the ``trace_ring`` metadata event.
 """
+import glob
 import json
 import tempfile
+import tracemalloc
 import threading
 import time
 import types
@@ -34,7 +41,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import Counters, HostCache, SSOEngine, StorageTier, build_plan
+from repro.core import (
+    Counters, HostCache, SSOEngine, StorageIOQueue, StorageTier, build_plan,
+)
 from repro.graph import (
     gcn_norm_coeffs, kronecker_graph, switching_aware_partition,
 )
@@ -55,6 +64,13 @@ def _export(tracer, tmp_path, name="trace.json"):
     tracer.export_chrome_trace(path)
     with open(path) as f:
         return json.load(f)
+
+
+def _spans(tracer, names, seconds=0.0):
+    for name in names:
+        with tracer.span(name):
+            if seconds:
+                time.sleep(seconds)
 
 
 def _assert_event_schema(ev):
@@ -89,15 +105,18 @@ def test_span_nesting_records_inner_before_outer(tmp_path):
 
 
 def test_complete_backdates_span_start():
+    # a span is emitted when its block exits, dated back to its start
     tr = Tracer()
     time.sleep(0.002)
-    tr.complete("gather", 0.001, args={"part": 3})
+    with tr.span("gather", part=3) as sp:
+        time.sleep(0.001)
+        sp.set(bytes=64)
     (ev,) = tr.events()
     assert ev["ph"] == "X"
-    assert ev["dur"] == pytest.approx(1000.0)   # 0.001s in µs
-    assert ev["args"] == {"part": 3}
-    # span ends "now" and is backdated by dur: start still after creation
-    assert 0.0 <= ev["ts"] <= (time.perf_counter() - tr._t0) * 1e6
+    assert ev["dur"] >= 1000.0                  # >= 0.001s, in µs
+    assert ev["args"] == {"part": 3, "bytes": 64}
+    assert 2000.0 <= ev["ts"]                   # started after the sleep
+    assert ev["ts"] + ev["dur"] <= (time.perf_counter() - tr._t0) * 1e6
 
 
 def test_cross_thread_begin_end_share_id(tmp_path):
@@ -126,8 +145,7 @@ def test_cross_thread_begin_end_share_id(tmp_path):
 
 def test_per_thread_span_ends_are_monotone(tmp_path):
     tr = Tracer()
-    for i in range(20):
-        tr.complete(f"s{i}", 0.0005)
+    _spans(tr, [f"s{i}" for i in range(20)], seconds=0.0005)
     doc = _export(tr, tmp_path)
     ends = {}
     for ev in doc["traceEvents"]:
@@ -151,8 +169,7 @@ def test_instant_and_counter_events():
 
 def test_ring_bound_drops_oldest_and_counts():
     tr = Tracer(ring_events=8)
-    for i in range(20):
-        tr.complete(f"e{i}", 0.0)
+    _spans(tr, [f"e{i}" for i in range(20)])
     assert tr.events_recorded == 8
     assert tr.dropped == 12
     assert [e["name"] for e in tr.events()] == [f"e{i}" for i in range(12, 20)]
@@ -162,8 +179,7 @@ def test_ring_bound_drops_oldest_and_counts():
 
 def test_export_payload_shape(tmp_path):
     tr = Tracer(ring_events=4)
-    for i in range(9):
-        tr.complete(f"e{i}", 0.001)
+    _spans(tr, [f"e{i}" for i in range(9)])
     doc = _export(tr, tmp_path)
     assert doc["displayTimeUnit"] == "ms"
     assert doc["otherData"]["dropped_events"] == 5
@@ -179,7 +195,9 @@ def test_disabled_tracer_is_inert():
     assert s1 is s2 is NULL_SPAN  # shared singleton: no per-call allocation
     with s1:
         pass
-    tr.complete("x", 1.0)
+    s1.set(bytes=1)
+    assert tr.bind_unit({"stream": 1, "seq": 0}) is None
+    assert tr.current_unit() is None
     tr.begin("y", 1)
     tr.end("y", 1)
     tr.instant("z")
@@ -190,28 +208,149 @@ def test_disabled_tracer_is_inert():
 def test_counters_default_tracer_disabled_and_cheap():
     c = Counters()
     assert c.tracer is NULL_TRACER
-    c.record_busy("gather", 0.1)
-    c.record_stall("compute_wait_fwd", 0.1)
+    with c.stage("gather", part=1):
+        pass
+    with c.wait("compute_wait_fwd"):
+        pass
     assert c.tracer.events_recorded == 0
-    # overhead pin: the disabled bridge is one attribute check + return;
-    # generous bound so loaded CI boxes don't flake (~20ns/call typical)
+    assert set(c.stage_busy_seconds) == {"gather"}
+    assert set(c.stage_stall_seconds) == {"compute_wait_fwd"}
+    # overhead pin: a disabled stage is two clock reads and a locked add;
+    # generous bound so loaded CI boxes don't flake (~1us/call typical)
     n = 50_000
     t0 = time.perf_counter()
     for _ in range(n):
-        NULL_TRACER.complete("gather", 0.1)
+        with c.stage("gather"):
+            pass
     assert (time.perf_counter() - t0) / n < 20e-6
 
 
 def test_record_busy_bridges_to_live_tracer():
+    # stage/wait count into today's busy/stall names AND span the block;
+    # record_busy/record_stall only add seconds measured elsewhere
     c = Counters()
     c.tracer = Tracer()
-    c.record_busy("gather", 0.01, args={"part": 1})
-    c.record_phase("fwd", 0.02)
-    c.record_stall("h2d.put", 1e-6)    # below the 50us trace floor
-    c.record_stall("compute_wait_fwd", 0.005)
+    with c.stage("gather", stream=2, seq=5, part=1):
+        time.sleep(0.001)
+    with c.wait("compute_wait_fwd", stream=2, seq=6):
+        time.sleep(0.001)
+    c.record_busy("h2d", 0.25)
+    c.record_stall("h2d.put", 1e-6)
     names = [e["name"] for e in c.tracer.events()]
-    assert names == ["gather", "fwd", "stall:compute_wait_fwd"]
+    assert names == ["gather", "stall:compute_wait_fwd"]
+    g, w = c.tracer.events()
+    assert g["args"] == {"stream": 2, "seq": 5, "part": 1}
+    assert w["args"] == {"stream": 2, "seq": 6}
+    assert c.stage_busy_seconds["gather"] >= 0.001
+    assert c.stage_busy_seconds["h2d"] == pytest.approx(0.25)
+    assert c.stage_stall_seconds["compute_wait_fwd"] >= 0.001
     assert c.stage_stall_seconds["h2d.put"] == pytest.approx(1e-6)
+    snap = c.snapshot()
+    assert snap["busy_gather"] == c.stage_busy_seconds["gather"]
+    assert snap["stall_compute_wait_fwd"] >= 0.001
+
+
+def test_disabled_stage_retains_nothing_and_records_nothing():
+    c = Counters()
+    with c.stage("gather", stream=1, seq=2) as sp:
+        assert sp is NULL_SPAN                 # the shared no-op span
+    with c.wait("x", stream=1) as sp:
+        assert sp is NULL_SPAN
+    for i in range(200):                   # warm every code path first
+        with c.stage("gather", stream=1, seq=i):
+            pass
+        with c.wait("compute_wait_fwd", stream=1, seq=i):
+            pass
+    tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        for i in range(10_000):
+            with c.stage("gather", stream=1, seq=i):
+                pass
+            with c.wait("compute_wait_fwd", stream=1, seq=i):
+                pass
+        after = tracemalloc.get_traced_memory()[0]
+    finally:
+        tracemalloc.stop()
+    assert after - before < 1024           # nothing retained per call
+    assert NULL_TRACER.events_recorded == 0 and NULL_TRACER.dropped == 0
+    assert c.stage_busy_seconds["gather"] > 0.0
+
+
+def _host_events(prof_dir):
+    """``(name, start_ns, end_ns, stats)`` of every event on the profile's
+    ``/host:CPU`` plane."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{prof_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out += [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+                    for e in line.events]
+    return out
+
+
+def _profile(prof_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(prof_dir), profiler_options=opts)
+
+
+def test_stage_span_mirrors_into_profiler(tmp_path):
+    c = Counters()
+    c.tracer = Tracer()
+    _profile(tmp_path)
+    try:
+        with c.stage("gather", stream=3, seq=7, layer=0, part=1,
+                     **{"pass": None}):
+            with c.tracer.span("storage_read", file="act0") as sp:
+                sp.set(bytes=4096)
+        with c.wait("compute_wait_xfer_fwd", stream=3, seq=7):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    evs = {n: st for n, _, _, st in _host_events(tmp_path)}
+    # a None argument is left out of the profiler's stats
+    assert evs["gather"] == {"stream": 3, "seq": 7, "layer": 0, "part": 1}
+    # the nested read inherits the unit; integers stay integers
+    assert evs["storage_read"] == {"stream": 3, "seq": 7, "layer": 0,
+                                   "file": "act0", "bytes": 4096}
+    assert evs["stall:compute_wait_xfer_fwd"] == {"stream": 3, "seq": 7}
+    ring = {e["name"]: e["args"] for e in c.tracer.events()}
+    assert ring["storage_read"] == {**evs["storage_read"], "pass": None}
+    # the unit binding ends with the span that set it
+    assert c.tracer.current_unit() is None
+
+
+def test_io_queue_spans_carry_the_submitters_unit(tmp_path):
+    c = Counters()
+    c.tracer = Tracer()
+    st_ = StorageTier(str(tmp_path), counters=c)
+    st_.alloc("a", (8, 4), np.float32)
+    q = StorageIOQueue(st_, counters=c)
+    try:
+        with c.stage("grad_fetch", stream=5, seq=3, layer=1, part=2):
+            q.submit_write("a", 0, np.ones((8, 4), np.float32)).result()
+            q.submit_read("a", 0, 8).result()
+        q.drain()
+    finally:
+        q.close()
+        st_.close()
+    evs = c.tracer.events()
+    unit = {"stream": 5, "seq": 3, "layer": 1}
+    io = [e for e in evs if e["name"] in ("write_behind", "async_read",
+                                          "storage_read")]
+    assert {e["name"] for e in io} == {"write_behind", "async_read",
+                                      "storage_read"}
+    for e in io:
+        assert {k: e["args"][k] for k in unit} == unit, e
+    (rd,) = [e for e in evs if e["name"] == "storage_read"]
+    (ar,) = [e for e in evs if e["name"] == "async_read"]
+    assert rd["tid"] == ar["tid"] and rd["args"]["bytes"] == 8 * 4 * 4
+    assert c.stage_busy_seconds["async_read"] > 0.0
 
 
 # ------------------------------------------------------------------ histograms
@@ -334,10 +473,50 @@ def test_pipelined_epoch_trace_covers_every_busy_stage(tmp_path):
     eng = SSOEngine(spec, plan, dims, st_, cache, c, mode="regather",
                     pipeline=PipelineConfig(depth=2, trace=trace))
     eng.initialize(Xr)
-    eng.run_epoch(params, Yr)
+    prof = tmp_path / "profile"
+    _profile(prof)
+    try:
+        eng.run_epoch(params, Yr)
+    finally:
+        jax.profiler.stop_trace()
     busy = dict(c.stage_busy_seconds)
     eng.close()       # exports the trace
     st_.close()
+
+    # every stage span is in the profiler's host plane, on the device
+    # trace's clock, naming its unit by integer stream and seq
+    host = _host_events(prof)
+    names = {n for n, *_ in host}
+    unit_spans = {
+        "prefetch", "prefetch_bwd", "storage_read", "gather", "regather",
+        "loss_fetch", "grad_fetch", "h2d", "d2h", "write_behind",
+        "stall:compute_wait_xfer_fwd", "stall:compute_wait_xfer_loss",
+        "stall:compute_wait_xfer_bwd", "scatter", "write_submit",
+    }
+    assert unit_spans | {"d2h_wait", "drain", "fwd_layer", "loss_layer",
+                         "bwd_layer", "epoch"} <= names
+    for n, _, _, stats in host:
+        if n in unit_spans:
+            assert type(stats["stream"]) is int, (n, stats)
+            assert type(stats["seq"]) is int, (n, stats)
+            assert stats["pass"] in ("fwd", "loss", "bwd"), (n, stats)
+    ends = {}
+    for n, _, end, stats in host:
+        if n in unit_spans:
+            ends.setdefault((stats["stream"], stats["seq"]), {})[n] = end
+    chains = 0
+    for (sid, seq), e in ends.items():
+        gather = e.get("gather", e.get("regather"))
+        if gather is None:
+            continue
+        pre = e.get("prefetch", e.get("prefetch_bwd"))
+        wait = e.get("stall:compute_wait_xfer_fwd",
+                     e.get("stall:compute_wait_xfer_bwd"))
+        assert pre <= gather <= e["h2d"] <= wait, (sid, seq, e)
+        chains += 1
+    assert chains == 4 * plan.n_parts     # 2 forward + 2 regather passes
+    scatter_paths = {st["path"] for n, _, _, st in host if n == "scatter"}
+    assert scatter_paths == {"ref"}
 
     with open(trace) as f:
         doc = json.load(f)
@@ -363,6 +542,54 @@ def test_pipelined_epoch_trace_covers_every_busy_stage(tmp_path):
     tnames = {ev["args"]["name"] for ev in evs
               if ev["ph"] == "M" and ev["name"] == "thread_name"}
     assert any(n.startswith("sso-") for n in tnames)
+
+
+def _traced_epoch(depth):
+    plan, Xr, Yr = _tiny_workload()
+    dims = [16, 24, 8]
+    spec = get_gnn("gcn")
+    params = spec.init(jax.random.PRNGKey(0), 16, 24, 8, 2)
+    c = Counters()
+    c.tracer = Tracer()
+    st_ = StorageTier(tempfile.mkdtemp(), counters=c)
+    eng = SSOEngine(spec, plan, dims, st_, HostCache(64 << 10, st_, c), c,
+                    mode="regather", pipeline=PipelineConfig(depth=depth))
+    eng.initialize(Xr)
+    eng.run_epoch(params, Yr)
+    eng.close()
+    st_.close()
+    return plan, c
+
+
+def test_each_stage_recorded_once_per_unit():
+    plan, c = _traced_epoch(depth=2)
+    evs = c.tracer.events()
+    names = {e["name"] for e in evs}
+    # nothing times an asynchronous dispatch
+    assert not names & {"compute_fwd", "compute_bwd", "bypass_write"}
+    assert not any(n.startswith("kernel:") for n in names)
+    seen = {}
+    for e in evs:
+        if e["ph"] == "X" and e["name"] in (
+                "prefetch", "prefetch_bwd", "gather", "regather",
+                "loss_fetch", "grad_fetch", "h2d", "scatter"):
+            key = (e["name"], e["args"]["stream"], e["args"]["seq"])
+            seen[key] = seen.get(key, 0) + 1
+    assert seen and set(seen.values()) == {1}
+    assert sum(1 for k in seen if k[0] == "regather") == 2 * plan.n_parts
+    # the async lifetime pair keeps its id, built from the same integers
+    for e in evs:
+        if e["ph"] == "b":
+            assert e["id"] == f"{e['args']['stream']}.{e['args']['seq']}"
+
+
+def test_serial_stream_spans_without_busy():
+    plan, c = _traced_epoch(depth=0)
+    names = {e["name"] for e in c.tracer.events()}
+    assert {"gather", "regather", "loss_fetch", "storage_read", "scatter",
+            "d2h_wait", "fwd_layer", "bwd_layer"} <= names
+    # nothing overlaps a serial stage: no busy seconds are counted
+    assert not c.stage_busy_seconds
 
 
 def test_untraced_run_attaches_no_tracer():
@@ -659,8 +886,7 @@ def test_trace_ring_gauges_track_live_tracer():
     assert snap["trace.dropped_events"] == 0
     assert snap["trace.ring_occupancy"] == 0.0
     c.tracer = Tracer(ring_events=4)           # gauges follow the rebind
-    for i in range(9):
-        c.tracer.complete(f"e{i}", 0.0)
+    _spans(c.tracer, [f"e{i}" for i in range(9)])
     snap = c.metrics.snapshot()
     assert snap["trace.dropped_events"] == 5
     assert snap["trace.ring_occupancy"] == 1.0  # ring at capacity
@@ -668,8 +894,7 @@ def test_trace_ring_gauges_track_live_tracer():
 
 def test_export_trace_ring_metadata_self_describes_truncation(tmp_path):
     tr = Tracer(ring_events=4)
-    for i in range(9):
-        tr.complete(f"e{i}", 0.001)
+    _spans(tr, [f"e{i}" for i in range(9)])
     doc = _export(tr, tmp_path)
     (meta,) = [ev for ev in doc["traceEvents"]
                if ev["ph"] == "M" and ev["name"] == "trace_ring"]
@@ -677,7 +902,7 @@ def test_export_trace_ring_metadata_self_describes_truncation(tmp_path):
                                 events_exported=4, truncated=True)
     # an un-truncated export says so
     tr2 = Tracer(ring_events=16)
-    tr2.complete("only", 0.001)
+    _spans(tr2, ["only"])
     doc2 = _export(tr2, tmp_path, "t2.json")
     (meta2,) = [ev for ev in doc2["traceEvents"]
                 if ev["ph"] == "M" and ev["name"] == "trace_ring"]
