@@ -25,8 +25,8 @@ COUNTERS_SCALAR_FIELDS = frozenset({
     "h2d_bytes", "d2h_bytes", "host_gather_bytes", "host_scatter_bytes",
     "cache_hits", "cache_misses", "cache_evictions", "cache_bypass",
     "cache_prefetches", "cache_peak_bytes", "pool_trims",
-    "pool_release_rejects", "device_flops", "threads_leaked",
-    "slow_lane_pins",
+    "pool_release_rejects", "device_flops", "narrow_aggregate_passes",
+    "threads_leaked", "slow_lane_pins",
 })
 
 # Blocking storage-tier / I/O-queue entry points (StorageTier + StorageIOQueue

@@ -81,6 +81,9 @@ class Counters:
     pool_release_rejects: int = 0  # release() calls refused by the guards
     # device compute (flop estimate filled by engine when available)
     device_flops: int = 0
+    # layer passes (forward or backward) whose layer program ran the
+    # transform-first order (``models/gnn/layers.transform_first``)
+    narrow_aggregate_passes: int = 0
     # fault tolerance (repro/core/faults.py + runtime unwind paths)
     threads_leaked: int = 0   # pipeline/I-O threads that outlived join timeout
     slow_lane_pins: int = 0   # prefetches forced cache-resident by slow lane

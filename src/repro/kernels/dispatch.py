@@ -197,6 +197,12 @@ class KernelDispatch:
             )
         return self._jit_gather
 
+    def fuses_aggregate(self, spec) -> bool:
+        """Whether :meth:`fused_forward_fn` runs ``spec``'s forward as the
+        one-kernel gather+aggregate (aggregate-first at every width)
+        instead of the model's ``apply_layer``."""
+        return spec.name == "gcn" and self.fused_aggregate
+
     def fused_forward_fn(self, spec, activate: bool):
         """``f(params_l, stack, idx, topo) -> out`` for one forward layer
         over the staged partition stack. Default: regather on device
@@ -213,7 +219,7 @@ class KernelDispatch:
             from repro.kernels.gather_scatter import ops
 
             interp = self.interpret
-            if spec.name == "gcn" and self.fused_aggregate:
+            if self.fuses_aggregate(spec):
                 @jax.jit
                 def f(params_l, stack, idx, topo):
                     erows = idx[topo.src]

@@ -10,6 +10,18 @@ the JAX analogue of the paper's custom grad engine replacing torch.autograd.
 
 Message passing is built on ``jax.ops.segment_sum``/``segment_max`` over edge
 indices (JAX sparse is BCOO-only; scatter-style MP is the system substrate).
+
+Order of the two linear steps in GCN and GraphSAGE (:func:`transform_first`):
+a layer that narrows (``d_out < d_in``, read from its weight's shape) runs
+its dense transform first and aggregates the transformed rows; every other
+layer aggregates first. The aggregation is linear, so ``(A X) W == A (X W)``:
+the order changes no mathematics, only the width at which the edge gather
+and segment sum run — and, through ``jax.vjp``, the width of their
+transposes in the backward (the cotangent's gather and scatter-add over the
+edges). On the device those edge passes, not the matmuls, take the layer's
+time, so the narrower side wins; the extra matmul runs over the unit's
+gathered rows instead of its output rows. Layers that do not narrow keep
+the aggregate-first program unchanged, bit for bit.
 """
 from __future__ import annotations
 
@@ -103,7 +115,20 @@ def gcn_init(rng, d_in, d_out):
     return {"lin": _dense(rng, d_in, d_out)}
 
 
+def transform_first(d_in: int, d_out: int) -> bool:
+    """Whether a GCN or SAGE layer of widths ``d_in -> d_out`` runs its
+    dense transform before the edge aggregation: exactly when it narrows,
+    so the gather and segment sums over the edges move ``d_out``-wide rows
+    instead of ``d_in``-wide ones."""
+    return d_out < d_in
+
+
 def gcn_apply(params, ga, topo: LocalTopo, activate: bool = True):
+    lin = params["lin"]
+    if transform_first(*lin["w"].shape):
+        msg = (ga @ lin["w"])[topo.src] * topo.edge_weight[:, None]
+        h = _seg_sum(msg, topo.dst, topo.n_dst) + lin["b"]
+        return jax.nn.relu(h) if activate else h
     msg = ga[topo.src] * topo.edge_weight[:, None]
     agg = _seg_sum(msg, topo.dst, topo.n_dst)
     h = _apply_dense(params["lin"], agg)
@@ -120,6 +145,12 @@ def sage_init(rng, d_in, d_out):
 
 
 def sage_apply(params, ga, topo: LocalTopo, activate: bool = True):
+    nbr = params["nbr"]
+    if transform_first(*nbr["w"].shape):
+        msg = (ga @ nbr["w"])[topo.src] * topo.edge_mask[:, None]
+        agg = _seg_sum(msg, topo.dst, topo.n_dst) / topo.in_deg[:, None]
+        h = _apply_dense(params["self"], ga[topo.dst_self]) + (agg + nbr["b"])
+        return jax.nn.relu(h) if activate else h
     msg = ga[topo.src] * topo.edge_mask[:, None]
     agg = _seg_sum(msg, topo.dst, topo.n_dst) / topo.in_deg[:, None]
     x_self = ga[topo.dst_self]
@@ -273,6 +304,13 @@ class GNNSpec:
     name: str
     init_layer: Callable[..., Dict[str, Any]]
     apply_layer: Callable[..., jnp.ndarray]
+
+    def transforms_first(self, d_in: int, d_out: int) -> bool:
+        """Whether this model's layer of widths ``d_in -> d_out`` runs the
+        transform-first order (GCN and SAGE layers that narrow); what the
+        engine counts as ``narrow_aggregate_passes``."""
+        return (self.apply_layer in (gcn_apply, sage_apply)
+                and transform_first(d_in, d_out))
 
     def init(self, rng, d_in: int, d_hidden: int, d_out: int, n_layers: int):
         dims = [d_in] + [d_hidden] * (n_layers - 1) + [d_out]

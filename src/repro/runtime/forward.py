@@ -481,6 +481,10 @@ class ForwardRunner:
                 # zero — the deadlock regression suite's contract)
                 self.release_pins()
                 raise
+            if (self.spec.transforms_first(self.dims[l], self.dims[l + 1])
+                    and not (use_stacked
+                             and self.kernels.fuses_aggregate(self.spec))):
+                self.counters.bump("narrow_aggregate_passes")
             # barrier: the next layer reads name_out — all writes must be
             # down (drain_writes retires pending D2H copies first)
             rt.drain_writes()

@@ -211,6 +211,22 @@ def test_fwd_bwd_overlap_split():
     assert s["overlapped_seconds_bwd"] <= s["busy_seconds"]
 
 
+@pytest.mark.parametrize("model,dims,kernels,passes", [
+    # both layers narrow: 2 forward + 2 backward passes
+    ("sage", [16, 12, 8], "auto", 4),
+    # no layer narrows
+    ("gcn", [16, 16, 16], "auto", 0),
+    # the one-kernel GCN forward aggregates first: the backward alone counts
+    ("gcn", [16, 12, 8], "pallas-fused", 2),
+])
+def test_narrow_aggregate_passes_counts_transform_first_passes(
+        model, dims, kernels, passes):
+    plan, Xr, Yr = _setup()
+    _, _, c = _run(plan, Xr, Yr, dims, "regather", depth=2, model=model,
+                   kernels=kernels)
+    assert c.narrow_aggregate_passes == passes
+
+
 # ------------------------------------------------------------- StorageIOQueue
 def test_write_behind_flushes_on_close(rng):
     c = Counters()

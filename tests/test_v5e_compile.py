@@ -7,6 +7,9 @@ chip would refuse (unaligned blocks, memory over the device's 16 GiB).
 - the GCN layer's forward and backward programs (``layer_apply``,
   ``layer_vjp``) at ``chip_smoke.py``'s largest padded work unit, for the
   1024->256 and 256->19 layers of ``gcn-igbm-3l``, fit one chip's HBM;
+- so do the GraphSAGE layer's, at the largest padded unit of the
+  ``graphsage-reddit`` benchmark plan, for its 602->128 and 128->41 layers
+  (both narrow, so both run the transform-first order);
 - each Pallas gather/scatter kernel is refused at width 1024 (strict xfail,
   so a kernel redesign that compiles must flip it).
 
@@ -23,12 +26,15 @@ from repro.core.engine import layer_vjp
 from repro.kernels.gather_scatter.gather_scatter import (
     gather_aggregate_pallas, gather_rows_pallas, scatter_add_pallas,
 )
-from repro.models.gnn.layers import LocalTopo, gcn_apply
+from repro.models.gnn.layers import LocalTopo, gcn_apply, sage_apply
 from repro.runtime.forward import layer_apply
 
 # chip_smoke.py's largest padded unit (r_pad, e_pad, d_pad) at seed 0:
 # 262,144 nodes, average degree 12, 16 partitions
 SMOKE_UNIT = (262_144, 2_097_152, 32_768)
+# the graphsage-reddit benchmark plan's largest padded unit: 16,384 nodes,
+# 6.3M edges, 16 partitions
+REDDIT_UNIT = (16_384, 1_048_576, 2_048)
 HBM_BYTES = 16 << 30
 REFUSAL = ("v5e refuses single-row blocks: 'the last two dimensions of your "
            "block shape are divisible by 8 and 128'")
@@ -59,22 +65,20 @@ def _sds(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _unit_args(sharding, d_in, d_out):
-    r, e, d = SMOKE_UNIT
+def _unit_args(sharding, unit, weights, d_in, d_out):
+    r, e, d = unit
     S = partial(_sds, sharding)
     topo = LocalTopo(S((e,), jnp.int32), S((e,), jnp.int32), d,
                      S((e,)), S((e,)), S((d,)), S((d,), jnp.int32))
-    params = {"lin": {"w": S((d_in, d_out)), "b": S((d_out,))}}
+    params = {k: {"w": S((d_in, d_out)), "b": S((d_out,))} for k in weights}
     return params, S((r, d_in)), topo, S((d, d_out))
 
 
-@pytest.mark.parametrize("d_in,d_out,activate", [(1024, 256, True),
-                                                 (256, 19, False)])
-@pytest.mark.parametrize("program", ["forward", "backward"])
-def test_gcn_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
-                                         activate):
-    params, ga, topo, d_out_arr = _unit_args(one_chip, d_in, d_out)
-    kw = dict(apply=gcn_apply, activate=activate)
+def _fits_one_chip(sharding, program, apply, unit, weights, d_in, d_out,
+                   activate):
+    params, ga, topo, d_out_arr = _unit_args(sharding, unit, weights, d_in,
+                                             d_out)
+    kw = dict(apply=apply, activate=activate)
     if program == "forward":
         lowered = layer_apply.lower(params, ga, topo, **kw)
     else:
@@ -83,6 +87,24 @@ def test_gcn_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
     used = (m.temp_size_in_bytes + m.argument_size_in_bytes
             + m.output_size_in_bytes)
     assert 0 < used < HBM_BYTES
+
+
+@pytest.mark.parametrize("d_in,d_out,activate", [(1024, 256, True),
+                                                 (256, 19, False)])
+@pytest.mark.parametrize("program", ["forward", "backward"])
+def test_gcn_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
+                                         activate):
+    _fits_one_chip(one_chip, program, gcn_apply, SMOKE_UNIT, ("lin",), d_in,
+                   d_out, activate)
+
+
+@pytest.mark.parametrize("d_in,d_out,activate", [(602, 128, True),
+                                                 (128, 41, False)])
+@pytest.mark.parametrize("program", ["forward", "backward"])
+def test_sage_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
+                                          activate):
+    _fits_one_chip(one_chip, program, sage_apply, REDDIT_UNIT,
+                   ("self", "nbr"), d_in, d_out, activate)
 
 
 def _kernel_case(name, sharding):
