@@ -14,8 +14,10 @@ def gnn_model_flops(model_mod, dims, n_nodes: int, n_edges: int,
                     train: bool = True) -> float:
     """Model FLOPs of one forward pass, or of an epoch (forward plus twice
     the forward for the backward) with ``train``."""
-    f = sum(model_mod.model_flops(n_nodes, n_edges, dims[i], dims[i + 1])
-            for i in range(len(dims) - 1))
+    L = len(dims) - 1
+    f = sum(model_mod.model_flops(n_nodes, n_edges, dims[l], dims[l + 1],
+                                  l < L - 1)
+            for l in range(L))
     return (3.0 if train else 1.0) * f
 
 

@@ -3,9 +3,14 @@
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix; both
 are data, found by name:
 
-- ``bench/configs/<config>.json``: the model (``bench/models/<model>.py``
-  holds its plain reference), widths, dataset shape, node count, partitions
-  and the dataset graph's seed;
+- ``bench/configs/<config>.json``: the model, widths, dataset shape, node
+  count, partitions, the dataset graph's seed, and ``rehearse``: the
+  ``n_nodes`` and ``n_parts`` of the CPU rehearsals in ``bench/tests``
+  (a chip run ignores it);
+- ``bench/models/<model>.py``: the model's plain reference. ``init``,
+  ``model_flops``, ``forward``, ``backward``, ``fwd_cost`` and ``bwd_cost``
+  are each told the layer's position as ``activate``, false on the output
+  layer alone;
 - ``bench/workloads/<traffic>.json``: the job (``train``: SSO epochs;
   ``infer``: offloaded inference passes), engine mode, host-cache budget as
   a fraction of named activations, pipeline depth;
@@ -177,9 +182,10 @@ def make_data(model_mod, n: int, dims, seed: int):
         kx, ky, kp = jax.random.split(key, 3)
         x = jax.random.normal(kx, (n, dims[0]), jnp.float32) * 0.1
         y = jax.random.randint(ky, (n,), 0, dims[-1], jnp.int32)
-        keys = jax.random.split(kp, len(dims) - 1)
-        params = [model_mod.init(keys[l], dims[l], dims[l + 1])
-                  for l in range(len(dims) - 1)]
+        L = len(dims) - 1
+        keys = jax.random.split(kp, L)
+        params = [model_mod.init(keys[l], dims[l], dims[l + 1], l < L - 1)
+                  for l in range(L)]
         return x, y, params
 
     seed = int(seed)    # may exceed 32 bits: folded in as two words

@@ -15,8 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def init(key, d_in: int, d_out: int):
-    """Weights in the program's parameter tree layout, float32."""
+def init(key, d_in: int, d_out: int, activate: bool):
+    """Weights in the program's parameter tree layout, float32. Every
+    layer has the same tree, whatever its position (``activate``)."""
     return {"lin": {"w": jax.random.normal(key, (d_in, d_out), jnp.float32)
                     / np.sqrt(d_in),
                     "b": jnp.zeros((d_out,), jnp.float32)}}
@@ -41,10 +42,10 @@ def backward(p, h, z, d_out, g, activate: bool):
     return ({"lin": {"w": h.T @ pt, "b": dz.sum(axis=0)}}, pt @ w.T)
 
 
-def model_flops(n_nodes, n_edges, d_in, d_out):
+def model_flops(n_nodes, n_edges, d_in, d_out, activate):
     """Model FLOPs of one layer's forward: the edge sum
     and the matmul over every real node and edge
-    (``repro.configs.base.gnn_model_flops``)."""
+    (``repro.configs.base.gnn_model_flops``), at any position."""
     return 2.0 * n_edges * d_in + 2.0 * n_nodes * d_in * d_out
 
 

@@ -22,8 +22,9 @@ def _dense(key, d_in, d_out):
             "b": jnp.zeros((d_out,), jnp.float32)}
 
 
-def init(key, d_in: int, d_out: int):
-    """Weights in the program's parameter tree layout, float32."""
+def init(key, d_in: int, d_out: int, activate: bool):
+    """Weights in the program's parameter tree layout, float32. Every
+    layer has the same tree, whatever its position (``activate``)."""
     k1, k2 = jax.random.split(key)
     return {"self": _dense(k1, d_in, d_out), "nbr": _dense(k2, d_in, d_out)}
 
@@ -48,10 +49,10 @@ def backward(p, h, z, d_out, g, activate: bool):
     return dp, dz @ p["self"]["w"].T + q @ p["nbr"]["w"].T
 
 
-def model_flops(n_nodes, n_edges, d_in, d_out):
+def model_flops(n_nodes, n_edges, d_in, d_out, activate):
     """Model FLOPs of one layer's forward: the neighbour
     sum and the two matmuls over every real node and edge
-    (``repro.configs.base.gnn_model_flops``)."""
+    (``repro.configs.base.gnn_model_flops``), at any position."""
     return 2.0 * n_edges * d_in + 4.0 * n_nodes * d_in * d_out
 
 

@@ -1,5 +1,6 @@
 """Tiny CPU runs of ``bench/run.py``: every phase of a run at the cells'
-widths on a few hundred nodes, with the look for a chip skipped. Besides
+widths on the few hundred nodes that each configuration's ``rehearse`` key
+names, with the look for a chip skipped. Besides
 the cells of ``BENCHMARK.json``, every cell that has a limits file
 (``bench/limits/<cell>.json``, naming its configuration and traffic) but no
 entry there yet is rehearsed too."""
@@ -9,8 +10,6 @@ import os
 import harness
 import run
 
-TINY = {"gcn-igbm-3l": dict(n_nodes=512, n_parts=4),
-        "graphsage-reddit": dict(n_nodes=256, n_parts=4)}
 SEED = 2**31 + 17
 
 
@@ -38,8 +37,9 @@ def workload(cell: str) -> dict:
 
 def tiny_run(cell: str, trace: int = 0, seconds: float = 0.3):
     wl = workload(cell)
+    config = harness.load_json("configs", wl["config"] + ".json")
     limits = harness.load_json("limits", cell + ".json")["limits"]
     return run.main(["--workload", cell, "--seed", str(SEED),
                      "--seconds", str(seconds), "--trace", str(trace)],
-                    rehearse=dict(config=TINY[wl["config"]], limits=limits,
+                    rehearse=dict(config=config["rehearse"], limits=limits,
                                   workload=wl))

@@ -13,13 +13,15 @@ import pytest
 
 import harness
 import run
-from _tiny import SEED, TINY, cells, tiny_run, workload
+from _tiny import SEED, cells, tiny_run, workload, workloads
 
 harness.use_program()
 from repro.core import engine as engine_mod  # noqa: E402
 from repro.runtime import forward as forward_mod  # noqa: E402
 
-TRAIN = [c for c in cells() if "train" in c]
+TRAIN = [w["name"] for w in workloads()
+         if harness.load_json("workloads", w["traffic"] + ".json")["job"]
+         == "train"]
 
 
 @pytest.mark.parametrize("cell", TRAIN)
@@ -72,8 +74,8 @@ def _control_check(cell: str, control: str):
     """The check of ``bench/run.py`` with the control, computed on the
     cell's data, in the program's place."""
     wl = workload(cell)
-    cfg = {**harness.load_json("configs", wl["config"] + ".json"),
-           **TINY[wl["config"]]}
+    cfg = harness.load_json("configs", wl["config"] + ".json")
+    cfg = {**cfg, **cfg["rehearse"]}
     c = harness.Cell(cell, cfg,
                      harness.load_json("workloads", wl["traffic"] + ".json"))
     c.load(SEED)
