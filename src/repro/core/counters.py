@@ -84,6 +84,9 @@ class Counters:
     # layer passes (forward or backward) whose layer program ran the
     # transform-first order (``models/gnn/layers.transform_first``)
     narrow_aggregate_passes: int = 0
+    # layer passes (forward or backward) of an attention layer (GAT), whose
+    # edge weights are a softmax of the activations
+    attention_passes: int = 0
     # fault tolerance (repro/core/faults.py + runtime unwind paths)
     threads_leaked: int = 0   # pipeline/I-O threads that outlived join timeout
     slow_lane_pins: int = 0   # prefetches forced cache-resident by slow lane

@@ -486,6 +486,8 @@ class SSOEngine:
                 grads[l] = self._backward_layer(l, params, use_stacked)
             if self.spec.transforms_first(self.dims[l], self.dims[l + 1]):
                 self.counters.bump("narrow_aggregate_passes")
+            if self.spec.attends():
+                self.counters.bump("attention_passes")
         self.cache.drop_layer("grad", 0, flush=False)
         rt.drain_writes()
         st.free(_grad_name(0))

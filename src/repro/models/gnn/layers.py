@@ -26,6 +26,7 @@ the aggregate-first program unchanged, bit for bit.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -159,41 +160,58 @@ def sage_apply(params, ga, topo: LocalTopo, activate: bool = True):
 
 
 # --------------------------------------------------------------------------
-# GAT (single-/multi-head graph attention)
+# GAT (Veličković et al. 2018, arXiv:1710.10903): multi-head graph attention
+#
+# The heads are read from the parameter tree: ``w`` is (d_in, H, d_head).
+# A layer whose bias is H * d_head wide concatenates its heads (hidden
+# layers); one whose bias is d_head wide averages them (the output layer of
+# the paper's §3.3 models). A hidden layer whose input width equals its
+# output width adds its own input before the ELU: the skip connection
+# across the middle attentional layer of the inductive model.
 # --------------------------------------------------------------------------
 
-def gat_init(rng, d_in, d_out, n_heads: int = 4):
-    if d_out % n_heads:
+def gat_init(rng, d_in, d_out, n_heads: int = 4, average: bool = False):
+    """A concatenating layer of ``n_heads`` heads of ``d_out // n_heads``
+    (one head where they do not divide ``d_out``), or with ``average`` an
+    averaging layer of ``n_heads`` heads of ``d_out``."""
+    if not average and d_out % n_heads:
         n_heads = 1
-    d_head = d_out // n_heads
+    d_head = d_out if average else d_out // n_heads
     k1, k2, k3 = jax.random.split(rng, 3)
     return {
         "w": jax.random.normal(k1, (d_in, n_heads, d_head), jnp.float32)
         / np.sqrt(d_in),
         "a_src": jax.random.normal(k2, (n_heads, d_head), jnp.float32) * 0.1,
         "a_dst": jax.random.normal(k3, (n_heads, d_head), jnp.float32) * 0.1,
-        "b": jnp.zeros((n_heads * d_head,), jnp.float32),
+        "b": jnp.zeros((d_out,), jnp.float32),
     }
 
 
 def gat_apply(params, ga, topo: LocalTopo, activate: bool = True):
-    h = jnp.einsum("nd,dhe->nhe", ga, params["w"])  # (n_src, H, d_head)
-    e_src = jnp.einsum("nhe,he->nh", h, params["a_src"])
-    e_dst = jnp.einsum("nhe,he->nh", h, params["a_dst"])
-    score = jax.nn.leaky_relu(
-        e_src[topo.src] + e_dst[topo.dst_self][topo.dst], 0.2
-    )  # (E, H)
-    # mask padding with -inf before segment softmax
-    neg = jnp.finfo(score.dtype).min
-    score = jnp.where(topo.edge_mask[:, None] > 0, score, neg)
-    smax = _seg_max(score, topo.dst, topo.n_dst)
-    smax = jnp.maximum(smax, -1e30)  # guard all-pad segments
-    ex = jnp.exp(score - smax[topo.dst]) * topo.edge_mask[:, None]
-    den = _seg_sum(ex, topo.dst, topo.n_dst)
-    attn = ex / jnp.maximum(den[topo.dst], 1e-9)
-    msg = h[topo.src] * attn[:, :, None]
-    agg = _seg_sum(msg, topo.dst, topo.n_dst)  # (n_dst, H, d_head)
-    out = agg.reshape(topo.n_dst, -1) + params["b"]
+    d_in, n_heads, d_head = params["w"].shape
+    with jax.named_scope("gat_score"):
+        h = jnp.einsum("nd,dhe->nhe", ga, params["w"])  # (n_src, H, d_head)
+        e_src = jnp.einsum("nhe,he->nh", h, params["a_src"])
+        e_dst = jnp.einsum("nhe,he->nh", h[topo.dst_self], params["a_dst"])
+        score = jax.nn.leaky_relu(e_src[topo.src] + e_dst[topo.dst], 0.2)
+    with jax.named_scope("gat_softmax"):
+        # padded edges are left out of the max and weigh 0
+        real = topo.edge_mask[:, None] > 0
+        score = jnp.where(real, score, jnp.finfo(score.dtype).min)
+        smax = _seg_max(score, topo.dst, topo.n_dst)
+        smax = jnp.maximum(smax, -1e30)  # guard all-pad segments
+        ex = jnp.where(real, jnp.exp(score - smax[topo.dst]), 0.0)
+        den = jnp.maximum(_seg_sum(ex, topo.dst, topo.n_dst), 1e-9)
+    with jax.named_scope("gat_aggregate"):
+        # the softmax's division is taken after the sum, once per dst row
+        msg = h[topo.src] * ex[:, :, None]
+        agg = _seg_sum(msg, topo.dst, topo.n_dst) / den[:, :, None]
+        if params["b"].shape[0] != n_heads * d_head:
+            out = agg.mean(axis=1) + params["b"]
+        else:
+            out = agg.reshape(topo.n_dst, -1) + params["b"]
+            if activate and d_in == out.shape[-1]:
+                out = out + ga[topo.dst_self]
     return jax.nn.elu(out) if activate else out
 
 
@@ -304,6 +322,8 @@ class GNNSpec:
     name: str
     init_layer: Callable[..., Dict[str, Any]]
     apply_layer: Callable[..., jnp.ndarray]
+    # the output layer's init where it differs from the hidden layers'
+    init_output: Optional[Callable[..., Dict[str, Any]]] = None
 
     def transforms_first(self, d_in: int, d_out: int) -> bool:
         """Whether this model's layer of widths ``d_in -> d_out`` runs the
@@ -312,19 +332,28 @@ class GNNSpec:
         return (self.apply_layer in (gcn_apply, sage_apply)
                 and transform_first(d_in, d_out))
 
+    def attends(self) -> bool:
+        """Whether this model's layers weigh their edges by attention
+        (GAT); what the engine counts as ``attention_passes``."""
+        return self.apply_layer is gat_apply
+
     def init(self, rng, d_in: int, d_hidden: int, d_out: int, n_layers: int):
         dims = [d_in] + [d_hidden] * (n_layers - 1) + [d_out]
         params = []
         for i in range(n_layers):
             rng, k = jax.random.split(rng)
-            params.append(self.init_layer(k, dims[i], dims[i + 1]))
+            init = (self.init_output if i == n_layers - 1 and self.init_output
+                    else self.init_layer)
+            params.append(init(k, dims[i], dims[i + 1]))
         return params
 
 
 GNN_REGISTRY: Dict[str, GNNSpec] = {
     "gcn": GNNSpec("gcn", gcn_init, gcn_apply),
     "sage": GNNSpec("sage", sage_init, sage_apply),
-    "gat": GNNSpec("gat", gat_init, gat_apply),
+    # hidden layers concatenate 4 heads; the output layer averages 6 (§3.3)
+    "gat": GNNSpec("gat", gat_init, gat_apply,
+                   init_output=partial(gat_init, n_heads=6, average=True)),
     "gin": GNNSpec("gin", gin_init, gin_apply),
     "pna": GNNSpec("pna", pna_init, pna_apply),
     "graphcast": GNNSpec("graphcast", graphcast_init, graphcast_apply),

@@ -485,6 +485,8 @@ class ForwardRunner:
                     and not (use_stacked
                              and self.kernels.fuses_aggregate(self.spec))):
                 self.counters.bump("narrow_aggregate_passes")
+            if self.spec.attends():
+                self.counters.bump("attention_passes")
             # barrier: the next layer reads name_out — all writes must be
             # down (drain_writes retires pending D2H copies first)
             rt.drain_writes()

@@ -227,6 +227,17 @@ def test_narrow_aggregate_passes_counts_transform_first_passes(
     assert c.narrow_aggregate_passes == passes
 
 
+@pytest.mark.parametrize("model,dims,passes", [
+    # three attention layers, each once forward and once backward
+    ("gat", [16, 16, 16, 8], 6),
+    ("sage", [16, 12, 8], 0),
+])
+def test_attention_passes_counts_gat_layer_passes(model, dims, passes):
+    plan, Xr, Yr = _setup()
+    _, _, c = _run(plan, Xr, Yr, dims, "regather", depth=2, model=model)
+    assert c.attention_passes == passes
+
+
 # ------------------------------------------------------------- StorageIOQueue
 def test_write_behind_flushes_on_close(rng):
     c = Counters()

@@ -10,6 +10,9 @@ chip would refuse (unaligned blocks, memory over the device's 16 GiB).
 - so do the GraphSAGE layer's, at the largest padded unit of the
   ``graphsage-reddit`` benchmark plan, for its 602->128 and 128->41 layers
   (both narrow, so both run the transform-first order);
+- so do the GAT layer's at the same unit, for the three layers of
+  ``gat-reddit``: 602 -> 4x256 concatenated, 1024 -> 4x256 with the skip,
+  1024 -> 6x41 averaged (about 4.6 GiB forward and 10.2 GiB backward each);
 - each Pallas gather/scatter kernel is refused at width 1024 (strict xfail,
   so a kernel redesign that compiles must flip it).
 
@@ -26,14 +29,16 @@ from repro.core.engine import layer_vjp
 from repro.kernels.gather_scatter.gather_scatter import (
     gather_aggregate_pallas, gather_rows_pallas, scatter_add_pallas,
 )
-from repro.models.gnn.layers import LocalTopo, gcn_apply, sage_apply
+from repro.models.gnn.layers import (
+    LocalTopo, gat_apply, gcn_apply, sage_apply,
+)
 from repro.runtime.forward import layer_apply
 
 # chip_smoke.py's largest padded unit (r_pad, e_pad, d_pad) at seed 0:
 # 262,144 nodes, average degree 12, 16 partitions
 SMOKE_UNIT = (262_144, 2_097_152, 32_768)
-# the graphsage-reddit benchmark plan's largest padded unit: 16,384 nodes,
-# 6.3M edges, 16 partitions
+# the largest padded unit of the benchmark plan of the reddit graph
+# (graphsage-reddit, gat-reddit): 16,384 nodes, 6.3M edges, 16 partitions
 REDDIT_UNIT = (16_384, 1_048_576, 2_048)
 HBM_BYTES = 16 << 30
 REFUSAL = ("v5e refuses single-row blocks: 'the last two dimensions of your "
@@ -65,18 +70,23 @@ def _sds(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _unit_args(sharding, unit, weights, d_in, d_out):
+def _dense(weights, d_in, d_out):
+    """Shapes of a layer of dense weights named ``weights``."""
+    return {k: {"w": (d_in, d_out), "b": (d_out,)} for k in weights}
+
+
+def _unit_args(sharding, unit, shapes, d_in, d_out):
     r, e, d = unit
     S = partial(_sds, sharding)
     topo = LocalTopo(S((e,), jnp.int32), S((e,), jnp.int32), d,
                      S((e,)), S((e,)), S((d,)), S((d,), jnp.int32))
-    params = {k: {"w": S((d_in, d_out)), "b": S((d_out,))} for k in weights}
+    params = jax.tree.map(S, shapes, is_leaf=lambda x: isinstance(x, tuple))
     return params, S((r, d_in)), topo, S((d, d_out))
 
 
-def _fits_one_chip(sharding, program, apply, unit, weights, d_in, d_out,
+def _fits_one_chip(sharding, program, apply, unit, shapes, d_in, d_out,
                    activate):
-    params, ga, topo, d_out_arr = _unit_args(sharding, unit, weights, d_in,
+    params, ga, topo, d_out_arr = _unit_args(sharding, unit, shapes, d_in,
                                              d_out)
     kw = dict(apply=apply, activate=activate)
     if program == "forward":
@@ -94,8 +104,8 @@ def _fits_one_chip(sharding, program, apply, unit, weights, d_in, d_out,
 @pytest.mark.parametrize("program", ["forward", "backward"])
 def test_gcn_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
                                          activate):
-    _fits_one_chip(one_chip, program, gcn_apply, SMOKE_UNIT, ("lin",), d_in,
-                   d_out, activate)
+    _fits_one_chip(one_chip, program, gcn_apply, SMOKE_UNIT,
+                   _dense(("lin",), d_in, d_out), d_in, d_out, activate)
 
 
 @pytest.mark.parametrize("d_in,d_out,activate", [(602, 128, True),
@@ -104,7 +114,22 @@ def test_gcn_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
 def test_sage_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
                                           activate):
     _fits_one_chip(one_chip, program, sage_apply, REDDIT_UNIT,
-                   ("self", "nbr"), d_in, d_out, activate)
+                   _dense(("self", "nbr"), d_in, d_out), d_in, d_out,
+                   activate)
+
+
+@pytest.mark.parametrize("d_in,heads,d_head,d_out,activate", [
+    (602, 4, 256, 1024, True),     # concatenated heads
+    (1024, 4, 256, 1024, True),    # concatenated, with the skip
+    (1024, 6, 41, 41, False),      # averaged output heads
+])
+@pytest.mark.parametrize("program", ["forward", "backward"])
+def test_gat_layer_program_fits_one_chip(one_chip, program, d_in, heads,
+                                         d_head, d_out, activate):
+    shapes = {"w": (d_in, heads, d_head), "a_src": (heads, d_head),
+              "a_dst": (heads, d_head), "b": (d_out,)}
+    _fits_one_chip(one_chip, program, gat_apply, REDDIT_UNIT, shapes, d_in,
+                   d_out, activate)
 
 
 def _kernel_case(name, sharding):
