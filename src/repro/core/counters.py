@@ -87,6 +87,10 @@ class Counters:
     # layer passes (forward or backward) of an attention layer (GAT), whose
     # edge weights are a softmax of the activations
     attention_passes: int = 0
+    # (layer, unit) passes of an attention layer, forward and backward, and
+    # those of them that ran the dense path (``models/gnn/layers.gat_dense``)
+    attention_units: int = 0
+    dense_attention_units: int = 0
     # fault tolerance (repro/core/faults.py + runtime unwind paths)
     threads_leaked: int = 0   # pipeline/I-O threads that outlived join timeout
     slow_lane_pins: int = 0   # prefetches forced cache-resident by slow lane

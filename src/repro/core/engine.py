@@ -488,6 +488,10 @@ class SSOEngine:
                 self.counters.bump("narrow_aggregate_passes")
             if self.spec.attends():
                 self.counters.bump("attention_passes")
+                self.counters.bump("attention_units", len(units))
+                self.counters.bump(
+                    "dense_attention_units",
+                    self.spec.dense_attention_units(params[l], units))
         self.cache.drop_layer("grad", 0, flush=False)
         rt.drain_writes()
         st.free(_grad_name(0))

@@ -168,6 +168,12 @@ def sage_apply(params, ga, topo: LocalTopo, activate: bool = True):
 # the paper's §3.3 models). A hidden layer whose input width equals its
 # output width adds its own input before the ELU: the skip connection
 # across the middle attentional layer of the inductive model.
+#
+# Scores, softmax and weighted sum take one of two forms, chosen from the
+# unit's static shapes (``gat_dense``): per edge, with gathers and segment
+# sums over the padded edges at H * d_head lanes; or, where the unit is
+# dense enough, as a masked (H, n_dst, n_src) attention matrix applied to
+# the rows by one float32 matmul per head.
 # --------------------------------------------------------------------------
 
 def gat_init(rng, d_in, d_out, n_heads: int = 4, average: bool = False):
@@ -187,12 +193,21 @@ def gat_init(rng, d_in, d_out, n_heads: int = 4, average: bool = False):
     }
 
 
-def gat_apply(params, ga, topo: LocalTopo, activate: bool = True):
-    d_in, n_heads, d_head = params["w"].shape
+def gat_dense(n_dst: int, n_src: int, n_edges: int, d_head: int) -> bool:
+    """Whether a GAT layer over a unit of ``n_dst`` output rows, ``n_src``
+    gathered rows and ``n_edges`` (padded) edges runs the dense path: when
+    its ``(H, n_dst, n_src)`` attention tensor is no larger than the
+    ``(E, H, d_head)`` messages the edge path would gather and scatter,
+    ``d_head`` counted as at least one 128-lane tile. So the dense path
+    never holds more than the edge path it replaces."""
+    return n_dst * n_src <= max(d_head, 128) * n_edges
+
+
+def _attend_edges(h, e_src, e_dst, topo: LocalTopo):
+    """Each head's softmax-weighted sum over the in-edges, per edge: scores
+    gathered to the edges, a segment max and two segment sums into the dst
+    rows. ``h`` is (n_src, H, d_head); returns (n_dst, H, d_head)."""
     with jax.named_scope("gat_score"):
-        h = jnp.einsum("nd,dhe->nhe", ga, params["w"])  # (n_src, H, d_head)
-        e_src = jnp.einsum("nhe,he->nh", h, params["a_src"])
-        e_dst = jnp.einsum("nhe,he->nh", h[topo.dst_self], params["a_dst"])
         score = jax.nn.leaky_relu(e_src[topo.src] + e_dst[topo.dst], 0.2)
     with jax.named_scope("gat_softmax"):
         # padded edges are left out of the max and weigh 0
@@ -205,7 +220,52 @@ def gat_apply(params, ga, topo: LocalTopo, activate: bool = True):
     with jax.named_scope("gat_aggregate"):
         # the softmax's division is taken after the sum, once per dst row
         msg = h[topo.src] * ex[:, :, None]
-        agg = _seg_sum(msg, topo.dst, topo.n_dst) / den[:, :, None]
+        return _seg_sum(msg, topo.dst, topo.n_dst) / den[:, :, None]
+
+
+def _attend_dense(h, e_src, e_dst, topo: LocalTopo):
+    """The same weighted sum over a dense ``(H, n_dst, n_src)`` attention
+    matrix: a masked row softmax, then one matmul per head. A non-edge
+    weighs exactly 0 and an edge listed twice weighs twice, as on the edge
+    path; only the order of the sums differs."""
+    with jax.named_scope("gat_mask"):
+        # edge counts, one scatter of E scalars; padded edges add 0
+        adj = jnp.zeros((topo.n_dst, h.shape[0]), h.dtype).at[
+            topo.dst, topo.src].add(topo.edge_mask)
+    with jax.named_scope("gat_score"):
+        score = jax.nn.leaky_relu(e_src.T[:, None, :] + e_dst.T[:, :, None],
+                                  0.2)
+    with jax.named_scope("gat_softmax"):
+        # non-edges are masked before the exp: the row max is taken over the
+        # in-edges alone, and no non-edge's exp can overflow (its VJP would
+        # then multiply 0 by inf)
+        score = jnp.where(adj > 0, score, jnp.finfo(score.dtype).min)
+        smax = jnp.maximum(score.max(axis=-1, keepdims=True), -1e30)
+        ex = jnp.exp(score - smax) * adj
+        den = jnp.maximum(ex.sum(axis=-1), 1e-9)
+    with jax.named_scope("gat_aggregate"):
+        # float32 products and sums, as the edge path's: the default
+        # precision would round both operands to bfloat16
+        agg = jnp.einsum("hds,she->dhe", ex, h,
+                         precision=jax.lax.Precision.HIGHEST)
+        return agg / den.T[:, :, None]
+
+
+def gat_apply(params, ga, topo: LocalTopo, activate: bool = True):
+    d_head = params["w"].shape[2]
+    dense = gat_dense(topo.n_dst, ga.shape[0], topo.src.shape[0], d_head)
+    return _gat(params, ga, topo, activate,
+                _attend_dense if dense else _attend_edges)
+
+
+def _gat(params, ga, topo: LocalTopo, activate: bool, attend):
+    d_in, n_heads, d_head = params["w"].shape
+    with jax.named_scope("gat_score"):
+        h = jnp.einsum("nd,dhe->nhe", ga, params["w"])  # (n_src, H, d_head)
+        e_src = jnp.einsum("nhe,he->nh", h, params["a_src"])
+        e_dst = jnp.einsum("nhe,he->nh", h[topo.dst_self], params["a_dst"])
+    agg = attend(h, e_src, e_dst, topo)
+    with jax.named_scope("gat_aggregate"):
         if params["b"].shape[0] != n_heads * d_head:
             out = agg.mean(axis=1) + params["b"]
         else:
@@ -336,6 +396,15 @@ class GNNSpec:
         """Whether this model's layers weigh their edges by attention
         (GAT); what the engine counts as ``attention_passes``."""
         return self.apply_layer is gat_apply
+
+    def dense_attention_units(self, params_l, units) -> int:
+        """How many of ``units`` (each with its padded ``d_pad``, ``r_pad``
+        and ``e_pad``) the attention layer of parameters ``params_l`` runs
+        on the dense path (``gat_dense``); what the engine counts as
+        ``dense_attention_units``."""
+        d_head = params_l["w"].shape[-1]
+        return sum(gat_dense(u.d_pad, u.r_pad, u.e_pad, d_head)
+                   for u in units)
 
     def init(self, rng, d_in: int, d_hidden: int, d_out: int, n_layers: int):
         dims = [d_in] + [d_hidden] * (n_layers - 1) + [d_out]

@@ -487,6 +487,10 @@ class ForwardRunner:
                 self.counters.bump("narrow_aggregate_passes")
             if self.spec.attends():
                 self.counters.bump("attention_passes")
+                self.counters.bump("attention_units", len(units))
+                self.counters.bump(
+                    "dense_attention_units",
+                    self.spec.dense_attention_units(params_l, units))
             # barrier: the next layer reads name_out — all writes must be
             # down (drain_writes retires pending D2H copies first)
             rt.drain_writes()

@@ -236,6 +236,9 @@ def test_attention_passes_counts_gat_layer_passes(model, dims, passes):
     plan, Xr, Yr = _setup()
     _, _, c = _run(plan, Xr, Yr, dims, "regather", depth=2, model=model)
     assert c.attention_passes == passes
+    # each pass over the plan's 5 units, every one dense enough for the
+    # dense path
+    assert c.attention_units == c.dense_attention_units == 5 * passes
 
 
 # ------------------------------------------------------------- StorageIOQueue

@@ -12,7 +12,10 @@ chip would refuse (unaligned blocks, memory over the device's 16 GiB).
   (both narrow, so both run the transform-first order);
 - so do the GAT layer's at the same unit, for the three layers of
   ``gat-reddit``: 602 -> 4x256 concatenated, 1024 -> 4x256 with the skip,
-  1024 -> 6x41 averaged (about 4.6 GiB forward and 10.2 GiB backward each);
+  1024 -> 6x41 averaged. The unit takes the dense path (``gat_dense``), whose
+  programs compile to 0.25 GiB of scratch forward and 0.63-0.89 GiB
+  backward; the edge path's backward, compiled at the same unit, holds more
+  (about 10 GiB);
 - each Pallas gather/scatter kernel is refused at width 1024 (strict xfail,
   so a kernel redesign that compiles must flip it).
 
@@ -30,7 +33,7 @@ from repro.kernels.gather_scatter.gather_scatter import (
     gather_aggregate_pallas, gather_rows_pallas, scatter_add_pallas,
 )
 from repro.models.gnn.layers import (
-    LocalTopo, gat_apply, gcn_apply, sage_apply,
+    LocalTopo, _attend_edges, _gat, gat_apply, gcn_apply, sage_apply,
 )
 from repro.runtime.forward import layer_apply
 
@@ -97,6 +100,7 @@ def _fits_one_chip(sharding, program, apply, unit, shapes, d_in, d_out,
     used = (m.temp_size_in_bytes + m.argument_size_in_bytes
             + m.output_size_in_bytes)
     assert 0 < used < HBM_BYTES
+    return m
 
 
 @pytest.mark.parametrize("d_in,d_out,activate", [(1024, 256, True),
@@ -118,6 +122,12 @@ def test_sage_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
                    activate)
 
 
+def _gat_shapes(d_in, heads, d_head, d_out):
+    """Shapes of a GAT layer of ``heads`` heads of ``d_head``."""
+    return {"w": (d_in, heads, d_head), "a_src": (heads, d_head),
+            "a_dst": (heads, d_head), "b": (d_out,)}
+
+
 @pytest.mark.parametrize("d_in,heads,d_head,d_out,activate", [
     (602, 4, 256, 1024, True),     # concatenated heads
     (1024, 4, 256, 1024, True),    # concatenated, with the skip
@@ -126,10 +136,19 @@ def test_sage_layer_program_fits_one_chip(one_chip, program, d_in, d_out,
 @pytest.mark.parametrize("program", ["forward", "backward"])
 def test_gat_layer_program_fits_one_chip(one_chip, program, d_in, heads,
                                          d_head, d_out, activate):
-    shapes = {"w": (d_in, heads, d_head), "a_src": (heads, d_head),
-              "a_dst": (heads, d_head), "b": (d_out,)}
-    _fits_one_chip(one_chip, program, gat_apply, REDDIT_UNIT, shapes, d_in,
-                   d_out, activate)
+    _fits_one_chip(one_chip, program, gat_apply, REDDIT_UNIT,
+                   _gat_shapes(d_in, heads, d_head, d_out), d_in, d_out, activate)
+
+
+def test_gat_dense_backward_holds_less_than_edge_backward(one_chip):
+    """At the reddit unit the 1024 -> 4x256 layer with the skip runs the
+    dense path; the edge path it replaces, compiled at the same unit, still
+    fits one chip but holds more scratch."""
+    temp = [_fits_one_chip(one_chip, "backward", apply, REDDIT_UNIT,
+                           _gat_shapes(1024, 4, 256, 1024), 1024, 1024,
+                           True).temp_size_in_bytes
+            for apply in (gat_apply, partial(_gat, attend=_attend_edges))]
+    assert temp[0] < temp[1]
 
 
 def _kernel_case(name, sharding):
