@@ -98,8 +98,7 @@ def run_engine_epoch(
     storage_latency_us: float = 0.0, storage_gbps: float = 0.0,
     per_epoch_walls: bool = False, gather_workers: int = 1,
     transfer_stage: bool = True, device_slots: int = 2,
-    trace: Optional[str] = None, kernels: str = "auto",
-    zero_copy_h2d: bool = True,
+    trace: Optional[str] = None, zero_copy_h2d: bool = True,
 ):
     """Returns (wall_s_per_epoch, modeled_s_per_epoch, counters).
 
@@ -108,8 +107,7 @@ def run_engine_epoch(
     ``storage_latency_us``/``storage_gbps`` emulate an NVMe tier.
     ``gather_workers`` shards the pipelined host gather;
     ``transfer_stage``/``device_slots`` control the async H2D/D2H stage.
-    ``kernels``/``zero_copy_h2d`` select the gather/scatter dispatch mode
-    and the pinned-buffer aliasing H2D path (repro/kernels/dispatch.py).
+    ``zero_copy_h2d`` selects the pinned-buffer aliasing H2D path.
     ``trace`` writes a Chrome/Perfetto timeline of the timed epochs (the
     warmup epoch's reset clears the trace ring, so the export shows steady
     state only)."""
@@ -130,7 +128,7 @@ def run_engine_epoch(
         pipeline=PipelineConfig(
             depth=depth, gather_workers=gather_workers,
             transfer_stage=transfer_stage, device_slots=device_slots,
-            trace=trace, kernels=kernels, zero_copy_h2d=zero_copy_h2d,
+            trace=trace, zero_copy_h2d=zero_copy_h2d,
         ),
     )
     eng.initialize(wl["X"])

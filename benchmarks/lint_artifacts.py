@@ -82,47 +82,8 @@ def lint_bench(path: str, doc) -> list:
         errs.append(f"{path}: missing 'config' section")
     if len(doc) < 2:
         errs.append(f"{path}: no result sections beside 'config'")
-    if os.path.basename(path).startswith("BENCH_kernel_hotpath"):
-        errs += lint_kernel_hotpath(path, doc)
     if os.path.basename(path).startswith("BENCH_fault_soak"):
         errs += lint_fault_soak(path, doc)
-    return errs
-
-
-_HOTPATH_KERNELS = ("gather_rows", "gather_aggregate", "scatter_add")
-
-
-def lint_kernel_hotpath(path: str, doc) -> list:
-    """benchmarks/kernel_hotpath.py payload: per-shape ref/pallas timings
-    plus the per-shape 'fallback' record that justifies the dispatch
-    layer's auto rule (consumed by perf-trajectory tooling)."""
-    errs = []
-    cfg = doc.get("config", {})
-    for key in ("backend", "interpret", "shapes"):
-        if key not in cfg:
-            errs.append(f"{path}: config missing '{key}'")
-    rows = doc.get("kernels")
-    if not isinstance(rows, list) or not rows:
-        return errs + [f"{path}: missing/empty 'kernels' result list"]
-    for i, e in enumerate(rows):
-        if "shape" not in e or "fallback" not in e:
-            errs.append(f"{path}: kernels[{i}] missing shape/fallback")
-            continue
-        for k in _HOTPATH_KERNELS:
-            r = e.get(k)
-            if not isinstance(r, dict) or not all(
-                isinstance(r.get(t), (int, float))
-                for t in ("ref_us", "pallas_us")
-            ):
-                errs.append(
-                    f"{path}: kernels[{i}].{k} missing ref_us/pallas_us"
-                )
-        wins = e["fallback"].get("pallas_wins", {}) \
-            if isinstance(e.get("fallback"), dict) else {}
-        if set(wins) != set(_HOTPATH_KERNELS):
-            errs.append(
-                f"{path}: kernels[{i}].fallback.pallas_wins incomplete"
-            )
     return errs
 
 

@@ -21,7 +21,7 @@ import sys
 
 
 def run_pair(wl, depth, epochs, cache_mb, mode, latency_us, gbps, workers,
-             transfer=True, device_slots=2, trace=None, kernels="auto"):
+             transfer=True, device_slots=2, trace=None):
     from benchmarks.common import run_engine_epoch
 
     out = {}
@@ -32,7 +32,7 @@ def run_pair(wl, depth, epochs, cache_mb, mode, latency_us, gbps, workers,
             per_epoch_walls=True, gather_workers=workers,
             transfer_stage=transfer, device_slots=device_slots,
             # only the pipelined run is worth a timeline
-            trace=trace if d == depth else None, kernels=kernels,
+            trace=trace if d == depth else None,
         )
         # min-of-epochs: robust to noisy-neighbour CPU spikes on shared boxes
         out[d] = dict(
@@ -56,11 +56,6 @@ def main() -> int:
                          "(2 = double buffer, 1 = serialized H2D)")
     ap.add_argument("--no-transfer", action="store_true",
                     help="disable the async H2D/D2H device-transfer stage")
-    ap.add_argument("--kernels", default="auto",
-                    choices=["auto", "reference", "pallas", "pallas-fused"],
-                    help="gather/scatter dispatch mode for both runs "
-                         "(repro/kernels/dispatch.py; 'pallas' is the fused "
-                         "staging path, interpret-mode on CPU)")
     ap.add_argument("--epochs", type=int, default=4)
     ap.add_argument("--cache-mb", type=int, default=8)
     ap.add_argument("--mode", default="regather",
@@ -104,8 +99,7 @@ def main() -> int:
     res = run_pair(wl, args.depth, args.epochs, args.cache_mb, args.mode,
                    args.storage_latency_us, args.storage_gbps,
                    args.gather_workers, transfer=not args.no_transfer,
-                   device_slots=args.device_slots, trace=args.trace,
-                   kernels=args.kernels)
+                   device_slots=args.device_slots, trace=args.trace)
     ser, pipe = res[0], res[args.depth]
     if args.trace:
         print(f"trace,{args.trace},written")
@@ -128,7 +122,6 @@ def main() -> int:
         f"depth={args.depth} workers={args.gather_workers} "
         f"slots={args.device_slots} "
         f"xfer={'off' if args.no_transfer else 'on'} "
-        f"kernels={args.kernels} "
         f"mean={pipe['mean_wall'] * 1e3:.1f}ms "
         f"speedup={speedup:.2f}x "
         f"overlapped_frac={ov['overlapped_frac']:.3f} "
@@ -159,7 +152,6 @@ def main() -> int:
         storage_gbps=args.storage_gbps,
         transfer_stage=not args.no_transfer,
         device_slots=args.device_slots,
-        kernels=args.kernels,
     )
     headline = dict(
         wall_s=pipe["wall"], serial_wall_s=ser["wall"], speedup=speedup,

@@ -127,7 +127,6 @@ def main(argv=None) -> int:
     from repro.configs import REGISTRY
     from repro.core import Counters, HostCache, StorageTier
     from repro.infer import OffloadedInference
-    from repro.kernels.dispatch import KernelDispatch
     from repro.launch.compile_cache import CompileCounter, enable_compile_cache
     from repro.launch.train import gnn_workload, offload_train
     from repro.runtime import PipelineConfig
@@ -144,7 +143,6 @@ def main(argv=None) -> int:
 
     # 1. device
     print(f"[device] {json.dumps(device)} versions={_versions()} "
-          f"kernels=auto->{KernelDispatch('auto').mode} "
           f"compile_cache={cache_dir}", flush=True)
 
     # 2. build

@@ -55,7 +55,6 @@ from repro.core.cache import HostCache
 from repro.core.counters import Counters
 from repro.core.plan import PartitionPlan, WorkUnit
 from repro.core.storage import StorageTier
-from repro.kernels.dispatch import scatter_add_rows_ref
 from repro.models.gnn.layers import GNNSpec, LocalTopo
 
 if TYPE_CHECKING:  # runtime is imported lazily to avoid an import cycle
@@ -104,11 +103,43 @@ def loss_and_grad(logits, labels, n_total):
     return jax.value_and_grad(loss_fn)(logits)
 
 
-# Reference host scatter-add (contiguous slice-add fast path, sorted
-# np.add.reduceat segments, np.add.at residual) — kept under its historical
-# name; the engine itself goes through ``self.kernels.scatter_add_rows`` so
-# the Pallas scatter-grad kernel can take this call site over.
-_scatter_add_rows = scatter_add_rows_ref
+def scatter_add_rows(
+    buf: np.ndarray, rows: np.ndarray, values: np.ndarray
+) -> None:
+    """Host scatter-add ``buf[rows] += values``: the backward's ∇A
+    write-back.
+
+    Fast paths, all bit-identical to a bare ``np.add.at`` for the orders
+    they accept:
+
+    - contiguous unique row run -> direct slice add (the loss layer's
+      ``arange`` scatter and dense regather runs);
+    - sorted rows (the engine's ``req_global`` slices are sorted-unique) ->
+      segment starts + ``np.add.reduceat``, vectorized instead of
+      ``np.add.at``'s per-element inner loop;
+    - anything else -> stable-sort first, then the reduceat path.
+
+    Bit-identical to ``add.at`` whenever rows are duplicate-free — which
+    every engine call site is. With duplicate rows the segment sum lands on
+    the base in ONE rounding instead of per-element (~1 ulp); callers that
+    need add.at's exact order for duplicates must not use this.
+    """
+    n = rows.size
+    if n == 0:
+        return
+    r0 = int(rows[0])
+    if int(rows[n - 1]) - r0 + 1 == n and (
+        n == 1 or bool(np.all(np.diff(rows) == 1))
+    ):
+        buf[r0 : r0 + n] += values
+        return
+    if n > 1 and not bool(np.all(rows[1:] >= rows[:-1])):
+        order = np.argsort(rows, kind="stable")
+        rows = rows[order]
+        values = values[order]
+    starts = np.flatnonzero(np.concatenate(([True], rows[1:] > rows[:-1])))
+    sums = np.add.reduceat(values, starts, axis=0)
+    buf[rows[starts]] += sums
 
 
 class SSOEngine:
@@ -171,15 +202,11 @@ class SSOEngine:
             # an eviction never stalls pipeline workers on a storage write;
             # grad/snap reads below go through the same FIFO for ordering
             cache.set_spill_queue(self._rt.writer)
-        # hot-loop kernel dispatch (Pallas vs numpy reference), shared with
-        # the runner so both halves of the pass pick the same path
-        from repro.kernels.dispatch import KernelDispatch
-        self.kernels = KernelDispatch(pipeline.kernels)
         # the shared forward layer pass (also the backward's regather path);
         # snapshot-mode backward pins live in the runner's pin table too
         self.fwd_runner = ForwardRunner(
             spec, plan, self.dims, storage, cache, self.counters, self._rt,
-            pipeline, dtype=self.dtype, kernels=self.kernels,
+            pipeline, dtype=self.dtype,
         )
         self._prefetch_pins = self.fwd_runner.prefetch_pins
 
@@ -192,13 +219,9 @@ class SSOEngine:
         """Compile the forward and backward layer programs of every padded
         shape bucket of the plan, a thread per core, before the first epoch;
         returns the number of programs. A cold epoch compiles them one after
-        another, and at published widths each takes tens of seconds on v5e.
-        Covers the reference dispatch path; the Pallas path compiles at its
-        first call."""
+        another, and at published widths each takes tens of seconds on v5e."""
         from repro.runtime.forward import compile_parallel, layer_apply
 
-        if self.kernels.use_pallas:
-            return 0
         jobs = []
         for l in range(self.n_layers):
             kw = dict(apply=self.spec.apply_layer,
@@ -330,12 +353,11 @@ class SSOEngine:
 
     def _grad_accumulate(
         self, layer: int, q: int, rows_local: np.ndarray, values: np.ndarray
-    ) -> str:
+    ) -> None:
         """Scatter-accumulate ∇A^{layer} rows for source partition q (the
         paper's host write-back buffer with storage spill). The buffer is
         pinned for the duration of the update so a concurrent pipeline-worker
-        eviction cannot flush it mid-accumulate. Returns the scatter's
-        dispatch path (``"ref"`` or ``"pallas"``)."""
+        eviction cannot flush it mid-accumulate."""
         key = ("grad", layer, q)
         a0, a1 = self.plan.ro.partition_slice(q)
         name = _grad_name(layer)
@@ -365,19 +387,18 @@ class SSOEngine:
                 # touched again); later fetches of this region go through
                 # the same FIFO, so they see it without blocking here.
                 # bump(): accumulates may race pipeline workers' counters
-                path = self.kernels.scatter_add_rows(buf, rows_local, values)
+                scatter_add_rows(buf, rows_local, values)
                 self._rt.write_rows(name, a0, buf)
                 self.counters.bump("host_scatter_bytes", values.nbytes)
-                return path
-        path = self.kernels.scatter_add_rows(buf, rows_local, values)
+                return
+        scatter_add_rows(buf, rows_local, values)
         self.cache.release(key)
         self.counters.bump("host_scatter_bytes", values.nbytes)
-        return path
 
     def _grad_fetch(self, layer: int, p: int) -> np.ndarray:
         """Read ∇A^{layer} for destination partition p (padded to topo rows).
 
-        Runs on the pipeline's aux-fetch stage when enabled, hiding the
+        Runs on the pipeline's aux stage when pipelined, hiding the
         grad-file read behind the previous unit's compute. The padded output
         comes from the runtime pool — the caller releases it via
         ``self._rt.pool.release`` once the device has consumed it."""
@@ -469,21 +490,15 @@ class SSOEngine:
                 self.counters.bump("d2h_bytes", dlog_np.nbytes)
                 if lg_host is not None:
                     rt.pool.release(lg_host)
-                with tracer.span("scatter") as sp:
-                    sp.set(path=self._grad_accumulate(
-                        L, u.p, np.arange(u.n_dst), dlog_np))
+                with tracer.span("scatter"):
+                    self._grad_accumulate(L, u.p, np.arange(u.n_dst), dlog_np)
 
         # ---- layers L..1
         grads: List = [None] * L
-        # Pallas dispatch: the regather backward consumes the partition
-        # stack directly (device-side regather + vjp at GA). Snapshot mode
-        # reads persisted GA buffers — no partition blocks to stack — so it
-        # stays on the reference path (a documented dispatch rule).
-        use_stacked = self.kernels.use_pallas and self.mode == "regather"
         for l in range(L - 1, -1, -1):
             with tracer.span("bwd_layer", layer=l,
                              units=len(plan.schedule)):
-                grads[l] = self._backward_layer(l, params, use_stacked)
+                grads[l] = self._backward_layer(l, params)
             if self.spec.transforms_first(self.dims[l], self.dims[l + 1]):
                 self.counters.bump("narrow_aggregate_passes")
             if self.spec.attends():
@@ -497,7 +512,7 @@ class SSOEngine:
         st.free(_grad_name(0))
         return total_loss, grads
 
-    def _backward_layer(self, l: int, params: List, use_stacked: bool):
+    def _backward_layer(self, l: int, params: List):
         """One backward layer pass: regather (or snapshot-fetch) GA^l and
         ∇A^{l+1} per unit, differentiate the layer, scatter ∇GA rows into
         ∇A^l; returns the layer's parameter gradient. Ends with the
@@ -505,21 +520,11 @@ class SSOEngine:
         plan, st, rt = self.plan, self.storage, self._rt
         L = self.n_layers
         tracer = self.counters.tracer
-        if use_stacked:
-            bwd = self.kernels.fused_backward_fn(
-                self.spec, activate=(l < L - 1)
-            )
-        else:
-            bwd = self._bwd(activate=(l < L - 1))
+        bwd = self._bwd(activate=(l < L - 1))
         dW_acc = None
         units = [plan.unit(p) for p in plan.schedule]
         if self.mode == "regather":
-            if use_stacked:
-                gather_fn = lambda u, _l=l: self.fwd_runner.stacked_gather(
-                    _l, u
-                )
-            else:
-                gather_fn = lambda u, _l=l: self._gather(_l, u, u.r_pad)
+            gather_fn = lambda u, _l=l: self._gather(_l, u, u.r_pad)
             prefetch_fn = (
                 (lambda u, _l=l: self._prefetch_unit(_l, u))
                 if self.pipeline.enabled else None
@@ -537,21 +542,14 @@ class SSOEngine:
         # stream started, and this stream only scatters into layer l.
         aux_fn = (
             (lambda u, _l=l: self._grad_fetch(_l + 1, u.p))
-            if (self.pipeline.enabled and self.pipeline.aux_fetch)
-            else None
+            if self.pipeline.enabled else None
         )
         use_xfer = self._use_xfer
 
-        def bwd_transfer(u, ga, d_out, _l=l):
-            # stage GA (or the Pallas partition stack) and ∇A^{l+1} on
-            # the transfer thread; when the aux stage is off, its fetch
-            # also lands here (still off the compute thread)
-            if d_out is None:
-                d_out = self._grad_fetch(_l + 1, u.p)
+        def bwd_transfer(u, ga, d_out):
+            # stage GA and ∇A^{l+1} (fetched by the aux stage) on the
+            # transfer thread
             do_dev = self.fwd_runner.stage_h2d(d_out)
-            if use_stacked:
-                stack_dev = self.fwd_runner.stage_h2d(ga.stack)
-                return (stack_dev, self.fwd_runner.idx_dev(u)), do_dev
             return self.fwd_runner.stage_h2d(ga), do_dev
 
         for u, ga, d_out in rt.run_stream(
@@ -564,30 +562,16 @@ class SSOEngine:
             xfer_up_stage="xfer_wait_up_bwd",
             layer=l, pass_name="bwd",
         ):
-            if not use_xfer and d_out is None:
-                # aux stage disabled: fetch inline
-                d_out = self._grad_fetch(l + 1, u.p)
             if use_xfer:
-                dev_in, do_dev = ga, d_out
+                ga_dev, do_dev = ga, d_out
                 ga = d_out = None
-            elif use_stacked:
-                self.counters.bump(
-                    "h2d_bytes", ga.stack.nbytes + d_out.nbytes
-                )
-                # aligned pool buffers: asarray aliases; safe — the dga
-                # materialization below blocks before release
-                dev_in = (
-                    jnp.asarray(ga.stack),
-                    self.fwd_runner.idx_dev(u),
-                )
-                do_dev = jnp.asarray(d_out)
             else:
+                if d_out is None:
+                    # serial: the grad fetch runs inline
+                    d_out = self._grad_fetch(l + 1, u.p)
                 self.counters.bump("h2d_bytes", ga.nbytes + d_out.nbytes)
-                dev_in, do_dev = jnp.asarray(ga), jnp.asarray(d_out)
-            if use_stacked:
-                dp, dga = bwd(params[l], dev_in[0], dev_in[1], u.topo, do_dev)
-            else:
-                dp, dga = bwd(params[l], dev_in, u.topo, do_dev)
+                ga_dev, do_dev = jnp.asarray(ga), jnp.asarray(d_out)
+            dp, dga = bwd(params[l], ga_dev, u.topo, do_dev)
             dga_req = dga[: u.n_req]
             # start the D2H copy; it lands under the dW accumulate
             dga_req.copy_to_host_async()
@@ -596,22 +580,19 @@ class SSOEngine:
                 dga_np = np.asarray(dga_req)
             self.counters.bump("d2h_bytes", dga_np.nbytes)
             if ga is not None:
-                rt.pool.release(ga.stack if use_stacked else ga)
+                rt.pool.release(ga)
             if d_out is not None:
                 rt.pool.release(d_out)
             if l > 0:
                 # scatter ∇GA rows back to their source partitions
-                with tracer.span("scatter") as sp:
-                    path = "ref"
+                with tracer.span("scatter"):
                     ptr = u.req_part_ptr
                     for q in u.req_parts:
                         a0, _ = plan.ro.partition_slice(int(q))
                         rows = u.req_global[ptr[q] : ptr[q + 1]] - a0
-                        if self._grad_accumulate(
+                        self._grad_accumulate(
                             l, int(q), rows, dga_np[ptr[q] : ptr[q + 1]]
-                        ) == "pallas":
-                            path = "pallas"
-                    sp.set(path=path)
+                        )
         with tracer.span("d2h_wait"):
             grads_l = jax.tree.map(np.asarray, dW_acc)
         # drop consumed grad layer l+1 from cache & storage; barrier

@@ -1,17 +1,17 @@
-"""Pallas TPU kernels for the compute hot-spots.
+"""Pallas TPU kernels of the LM and recsys stack (no GNN cell runs them).
 
 Each subpackage ships: <name>.py (pl.pallas_call + explicit BlockSpec VMEM
 tiling), ops.py (jit'd wrapper + format helpers), ref.py (pure-jnp oracle).
-Validated in interpret mode on CPU; the TPU target is v5e (128-aligned MXU
-tiles, HBM->VMEM streaming via BlockSpec index maps / scalar prefetch).
+Validated in interpret mode on CPU; the TPU target is v5e.
 
-- bsr_spmm:         partition-pair block-sparse aggregation (SSO hot path)
-- edge_softmax:     GAT segment softmax over padded per-block edge tiles
-- embedding_bag:    recsys gather-reduce with scalar-prefetched row DMAs
-- flash_attention:  online-softmax attention (GQA + sliding window)
-- gather_scatter:   fused gather/aggregate + scatter-grad over the staged
-                    partition stack (the engine hot path; see README.md)
+- embedding_bag:    recsys gather-reduce with scalar-prefetched row DMAs;
+                    the kernel form of ``models/recsys/two_tower.embedding_bag``
+- flash_attention:  online-softmax attention (GQA + sliding window); the
+                    kernel form of ``models/lm/attention.chunked_attention``
 
-``dispatch.py`` routes the engine's hot loops to these kernels or their
-numpy references by backend/mode/shape (``PipelineConfig.kernels``).
+No model module imports them: the models run the pure-JAX forms, and
+``tests/test_kernels.py`` checks each kernel against its oracle and the
+model function it mirrors (``tests/test_lm.py`` uses ``attention_ref``).
+The SSO engine's gather and scatter are host numpy and plain XLA layer
+programs (``runtime/forward.py``, ``core/engine.py``).
 """

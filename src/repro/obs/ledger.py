@@ -5,7 +5,7 @@ Every benchmark / engine run appends ONE schema-versioned JSON line to a
 later reader needs to compare runs without re-running them:
 
 - ``run_kind`` — which producer wrote it (``pipeline_overlap``,
-  ``serving_throughput``, ``kernel_hotpath``, ``fault_soak``, launchers);
+  ``serving_throughput``, ``fault_soak``, launchers);
 - ``fingerprint`` — a stable hash of the run's config dict, so the
   regression sentinel only ever compares like against like (changing
   ``--nodes`` starts a fresh series instead of poisoning the old one);

@@ -14,10 +14,8 @@ class PipelineConfig:
         computing may be in the prefetch/gather stages. ``0`` disables the
         pipeline entirely — the engine runs the exact serial schedule the
         equivalence tests pin down. ``1`` is classic double buffering.
-    queue_capacity
-        Capacity of each bounded stage queue (defaults to ``depth``). Also
-        bounds the number of live gather output buffers to
-        ``queue_capacity + 1`` per shape bucket.
+        Stage queues hold ``max(1, depth)`` units (:attr:`capacity`), and a
+        pipelined backward always fetches ∇A^{l+1} on the gather stage.
     write_behind
         Route bypass writes through the storage I/O queue instead of
         blocking the compute loop on them.
@@ -33,15 +31,6 @@ class PipelineConfig:
         through a sequence-numbered reassembly buffer, so the compute stage
         still consumes units in strict schedule order (bit-identical math)
         while multi-core boxes shard the gather/aux work.
-    aux_fetch
-        Run each backward unit's aux fetch (the ∇A^{l+1} read) on the
-        gather stage instead of the compute thread, so grad-file reads hide
-        behind the previous unit's compute.
-    batched_reads
-        Prefetch issues ONE vectored storage submission per work unit
-        (``StorageTier.read_rows_batched``) covering every missing source
-        partition, instead of one ``read_rows`` per partition — paying the
-        per-op latency once per unit.
     transfer_stage
         Run host→device staging on a dedicated transfer thread: the next
         unit's gathered buffer (and aux grad) is ``jax.device_put`` onto the
@@ -63,16 +52,6 @@ class PipelineConfig:
         overflow the stalest shape bucket is dropped (counted as
         ``pool_trims``) so long multi-epoch runs can't pin peak gather
         footprint forever.
-    kernels
-        Hot-loop kernel dispatch (``repro.kernels.dispatch``): ``"auto"``
-        is the numpy/jnp reference path on every backend (interpret-mode
-        Pallas loses on CPU; v5e refuses the kernels' single-row blocks);
-        ``"pallas"`` / ``"pallas-fused"`` force the fused gather/aggregate
-        + scatter-grad kernels (``interpret=True`` on CPU — how CI
-        exercises the fused path). Both paths are bit-identical for the
-        engine's schedules; the Pallas path additionally skips the
-        host-side gathered copy by staging whole partition blocks and
-        indexing rows on device.
     zero_copy_h2d
         Stage host buffers onto the device with a zero-copy
         ``jax.device_put`` (the :class:`BufferPool`'s 64-byte-aligned
@@ -107,18 +86,14 @@ class PipelineConfig:
     """
 
     depth: int = 0
-    queue_capacity: Optional[int] = None
     write_behind: bool = True
     max_inflight_write_bytes: int = 64 << 20
     pin_prefetched: bool = True
     gather_workers: int = 1
-    aux_fetch: bool = True
-    batched_reads: bool = True
     transfer_stage: bool = True
     device_slots: int = 2
     async_d2h: bool = True
     pool_max_bytes: int = 256 << 20
-    kernels: str = "auto"
     zero_copy_h2d: bool = True
     trace: Optional[str] = None
     trace_ring_events: int = 1 << 18
@@ -131,5 +106,7 @@ class PipelineConfig:
 
     @property
     def capacity(self) -> int:
-        cap = self.queue_capacity if self.queue_capacity is not None else self.depth
-        return max(1, int(cap))
+        """Bound of each stage queue and of the reassembly buffer: live
+        gather buffers stay within ``capacity + gather_workers + 1`` per
+        shape bucket."""
+        return max(1, self.depth)

@@ -59,7 +59,7 @@ _log = logging.getLogger("repro.runtime")
 
 def _host_bytes(obj) -> int:
     """Bytes of the host arrays in a stage product (an array, a tuple of
-    them such as ``StackedGather``, or None)."""
+    them, or None)."""
     if isinstance(obj, np.ndarray):
         return int(obj.nbytes)
     if isinstance(obj, tuple):

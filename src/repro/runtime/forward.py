@@ -30,7 +30,7 @@ cache, whose entries are raw storage blocks); compute always happens in
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, Dict, NamedTuple, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +40,6 @@ from repro.core.cache import HostCache
 from repro.core.counters import Counters
 from repro.core.plan import PartitionPlan, WorkUnit
 from repro.core.storage import StorageTier
-from repro.kernels.dispatch import KernelDispatch
 from repro.runtime.config import PipelineConfig
 
 
@@ -82,17 +81,6 @@ def compile_parallel(jobs) -> int:
     return len(jobs)
 
 
-class StackedGather(NamedTuple):
-    """Pallas-path host staging product: whole cached partition blocks
-    memcpy'd back to back (``stack``, a pooled buffer with one zeroed pad
-    row at the end) plus the unit's layer-independent row map ``idx``
-    (``(r_pad,) int32``, cached — NOT pool-owned) such that
-    ``stack[idx] == GA_p`` bitwise."""
-
-    stack: np.ndarray
-    idx: np.ndarray
-
-
 class ForwardRunner:
     def __init__(
         self,
@@ -108,7 +96,6 @@ class ForwardRunner:
         store_dtype=None,
         act_kind: str = "act",
         act_name: Callable[[int], str] = act_file,
-        kernels: Optional[KernelDispatch] = None,
     ):
         self.spec = spec
         self.plan = plan
@@ -125,20 +112,10 @@ class ForwardRunner:
         self.act_kind = act_kind
         self.act_name = act_name
         self._use_xfer = pipeline.enabled and pipeline.transfer_stage
-        self.kernels = (
-            kernels
-            if kernels is not None
-            else KernelDispatch(pipeline.kernels)
-        )
         # (layer, p) -> keys the prefetch stage actually pinned for that
         # unit; the gather stage pops and releases exactly these (prefetch
         # of a unit strictly precedes its gather via the stage queues)
         self.prefetch_pins: Dict = {}
-        # Pallas path: per-unit (idx, sizes, total) row maps and their
-        # device-resident copies — layer-independent (plan-derived), so one
-        # H2D per unit for the whole run
-        self._idx_cache: Dict = {}
-        self._idx_dev_cache: Dict = {}
 
     # ------------------------------------------------------------------ jit
     def fwd_fn(self, activate: bool):
@@ -201,85 +178,12 @@ class ForwardRunner:
         )
         return buf
 
-    # ------------------------------------------------- stacked gather (Pallas)
-    def _unit_idx(self, u: WorkUnit):
-        """Layer-independent row map for the Pallas path: ``idx[i]`` is the
-        stack row holding GA row ``i`` (partition blocks laid back to back
-        in ``u.req_parts`` order); padding rows ``[n_req, r_pad)`` point at
-        the stack's dedicated zeroed row at offset ``total``. Cached per
-        unit — it only depends on the plan."""
-        ent = self._idx_cache.get(u.p)
-        if ent is None:
-            ptr = u.req_part_ptr
-            sizes = []
-            total = 0
-            offs = {}
-            for q in u.req_parts:
-                a0, a1 = self.plan.ro.partition_slice(int(q))
-                offs[int(q)] = total
-                sizes.append(a1 - a0)
-                total += a1 - a0
-            idx = np.full(u.r_pad, total, np.int32)
-            for q in u.req_parts:
-                a0, _ = self.plan.ro.partition_slice(int(q))
-                idx[ptr[q] : ptr[q + 1]] = (
-                    offs[int(q)] + (u.req_global[ptr[q] : ptr[q + 1]] - a0)
-                ).astype(np.int32)
-            ent = (idx, sizes, total)
-            self._idx_cache[u.p] = ent
-        return ent
-
-    def idx_dev(self, u: WorkUnit):
-        """Device-resident copy of the unit's row map (one H2D ever; the
-        host idx is never mutated, so a zero-copy alias is fine)."""
-        dev = self._idx_dev_cache.get(u.p)
-        if dev is None:
-            idx, _, _ = self._unit_idx(u)
-            dev = jax.device_put(idx)
-            dev.block_until_ready()
-            self.counters.bump("h2d_bytes", idx.nbytes)
-            self._idx_dev_cache[u.p] = dev
-        return dev
-
-    def stacked_gather(self, layer: int, u: WorkUnit) -> StackedGather:
-        """Pallas-path host staging: instead of indexing rows out of every
-        cached partition block (the reference :meth:`gather`'s intermediate
-        gathered copy), memcpy the whole blocks back to back into one pooled
-        stack buffer and let the fused device kernel index rows out of the
-        staged stack directly (``gather_rows(stack, idx) == GA_p``
-        bitwise). Contiguous block copies release the GIL and skip the
-        per-row indexing entirely; the row selection moves into the kernel's
-        scalar-prefetched BlockSpec index map."""
-        d = self.dims[layer]
-        idx, sizes, total = self._unit_idx(u)
-        buf = self._rt.pool.acquire((total + 1, d), self.dtype)
-        off = 0
-        for q, sz in zip(u.req_parts, sizes):
-            block = self.cache.get(
-                (self.act_kind, layer, int(q)),
-                loader=partial(self.load_part_block, layer, int(q)),
-                size_hint=self.block_nbytes(layer, int(q)),
-            )
-            if block.dtype == buf.dtype:
-                np.copyto(buf[off : off + sz], block)
-            else:
-                # reduced-precision storage: upcast into the compute buffer
-                buf[off : off + sz] = block
-            off += sz
-        buf[total] = 0   # the pad row every idx >= n_req points at
-        for key in self.prefetch_pins.pop((layer, u.p), ()):
-            self.cache.unpin(key)
-        self.counters.bump(
-            "host_gather_bytes", total * d * self.dtype.itemsize
-        )
-        return StackedGather(buf, idx)
-
     def prefetch_unit(self, layer: int, u: WorkUnit) -> None:
         """Stage-1: make (and keep) the unit's source partitions resident.
-        With ``batched_reads`` every missing partition is fetched in ONE
-        vectored storage submission instead of one read per partition; block
-        sizes are passed so the cache reserves room BEFORE the blocks are
-        materialized (host memory never transiently exceeds the budget)."""
+        Every missing partition is fetched in ONE vectored storage
+        submission; block sizes are passed so the cache reserves room BEFORE
+        the blocks are materialized (host memory never transiently exceeds
+        the budget)."""
         pin = self.pipeline.pin_prefetched
         if not pin and self.pipeline.slow_lane_pin:
             # degradation: while the storage lane is flagged slow (EWMA
@@ -291,32 +195,18 @@ class ForwardRunner:
                 pin = True
                 self.counters.bump("slow_lane_pins")
         keys = [(self.act_kind, layer, int(q)) for q in u.req_parts]
-        if self.pipeline.batched_reads:
-            name = self.act_name(layer)
-            sizes = {k: self.block_nbytes(layer, k[2]) for k in keys}
+        name = self.act_name(layer)
+        sizes = {k: self.block_nbytes(layer, k[2]) for k in keys}
 
-            def batch_loader(missing):
-                reqs = []
-                for (_, _, q) in missing:
-                    a0, a1 = self.plan.ro.partition_slice(q)
-                    reqs.append((name, a0, a1))
-                return self.storage.read_rows_batched(reqs)
+        def batch_loader(missing):
+            reqs = []
+            for (_, _, q) in missing:
+                a0, a1 = self.plan.ro.partition_slice(q)
+                reqs.append((name, a0, a1))
+            return self.storage.read_rows_batched(reqs)
 
-            res = self.cache.prefetch_many(
-                keys, batch_loader, pin=pin, sizes=sizes
-            )
-            pinned = [k for k in keys if res.get(k)] if pin else []
-        else:
-            pinned = []
-            for key in keys:
-                resident = self.cache.prefetch(
-                    key,
-                    loader=partial(self.load_part_block, layer, key[2]),
-                    pin=pin,
-                    size_hint=self.block_nbytes(layer, key[2]),
-                )
-                if pin and resident:
-                    pinned.append(key)
+        res = self.cache.prefetch_many(keys, batch_loader, pin=pin, sizes=sizes)
+        pinned = [k for k in keys if res.get(k)] if pin else []
         if pinned:
             self.prefetch_pins[(layer, u.p)] = pinned
 
@@ -335,15 +225,12 @@ class ForwardRunner:
 
     def release_gather(self, obj) -> None:
         """Unwind path: hand any stranded gather product back to the buffer
-        pool. Handles every shape the stream stages carry — pooled ndarrays,
-        :class:`StackedGather` (only ``stack`` is pool-owned), and
-        post-transfer tuples (device arrays are skipped; the pool's release
-        guards make an over-eager call on a non-pool object a counted no-op).
+        pool. Handles every shape the stream stages carry — pooled ndarrays
+        and post-transfer tuples (device arrays are skipped; the pool's
+        release guards make an over-eager call on a non-pool object a counted
+        no-op).
         """
         if obj is None:
-            return
-        if isinstance(obj, StackedGather):
-            self._rt.pool.release(obj.stack)
             return
         if isinstance(obj, tuple):
             for o in obj:
@@ -415,14 +302,6 @@ class ForwardRunner:
 
         return transfer
 
-    def _make_stacked_transfer_fn(self):
-        def transfer(u: WorkUnit, sg: StackedGather, _aux):
-            # stage the partition stack; the row map is already device-
-            # resident after the first epoch touches the unit
-            return (self.stage_h2d(sg.stack), self.idx_dev(u)), None
-
-        return transfer
-
     # -------------------------------------------------------------- forward
     def run_layer(
         self,
@@ -447,21 +326,11 @@ class ForwardRunner:
         rt = self._rt
         use_xfer = self._use_xfer
         keep_host = after_compute is not None
-        # Pallas dispatch: fused stack-consuming forward. Snapshot mode
-        # (keep_host) needs GA materialized on the host for persistence —
-        # exactly the copy the fused path eliminates — so it stays on the
-        # reference host gather (a documented dispatch rule).
-        use_stacked = self.kernels.use_pallas and not keep_host
         name_out = out_name if out_name is not None else self.act_name(l + 1)
         cast = self.store_dtype != self.dtype
-        if use_stacked:
-            fwd = self.kernels.fused_forward_fn(self.spec, activate)
-            gather_fn = lambda u, _l=l: self.stacked_gather(_l, u)
-            transfer_fn = self._make_stacked_transfer_fn()
-        else:
-            fwd = self.fwd_fn(activate)
-            gather_fn = lambda u, _l=l: self.gather(_l, u, u.r_pad)
-            transfer_fn = self._make_transfer_fn(keep_host)
+        fwd = self.fwd_fn(activate)
+        gather_fn = lambda u, _l=l: self.gather(_l, u, u.r_pad)
+        transfer_fn = self._make_transfer_fn(keep_host)
         units = [self.plan.unit(p) for p in self.plan.schedule]
         prefetch_fn = (
             (lambda u, _l=l: self.prefetch_unit(_l, u))
@@ -473,7 +342,7 @@ class ForwardRunner:
                 self._run_layer_stream(
                     l, params_l, fwd, activate, after_compute, name_out, cast,
                     units, gather_fn, prefetch_fn, transfer_fn, use_xfer,
-                    use_stacked, keep_host,
+                    keep_host,
                 )
             except BaseException:
                 # faulted epoch: pins taken by prefetches whose gather never
@@ -481,9 +350,7 @@ class ForwardRunner:
                 # zero — the deadlock regression suite's contract)
                 self.release_pins()
                 raise
-            if (self.spec.transforms_first(self.dims[l], self.dims[l + 1])
-                    and not (use_stacked
-                             and self.kernels.fuses_aggregate(self.spec))):
+            if self.spec.transforms_first(self.dims[l], self.dims[l + 1]):
                 self.counters.bump("narrow_aggregate_passes")
             if self.spec.attends():
                 self.counters.bump("attention_passes")
@@ -501,8 +368,7 @@ class ForwardRunner:
 
     def _run_layer_stream(
         self, l, params_l, fwd, activate, after_compute, name_out, cast,
-        units, gather_fn, prefetch_fn, transfer_fn, use_xfer, use_stacked,
-        keep_host,
+        units, gather_fn, prefetch_fn, transfer_fn, use_xfer, keep_host,
     ) -> None:
         rt = self._rt
         tracer = self.counters.tracer
@@ -515,20 +381,7 @@ class ForwardRunner:
             xfer_up_stage="xfer_wait_up_fwd",
             layer=l, pass_name="fwd",
         ):
-            if use_stacked:
-                ga_host = None
-                if use_xfer:
-                    stack_dev, idx_dev = ga
-                    stack_host = None
-                else:
-                    stack_host = ga.stack
-                    # aligned pool buffer: asarray aliases; safe because
-                    # the serial path blocks on out before releasing
-                    stack_dev = jnp.asarray(stack_host)
-                    idx_dev = self.idx_dev(u)
-                    self.counters.bump("h2d_bytes", stack_host.nbytes)
-                out = fwd(params_l, stack_dev, idx_dev, u.topo)
-            elif use_xfer:
+            if use_xfer:
                 ga_dev, ga_host = ga
                 out = fwd(params_l, ga_dev, u.topo)
             else:
@@ -552,10 +405,6 @@ class ForwardRunner:
                     out_np = out_np.astype(self.store_dtype)
             if after_compute is not None:
                 after_compute(u, ga_host)
-            if use_stacked and not use_xfer and stack_host is not None:
-                # out was materialized above (serial never async-retires),
-                # so the aliasing device array is no longer read
-                rt.pool.release(stack_host)
             if ga_host is not None and (not use_xfer or keep_host):
                 # the transfer thread recycled the host buffer already
                 # unless it was told to keep it for after_compute

@@ -158,7 +158,7 @@ def handed_off(pool, q, shape):
 
 def wrapped(pool, shape, idx):
     buf = pool.acquire(shape, "f32")
-    return StackedGather(buf, idx)
+    return Staged(buf, idx)
 """
 
 

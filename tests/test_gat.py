@@ -164,6 +164,77 @@ def test_dense_path_matches_edge_path(kind):
         out[7], jax.nn.elu(want) if activate else want, rtol=1e-6)
 
 
+def _softmax_unit(rng, n, E, n_pad=0):
+    """A unit of ``n`` rows, each its own dst, ``E`` real in-edges (sorted
+    dst) and ``n_pad`` padding edges as the plan pads them: mask 0, at row
+    0."""
+    dst = np.sort(rng.integers(0, n, E))
+    src = rng.integers(0, n, E)
+    pad = lambda a, dt: np.concatenate([a, np.zeros(n_pad)]).astype(dt)
+    topo = LocalTopo(
+        src=jnp.asarray(pad(src, np.int32)),
+        dst=jnp.asarray(pad(dst, np.int32)),
+        n_dst=n,
+        edge_weight=jnp.asarray(pad(np.ones(E), np.float32)),
+        edge_mask=jnp.asarray(pad(np.ones(E), np.float32)),
+        in_deg=jnp.ones(n, jnp.float32),
+        dst_self=jnp.arange(n, dtype=jnp.int32),
+    )
+    return topo, src, dst
+
+
+def _edge_softmax_oracle(h, e_src, e_dst, src, dst, n):
+    """Each head's softmax over a dst row's in-edges of
+    ``leaky_relu(e_src[src] + e_dst[dst])``, weighting ``h[src]``: float64
+    numpy, one dst row at a time."""
+    h, e_src, e_dst = (np.asarray(a, np.float64) for a in (h, e_src, e_dst))
+    out = np.zeros((n,) + h.shape[1:])
+    for v in range(n):
+        ins = np.flatnonzero(dst == v)
+        if ins.size == 0:
+            continue
+        s = e_src[src[ins]] + e_dst[v]
+        s = np.where(s > 0, s, 0.2 * s)
+        a = np.exp(s - s.max(axis=0))
+        a /= a.sum(axis=0)
+        out[v] = np.einsum("eh,ehd->hd", a, h[src[ins]])
+    return out
+
+
+@pytest.mark.parametrize("n,E,H", [(200, 1500, 1), (300, 2500, 4),
+                                   (128, 600, 8)])
+def test_edge_softmax_matches_float64(n, E, H):
+    """The edge path's neighbourhood softmax and weighted sum
+    (``_attend_edges``) at 1, 4 and 8 heads, padding edges included, against
+    a float64 numpy softmax per dst row."""
+    rng = np.random.default_rng(n + H)
+    topo, src, dst = _softmax_unit(rng, n, E, n_pad=64)
+    h = jnp.asarray(rng.standard_normal((n, H, 8)), jnp.float32)
+    e_src = jnp.asarray(rng.standard_normal((n, H)), jnp.float32)
+    e_dst = jnp.asarray(rng.standard_normal((n, H)), jnp.float32)
+    got = layers._attend_edges(h, e_src, e_dst, topo)
+    want = _edge_softmax_oracle(h, e_src, e_dst, src, dst, n)
+    assert got.shape == (n, H, 8)
+    assert _rel(got, want) < LAYER_TOL
+
+
+def test_edge_softmax_rows_sum_to_one():
+    """Weighting rows of ones, the edge path's weighted sum is each dst
+    row's sum of coefficients: one where the row has in-edges, zero where
+    it has none (padding edges weigh nothing)."""
+    rng = np.random.default_rng(7)
+    n, E, H = 100, 150, 2
+    topo, _, dst = _softmax_unit(rng, n, E, n_pad=32)
+    e_src = jnp.asarray(3 * rng.standard_normal((n, H)), jnp.float32)
+    e_dst = jnp.asarray(3 * rng.standard_normal((n, H)), jnp.float32)
+    sums = np.asarray(layers._attend_edges(
+        jnp.ones((n, H, 1), jnp.float32), e_src, e_dst, topo))[:, :, 0]
+    touched = np.bincount(dst, minlength=n) > 0
+    assert (~touched).any()
+    np.testing.assert_allclose(sums[touched], 1.0, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(sums[~touched], 0.0)
+
+
 # the padded (r_pad, e_pad, d_pad) buckets of the reddit plan (16,384 nodes,
 # 16 parts) and the largest of the gcn-igbm-3l plan (65,536 nodes, 8 parts)
 REDDIT_BUCKETS = [(16_384, 524_288, 1_024), (16_384, 524_288, 2_048),

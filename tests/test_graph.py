@@ -133,6 +133,24 @@ class TestReorder:
             ps = ro.parts[nbrs]
             assert np.all(np.diff(ps.astype(int)) >= 0)
 
+    def test_partition_reorder_concentrates_blocks(self, small_graph):
+        """Partition-contiguous reordering concentrates edge mass into
+        diagonal blocks of the adjacency: the locality the SSO plan's
+        per-partition gather runs exploit."""
+        g = small_graph
+        block = 256
+
+        def diag_fraction(ei):
+            br = ei[1] // block
+            bc = ei[0] // block
+            return float(np.mean(br == bc))
+
+        frac_orig = diag_fraction(g.edge_index())
+        res = switching_aware_partition(g, 8, max_iters=10)
+        ro = reorder_by_partition(g, res.parts, 8)
+        frac_part = diag_fraction(ro.graph.edge_index())
+        assert frac_part > frac_orig
+
 
 class TestSampler:
     def test_mfg_shapes(self, small_graph):

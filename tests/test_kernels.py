@@ -1,15 +1,10 @@
-"""Per-kernel shape/dtype sweeps vs pure-jnp oracles (interpret mode)."""
+"""Per-kernel shape/dtype sweeps of the LM and recsys Pallas kernels vs
+pure-jnp oracles and the model functions they mirror (interpret mode)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.bsr_spmm import (
-    blockify_edges, bsr_spmm, spmm_edges_ref,
-)
-from repro.kernels.edge_softmax import (
-    edge_softmax, edge_softmax_ref, pack_edges_by_block,
-)
 from repro.kernels.embedding_bag import (
     embedding_bag_kernel_call, embedding_bag_ref,
 )
@@ -20,94 +15,6 @@ def _tol(dtype):
     return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(
         rtol=2e-5, atol=2e-5
     )
-
-
-class TestBsrSpmm:
-    @pytest.mark.parametrize("n,E,D", [(300, 2000, 64), (700, 5000, 128),
-                                       (128, 400, 96), (513, 3000, 32)])
-    def test_shapes(self, n, E, D, rng):
-        src = rng.integers(0, n, E)
-        dst = rng.integers(0, n, E)
-        w = rng.standard_normal(E).astype(np.float32)
-        a, rows, cols, nb = blockify_edges(src, dst, w, n, block=128)
-        x = rng.standard_normal((nb * 128, D)).astype(np.float32)
-        out = bsr_spmm(
-            jnp.asarray(x), jnp.asarray(a), jnp.asarray(rows),
-            jnp.asarray(cols), nb,
-        )
-        ref = spmm_edges_ref(
-            jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w),
-            jnp.asarray(x), nb * 128,
-        )
-        np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
-
-    def test_partition_reorder_concentrates_blocks(self, small_graph):
-        """Partition-contiguous reordering concentrates edge mass into
-        diagonal blocks (what makes the BSR kernel effective)."""
-        from repro.graph import switching_aware_partition, reorder_by_partition
-
-        g = small_graph
-        block = 256
-
-        def diag_fraction(ei):
-            br = ei[1] // block
-            bc = ei[0] // block
-            return float(np.mean(br == bc))
-
-        frac_orig = diag_fraction(g.edge_index())
-        res = switching_aware_partition(g, 8, max_iters=10)
-        ro = reorder_by_partition(g, res.parts, 8)
-        frac_part = diag_fraction(ro.graph.edge_index())
-        assert frac_part > frac_orig
-
-    def test_bf16(self, rng):
-        n, E, D = 256, 1500, 64
-        src = rng.integers(0, n, E)
-        dst = rng.integers(0, n, E)
-        w = rng.standard_normal(E).astype(np.float32)
-        a, rows, cols, nb = blockify_edges(src, dst, w, n)
-        x = rng.standard_normal((nb * 128, D)).astype(np.float32)
-        out = bsr_spmm(
-            jnp.asarray(x, jnp.bfloat16), jnp.asarray(a),
-            jnp.asarray(rows), jnp.asarray(cols), nb,
-        )
-        ref = spmm_edges_ref(
-            jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w),
-            jnp.asarray(x), nb * 128,
-        )
-        np.testing.assert_allclose(
-            out.astype(np.float32), ref, rtol=5e-2, atol=5e-2
-        )
-
-
-class TestEdgeSoftmax:
-    @pytest.mark.parametrize("n,E,H", [(200, 1500, 1), (300, 2500, 4),
-                                       (128, 600, 8)])
-    def test_shapes(self, n, E, H, rng):
-        dst = np.sort(rng.integers(0, n, E)).astype(np.int32)
-        scores = jnp.asarray(rng.standard_normal((E, H)).astype(np.float32))
-        perm, dst_local, mask, _ = pack_edges_by_block(dst, n)
-        out = edge_softmax(
-            scores, jnp.asarray(perm), jnp.asarray(dst_local),
-            jnp.asarray(mask),
-        )
-        ref = edge_softmax_ref(scores, jnp.asarray(dst), n)
-        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
-
-    def test_rows_sum_to_one(self, rng):
-        n, E = 100, 800
-        dst = np.sort(rng.integers(0, n, E)).astype(np.int32)
-        scores = jnp.asarray(rng.standard_normal((E, 2)).astype(np.float32))
-        perm, dst_local, mask, _ = pack_edges_by_block(dst, n)
-        out = edge_softmax(
-            scores, jnp.asarray(perm), jnp.asarray(dst_local),
-            jnp.asarray(mask),
-        )
-        sums = jax.ops.segment_sum(out, jnp.asarray(dst), num_segments=n)
-        touched = np.bincount(dst, minlength=n) > 0
-        np.testing.assert_allclose(
-            np.asarray(sums)[touched], 1.0, rtol=1e-4, atol=1e-5
-        )
 
 
 class TestEmbeddingBag:

@@ -515,8 +515,6 @@ def test_pipelined_epoch_trace_covers_every_busy_stage(tmp_path):
         assert pre <= gather <= e["h2d"] <= wait, (sid, seq, e)
         chains += 1
     assert chains == 4 * plan.n_parts     # 2 forward + 2 regather passes
-    scatter_paths = {st["path"] for n, _, _, st in host if n == "scatter"}
-    assert scatter_paths == {"ref"}
 
     with open(trace) as f:
         doc = json.load(f)

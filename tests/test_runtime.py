@@ -37,7 +37,7 @@ def _setup(n_nodes=900, n_parts=5, d_in=16, seed=0):
 
 def _run(plan, Xr, Yr, dims, mode, depth, budget_kb=8192, epochs=1,
          gather_workers=1, transfer_stage=True, device_slots=2,
-         async_d2h=True, kernels="auto", zero_copy_h2d=True, model="gcn"):
+         async_d2h=True, zero_copy_h2d=True, model="gcn"):
     spec = get_gnn(model)
     params = spec.init(jax.random.PRNGKey(0), dims[0], dims[1], dims[-1],
                        len(dims) - 1)
@@ -49,7 +49,7 @@ def _run(plan, Xr, Yr, dims, mode, depth, budget_kb=8192, epochs=1,
         pipeline=PipelineConfig(depth=depth, gather_workers=gather_workers,
                                 transfer_stage=transfer_stage,
                                 device_slots=device_slots,
-                                async_d2h=async_d2h, kernels=kernels,
+                                async_d2h=async_d2h,
                                 zero_copy_h2d=zero_copy_h2d),
     )
     eng.initialize(Xr)
@@ -68,18 +68,19 @@ def _assert_trees_identical(a, b):
 
 
 # --------------------------------------------------------- engine equivalence
-@pytest.mark.parametrize("kernels", ["reference", "pallas"])
+MODELS = ["gcn", "sage", "gat"]
+
+
+@pytest.mark.parametrize("model", MODELS)
 @pytest.mark.parametrize("mode", ["regather", "snapshot"])
 @pytest.mark.parametrize("depth", [1, 3])
-def test_pipelined_matches_serial_exactly(mode, depth, kernels):
-    """Pipelined == serial bitwise, under BOTH kernel dispatch modes: the
-    baseline stays the serial reference engine, so the pallas rows also pin
-    kernels='pallas' == reference bit-identity (the PR acceptance bar)."""
+def test_pipelined_matches_serial_exactly(mode, depth, model):
+    """Pipelined == serial bitwise for every model the benchmark runs: the
+    baseline is the same model's serial engine (depth 0)."""
     plan, Xr, Yr = _setup()
     dims = [16, 24, 8]
-    l0, g0, _ = _run(plan, Xr, Yr, dims, mode, depth=0)
-    l1, g1, c1 = _run(plan, Xr, Yr, dims, mode, depth=depth,
-                      kernels=kernels)
+    l0, g0, _ = _run(plan, Xr, Yr, dims, mode, depth=0, model=model)
+    l1, g1, c1 = _run(plan, Xr, Yr, dims, mode, depth=depth, model=model)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
     if mode == "regather":
@@ -88,17 +89,17 @@ def test_pipelined_matches_serial_exactly(mode, depth, kernels):
         assert c1.cache_prefetches > 0
 
 
-@pytest.mark.parametrize("kernels", ["reference", "pallas"])
+@pytest.mark.parametrize("model", MODELS)
 @pytest.mark.parametrize("mode", ["regather", "snapshot"])
-def test_multiworker_gather_matches_serial(mode, kernels):
+def test_multiworker_gather_matches_serial(mode, model):
     """gather_workers > 1: units complete out of order on the workers, the
     reassembly buffer re-serializes them — loss and grads stay bit-identical
-    to the serial engine in both backward modes and both dispatch modes."""
+    to the serial engine in both backward modes, for every model."""
     plan, Xr, Yr = _setup()
     dims = [16, 24, 8]
-    l0, g0, _ = _run(plan, Xr, Yr, dims, mode, depth=0)
+    l0, g0, _ = _run(plan, Xr, Yr, dims, mode, depth=0, model=model)
     l1, g1, c1 = _run(plan, Xr, Yr, dims, mode, depth=2, gather_workers=3,
-                      kernels=kernels)
+                      model=model)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
     # the backward aux stage really ran on workers
@@ -211,19 +212,18 @@ def test_fwd_bwd_overlap_split():
     assert s["overlapped_seconds_bwd"] <= s["busy_seconds"]
 
 
-@pytest.mark.parametrize("model,dims,kernels,passes", [
+@pytest.mark.parametrize("model,dims,passes", [
     # both layers narrow: 2 forward + 2 backward passes
-    ("sage", [16, 12, 8], "auto", 4),
+    ("sage", [16, 12, 8], 4),
     # no layer narrows
-    ("gcn", [16, 16, 16], "auto", 0),
-    # the one-kernel GCN forward aggregates first: the backward alone counts
-    ("gcn", [16, 12, 8], "pallas-fused", 2),
+    ("gcn", [16, 16, 16], 0),
+    # GCN narrowing at both layers transforms first in both passes
+    ("gcn", [16, 12, 8], 4),
 ])
 def test_narrow_aggregate_passes_counts_transform_first_passes(
-        model, dims, kernels, passes):
+        model, dims, passes):
     plan, Xr, Yr = _setup()
-    _, _, c = _run(plan, Xr, Yr, dims, "regather", depth=2, model=model,
-                   kernels=kernels)
+    _, _, c = _run(plan, Xr, Yr, dims, "regather", depth=2, model=model)
     assert c.narrow_aggregate_passes == passes
 
 
@@ -468,21 +468,18 @@ def test_plan_lookahead_and_upcoming_parts():
 
 
 # ------------------------------------------------- device-transfer stage
-@pytest.mark.parametrize("kernels", ["reference", "pallas"])
+@pytest.mark.parametrize("model", MODELS)
 @pytest.mark.parametrize("mode", ["regather", "snapshot"])
 @pytest.mark.parametrize("slots", [1, 2])
-def test_transfer_stage_bit_identical(mode, slots, kernels):
-    """Satellite: the async H2D/D2H device-transfer stage (at 1 and 2 device
-    slots) must not change the math — forward, regather and snapshot
-    backward all stay bit-identical to the serial engine, under both kernel
-    dispatch modes (the pallas rows stage the partition stack + idx instead
-    of the gathered GA buffer)."""
+def test_transfer_stage_bit_identical(mode, slots, model):
+    """The async H2D/D2H device-transfer stage (at 1 and 2 device slots)
+    must not change the math — forward, regather and snapshot backward all
+    stay bit-identical to the serial engine, for every model."""
     plan, Xr, Yr = _setup()
     dims = [16, 24, 8]
-    l0, g0, _ = _run(plan, Xr, Yr, dims, mode, depth=0)
+    l0, g0, _ = _run(plan, Xr, Yr, dims, mode, depth=0, model=model)
     l1, g1, c1 = _run(plan, Xr, Yr, dims, mode, depth=2,
-                      transfer_stage=True, device_slots=slots,
-                      kernels=kernels)
+                      transfer_stage=True, device_slots=slots, model=model)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
     # H2D staging and D2H retire really ran on the transfer/retire threads
@@ -490,41 +487,44 @@ def test_transfer_stage_bit_identical(mode, slots, kernels):
     assert c1.stage_busy_seconds.get("d2h", 0.0) > 0.0
 
 
-def test_transfer_stage_off_bit_identical():
+@pytest.mark.parametrize("model", MODELS)
+def test_transfer_stage_off_bit_identical(model):
     """The inline jnp.asarray path (transfer stage disabled) remains
     available and bit-identical."""
     plan, Xr, Yr = _setup()
     dims = [16, 24, 8]
-    l0, g0, _ = _run(plan, Xr, Yr, dims, "regather", depth=0)
+    l0, g0, _ = _run(plan, Xr, Yr, dims, "regather", depth=0, model=model)
     l1, g1, c1 = _run(plan, Xr, Yr, dims, "regather", depth=2,
-                      transfer_stage=False)
+                      transfer_stage=False, model=model)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
     assert "h2d" not in c1.stage_busy_seconds
 
 
-def test_transfer_stage_sync_d2h_bit_identical():
+@pytest.mark.parametrize("model", MODELS)
+def test_transfer_stage_sync_d2h_bit_identical(model):
     """async_d2h off: H2D staging still on the transfer thread, result
     copies synchronous — still bit-identical."""
     plan, Xr, Yr = _setup(n_nodes=500, n_parts=4)
     dims = [16, 16, 8]
-    l0, g0, _ = _run(plan, Xr, Yr, dims, "regather", depth=0)
+    l0, g0, _ = _run(plan, Xr, Yr, dims, "regather", depth=0, model=model)
     l1, g1, _ = _run(plan, Xr, Yr, dims, "regather", depth=2,
-                     async_d2h=False)
+                     async_d2h=False, model=model)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
 
 
-@pytest.mark.parametrize("kernels", ["reference", "pallas"])
-def test_zero_copy_h2d_off_bit_identical(kernels):
-    """zero_copy_h2d=False forces the pre-PR copying jnp.array staging —
-    the math must not depend on whether device_put aliased the pinned
-    buffer or copied it."""
+@pytest.mark.parametrize("model", MODELS)
+@pytest.mark.parametrize("mode", ["regather", "snapshot"])
+def test_zero_copy_h2d_off_bit_identical(mode, model):
+    """zero_copy_h2d=False forces the copying jnp.array staging — the math
+    must not depend on whether device_put aliased the pinned buffer or
+    copied it."""
     plan, Xr, Yr = _setup(n_nodes=500, n_parts=4)
     dims = [16, 16, 8]
-    l0, g0, _ = _run(plan, Xr, Yr, dims, "regather", depth=0)
-    l1, g1, _ = _run(plan, Xr, Yr, dims, "regather", depth=2,
-                     kernels=kernels, zero_copy_h2d=False)
+    l0, g0, _ = _run(plan, Xr, Yr, dims, mode, depth=0, model=model)
+    l1, g1, _ = _run(plan, Xr, Yr, dims, mode, depth=2,
+                     zero_copy_h2d=False, model=model)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
 

@@ -15,9 +15,7 @@ chip would refuse (unaligned blocks, memory over the device's 16 GiB).
   1024 -> 6x41 averaged. The unit takes the dense path (``gat_dense``), whose
   programs compile to 0.25 GiB of scratch forward and 0.63-0.89 GiB
   backward; the edge path's backward, compiled at the same unit, holds more
-  (about 10 GiB);
-- each Pallas gather/scatter kernel is refused at width 1024 (strict xfail,
-  so a kernel redesign that compiles must flip it).
+  (about 10 GiB).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -29,9 +27,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.engine import layer_vjp
-from repro.kernels.gather_scatter.gather_scatter import (
-    gather_aggregate_pallas, gather_rows_pallas, scatter_add_pallas,
-)
 from repro.models.gnn.layers import (
     LocalTopo, _attend_edges, _gat, gat_apply, gcn_apply, sage_apply,
 )
@@ -44,8 +39,6 @@ SMOKE_UNIT = (262_144, 2_097_152, 32_768)
 # (graphsage-reddit, gat-reddit): 16,384 nodes, 6.3M edges, 16 partitions
 REDDIT_UNIT = (16_384, 1_048_576, 2_048)
 HBM_BYTES = 16 << 30
-REFUSAL = ("v5e refuses single-row blocks: 'the last two dimensions of your "
-           "block shape are divisible by 8 and 128'")
 
 
 @pytest.fixture(scope="module")
@@ -149,23 +142,3 @@ def test_gat_dense_backward_holds_less_than_edge_backward(one_chip):
                            True).temp_size_in_bytes
             for apply in (gat_apply, partial(_gat, attend=_attend_edges))]
     assert temp[0] < temp[1]
-
-
-def _kernel_case(name, sharding):
-    N, D, R, E = 8192, 1024, 4096, 16384
-    S = partial(_sds, sharding)
-    if name == "gather_rows":
-        return gather_rows_pallas, (S((N, D)), S((R,), jnp.int32))
-    if name == "gather_aggregate":
-        return gather_aggregate_pallas, (
-            S((N, D)), S((E,), jnp.int32), S((E,), jnp.int32), S((E,)),
-            S((R, D)))
-    return scatter_add_pallas, (S((N, D)), S((R,), jnp.int32), S((R, D)))
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError, reason=REFUSAL)
-@pytest.mark.parametrize("kernel", ["gather_rows", "gather_aggregate",
-                                    "scatter_add"])
-def test_pallas_kernel_compiles_for_v5e(one_chip, kernel):
-    fn, args = _kernel_case(kernel, one_chip)
-    jax.jit(partial(fn, d_block=128, interpret=False)).lower(*args).compile()
