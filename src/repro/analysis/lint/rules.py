@@ -27,7 +27,7 @@ COUNTERS_SCALAR_FIELDS = frozenset({
     "cache_prefetches", "cache_peak_bytes", "pool_trims",
     "pool_release_rejects", "device_flops", "narrow_aggregate_passes",
     "attention_passes", "attention_units", "dense_attention_units",
-    "threads_leaked", "slow_lane_pins",
+    "retired_scatter_units", "threads_leaked", "slow_lane_pins",
 })
 
 # Blocking storage-tier / I/O-queue entry points (StorageTier + StorageIOQueue

@@ -91,6 +91,10 @@ class Counters:
     # those of them that ran the dense path (``models/gnn/layers.gat_dense``)
     attention_units: int = 0
     dense_attention_units: int = 0
+    # backward units whose ∇GA scatter ran on the D2H retire thread
+    # (``SSOEngine._backward_layer``, layers l > 0, pipelined with
+    # ``async_d2h`` and the transfer stage)
+    retired_scatter_units: int = 0
     # fault tolerance (repro/core/faults.py + runtime unwind paths)
     threads_leaked: int = 0   # pipeline/I-O threads that outlived join timeout
     slow_lane_pins: int = 0   # prefetches forced cache-resident by slow lane

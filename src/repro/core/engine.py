@@ -33,9 +33,11 @@ spills (plus dirty cache evictions) retire on the storage I/O queue (whose
 FIFO orders the later reads behind them). Device transfers are off the
 compute thread too: the transfer stage ``jax.device_put``s the next unit's
 gathered buffer / labels / aux grad while the current unit's kernel runs
-(``PipelineConfig.device_slots`` bounds the staged units), and forward
-bypass results retire via ``copy_to_host_async`` + a deferred
-``np.asarray`` on the runtime's D2H retire thread.
+(``PipelineConfig.device_slots`` bounds the staged units), and results
+retire via ``copy_to_host_async`` + a deferred ``np.asarray`` on the
+runtime's D2H retire thread: the forward's bypass writes, and the
+backward's scatter of ∇GA rows into the ∇A write-back buffers, in unit
+order, while the compute loop launches the next unit.
 ``pipeline.depth == 0`` is the serial engine; ``depth >= 1`` (with any
 ``gather_workers``, with or without the transfer stage) overlaps I/O with
 compute and is bit-identical to serial (the compute order and every
@@ -395,6 +397,21 @@ class SSOEngine:
         self.cache.release(key)
         self.counters.bump("host_scatter_bytes", values.nbytes)
 
+    def _scatter_unit(self, layer: int, u: WorkUnit,
+                      dga_np: np.ndarray) -> None:
+        """Scatter one unit's ∇GA rows back into ∇A^{layer}, one source
+        partition after another (the ``scatter`` span): on the compute loop
+        when serial, else on the D2H retire thread, whose FIFO keeps each
+        partition's accumulation order."""
+        with self.counters.tracer.span("scatter"):
+            ptr = u.req_part_ptr
+            for q in u.req_parts:
+                a0, _ = self.plan.ro.partition_slice(int(q))
+                rows = u.req_global[ptr[q] : ptr[q + 1]] - a0
+                self._grad_accumulate(
+                    layer, int(q), rows, dga_np[ptr[q] : ptr[q + 1]]
+                )
+
     def _grad_fetch(self, layer: int, p: int) -> np.ndarray:
         """Read ∇A^{layer} for destination partition p (padded to topo rows).
 
@@ -545,6 +562,10 @@ class SSOEngine:
             if self.pipeline.enabled else None
         )
         use_xfer = self._use_xfer
+        # the ∇GA scatter retires with the D2H copy, as the forward's bypass
+        # writes do (staged inputs only: an inline jnp.asarray may alias a
+        # pooled buffer that is recycled after the copy)
+        retire = l > 0 and use_xfer and self.pipeline.async_d2h
 
         def bwd_transfer(u, ga, d_out):
             # stage GA and ∇A^{l+1} (fetched by the aux stage) on the
@@ -576,6 +597,13 @@ class SSOEngine:
             # start the D2H copy; it lands under the dW accumulate
             dga_req.copy_to_host_async()
             dW_acc = dp if dW_acc is None else jax.tree.map(jnp.add, dW_acc, dp)
+            if retire:
+                # the retire thread completes the copy and scatters the
+                # rows while this loop launches the next unit
+                rt.retire(dga_req, partial(self._scatter_unit, l, u),
+                          stall="retire_submit_bwd")
+                self.counters.bump("retired_scatter_units")
+                continue
             with tracer.span("d2h_wait"):
                 dga_np = np.asarray(dga_req)
             self.counters.bump("d2h_bytes", dga_np.nbytes)
@@ -584,21 +612,15 @@ class SSOEngine:
             if d_out is not None:
                 rt.pool.release(d_out)
             if l > 0:
-                # scatter ∇GA rows back to their source partitions
-                with tracer.span("scatter"):
-                    ptr = u.req_part_ptr
-                    for q in u.req_parts:
-                        a0, _ = plan.ro.partition_slice(int(q))
-                        rows = u.req_global[ptr[q] : ptr[q + 1]] - a0
-                        self._grad_accumulate(
-                            l, int(q), rows, dga_np[ptr[q] : ptr[q + 1]]
-                        )
+                self._scatter_unit(l, u, dga_np)
         with tracer.span("d2h_wait"):
             grads_l = jax.tree.map(np.asarray, dW_acc)
-        # drop consumed grad layer l+1 from cache & storage; barrier
-        # first so no queued degraded spill targets the freed file
-        self.cache.drop_layer("grad", l + 1, flush=False)
+        # barrier: the retired scatters into ∇A^l and every queued write
+        # (degraded spills, dirty evictions) land before layer l-1 reads
+        # ∇A^l and before the consumed ∇A^{l+1} is dropped and its file
+        # freed
         rt.drain_writes()
+        self.cache.drop_layer("grad", l + 1, flush=False)
         st.free(_grad_name(l + 1))
         if self.mode == "snapshot":
             self.cache.drop_layer("snap", l, flush=False)
@@ -613,9 +635,11 @@ class SSOEngine:
                 loss, grads = self.backward(params, labels_reordered)
         except BaseException:
             # faulted epoch (fatal storage error, stage crash): the stream's
-            # own unwind released stranded buffers; drop any pins taken by
-            # prefetches whose gather never ran so cache pins return to zero
-            # and the engine stays closeable
+            # own unwind released stranded buffers; stop the retires still
+            # queued (a retired scatter pins its grad block) and drop any
+            # pins taken by prefetches whose gather never ran, so cache pins
+            # return to zero and the engine stays closeable
+            self._rt.cancel_retires()
             self.fwd_runner.release_pins()
             raise
         # one structured line per epoch (repro.obs logger; silent unless

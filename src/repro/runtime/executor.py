@@ -5,7 +5,8 @@ Turns each per-partition work unit into a multi-stage job
     storage-read / prefetch -> host gather -> device transfer -> device compute
          (worker thread)       (worker threads)  (H2D thread)     (main loop)
                                                                       |
-                 bypass write-behind (I/O thread) <- D2H retire (retire thread)
+    write-behind (I/O thread) <- D2H retire, then bypass write or ∇A scatter
+                                               (retire thread)
 
 flowing through bounded stage queues. The compute stage stays on the caller
 thread and consumes gathered buffers strictly in schedule order, so a
@@ -18,8 +19,9 @@ staged onto the device (``jax.device_put`` on the transfer thread, bounded
 by :class:`DeviceSlotPool` slots) while the current unit's kernel runs, and
 bypass writes retire on the storage I/O queue behind the compute — with
 ``async_d2h`` the device→host result copy itself retires on a dedicated
-thread (``copy_to_host_async`` + deferred ``np.asarray``), so the compute
-loop never blocks on either direction of the host↔device link.
+thread (``copy_to_host_async`` + deferred ``np.asarray``, then the
+caller's callback: a bypass write, or the backward's host scatter), so the
+compute loop never blocks on either direction of the host↔device link.
 
 The gather stage may be sharded across ``gather_workers`` threads; their
 out-of-order completions are rejoined by a sequence-numbered
@@ -332,7 +334,7 @@ class PipelineExecutor:
     """Drives work units through prefetch/gather/transfer worker stages and
     hands the main loop (item, staged-buffer) tuples in schedule order; owns
     the write-behind storage queue for the bypass stage and the D2H retire
-    thread for asynchronous result copies."""
+    thread for asynchronous result copies and what consumes them."""
 
     def __init__(
         self,
@@ -386,39 +388,60 @@ class PipelineExecutor:
             self.storage.write_rows(name, row0, arr)
 
     # ------------------------------------------------------------ D2H retire
-    def retire_write(self, name: str, row0: int, dev) -> None:
-        """Retire a device-resident result to storage: the deferred
-        ``np.asarray`` (which completes the ``copy_to_host_async`` the
-        caller already started) and the bypass write both run on the retire
-        thread, so the compute loop never blocks on the D2H copy. Counted as
-        ``d2h`` stage busy + ``d2h_bytes``, spanned for the caller's current
-        unit. Falls back to a synchronous copy-and-write when ``async_d2h``
-        is off or the pipeline is disabled."""
+    def retire(self, dev, fn: Callable[[np.ndarray], None],
+               stall: str = "d2h_submit", **span) -> None:
+        """Retire a device-resident result: the deferred ``np.asarray``
+        (which completes the ``copy_to_host_async`` the caller already
+        started), then ``fn(host_array)``, both on the retire thread, so the
+        compute loop never blocks on the D2H copy or on what follows it.
+        One thread and one FIFO: callbacks run in submission order. The
+        copy is counted as ``d2h`` stage busy (spanned with ``span``) and
+        ``d2h_bytes``; ``fn`` runs with the caller's current unit bound, so
+        its spans carry it. At most ``max(2, 2 * device_slots)`` retires
+        are pending (each holds a device result alive); a caller over the
+        cap waits, charged to the ``stall`` stall. The first error of a
+        retire drops the retires queued behind it and is raised by the next
+        ``retire`` or ``drain_writes``. Falls back to a synchronous copy and
+        call when ``async_d2h`` is off or the pipeline is disabled."""
         if not (self.cfg.enabled and self.cfg.async_d2h):
             arr = np.asarray(dev)
             self.counters.bump("d2h_bytes", arr.nbytes)
-            self.write_rows(name, row0, arr)
+            fn(arr)
             return
-        # backpressure: each pending retire holds a device result alive, so
-        # bound them like staging slots rather than queueing without limit
         cap = max(2, 2 * int(self.cfg.device_slots))
         unit = self.counters.tracer.current_unit()
         with self._retire_cond:
             if self._closed:
                 raise RuntimeError("PipelineExecutor is closed")
-            if self._retire_exc is not None:
-                raise self._retire_exc
+            self._raise_retire_error()
             if self._retire_thread is None:
                 self._retire_thread = spawn("sso-d2h", self._retire_worker)
             if self._retire_inflight >= cap:
-                with self.counters.wait("d2h_submit"):
+                with self.counters.wait(stall):
                     while self._retire_inflight >= cap:
                         self._retire_cond.wait(0.02)
-                        if self._retire_exc is not None:
-                            raise self._retire_exc
-            self._retire_q.append((name, row0, dev, unit))
+                        self._raise_retire_error()
+            self._retire_q.append((dev, fn, unit, span))
             self._retire_inflight += 1
             self._retire_cond.notify_all()
+
+    def retire_write(self, name: str, row0: int, dev) -> None:
+        """Retire a device-resident result to storage: :meth:`retire` with
+        the bypass write as its callback."""
+        self.retire(dev, lambda arr: self.write_rows(name, row0, arr),
+                    file=name)
+
+    def _raise_retire_error(self) -> None:
+        # caller holds self._retire_cond: a retire error surfaces once
+        if self._retire_exc is not None:
+            exc, self._retire_exc = self._retire_exc, None
+            raise exc
+
+    def _drop_queued_retires(self) -> None:
+        # caller holds self._retire_cond
+        self._retire_inflight -= len(self._retire_q)
+        self._retire_q.clear()
+        self._retire_cond.notify_all()
 
     def _retire_worker(self) -> None:
         while True:
@@ -427,19 +450,25 @@ class PipelineExecutor:
                     if self._closed:
                         return
                     self._retire_cond.wait(0.05)
-                name, row0, dev, unit = self._retire_q.popleft()
+                dev, fn, unit, span = self._retire_q.popleft()
+            tracer = self.counters.tracer
+            prev = tracer.bind_unit(unit)
             try:
-                with self.counters.stage("d2h", **(unit or NO_UNIT), file=name,
+                with self.counters.stage("d2h", **(unit or NO_UNIT), **span,
                                          bytes=int(dev.nbytes)):
                     arr = np.asarray(dev)   # completes the async D2H copy
                     self.counters.bump("d2h_bytes", arr.nbytes)
-                    self.write_rows(name, row0, arr)
+                dev = None          # the device result is free to go
+                fn(arr)
             except BaseException as e:  # surfaced on the next drain/retire
                 with self._retire_cond:
                     self._retire_exc = e
                     self._retire_inflight -= 1
-                    self._retire_cond.notify_all()
+                    self._drop_queued_retires()
                 continue
+            finally:
+                tracer.bind_unit(prev)
+                dev = arr = None
             with self._retire_cond:
                 self._retire_inflight -= 1
                 self._retire_cond.notify_all()
@@ -448,15 +477,24 @@ class PipelineExecutor:
         with self._retire_cond:
             while self._retire_inflight > 0:
                 self._retire_cond.wait(0.05)
-            if self._retire_exc is not None:
-                exc, self._retire_exc = self._retire_exc, None
-                raise exc
+            self._raise_retire_error()
+
+    def cancel_retires(self) -> None:
+        """Unwind path: drop the retires not yet started, wait out the one
+        running, and forget any retire error (the caller is raising its
+        own), so no callback of a faulted pass runs on after it."""
+        with self._retire_cond:
+            self._drop_queued_retires()
+            while self._retire_inflight > 0:
+                self._retire_cond.wait(0.05)
+            self._retire_exc = None
 
     def drain_writes(self) -> None:
         """Barrier: all submitted bypass writes are on storage. Called at
         layer boundaries, before anything reads the freshly written file
-        (spanned as ``drain`` on the caller's thread). Retiring D2H copies
-        are drained first — they feed the write queue."""
+        (spanned as ``drain`` on the caller's thread). Retires (the D2H
+        copies and their callbacks) are drained first — they feed the write
+        queue."""
         with self.counters.tracer.span("drain"):
             self._drain_retires()
             if self._writer is not None:

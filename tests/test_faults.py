@@ -17,6 +17,7 @@ import sys
 import tempfile
 import textwrap
 import time
+import traceback
 
 import jax
 import numpy as np
@@ -332,6 +333,68 @@ def test_unrecoverable_fault_unwinds_engine_cleanly():
     t0 = time.perf_counter()
     eng.close()
     assert time.perf_counter() - t0 < 10.0
+    st_.close()
+
+
+class _NthReadFails(StorageTier):
+    """Fails the ``fail_at``-th read of file ``name`` with a fatal
+    (non-retryable) error, kept as ``raised``."""
+
+    def __init__(self, root, name, fail_at, **kw):
+        super().__init__(root, **kw)
+        self.fail_name, self.fail_at = name, fail_at
+        self.reads, self.raised = 0, None
+
+    def _read_rows_once(self, name, row0, row1):
+        if name == self.fail_name:
+            self.reads += 1
+            if self.reads == self.fail_at:
+                self.raised = StorageFullError(f"{name}: read {self.reads}")
+                raise self.raised
+        return super()._read_rows_once(name, row0, row1)
+
+
+def test_retired_scatter_fault_surfaces_from_layer_drain():
+    """A fatal storage error inside a scatter on the retire thread: the
+    degraded write-back (cache too small for any ∇A block) reads ∇A^1 back
+    before each add after a partition's first, and the last of those reads,
+    in the layer's last unit, fails. The compute loop has handed every unit
+    over by then, so the layer's drain raises the original exception; pins
+    return to zero and the engine closes."""
+    plan, Xr, Yr = _setup()
+    dims = [16, 24, 8]
+    units = [plan.unit(p) for p in plan.schedule]
+    seen, rmw = set(), []
+    for u in units:
+        parts = {int(q) for q in u.req_parts}
+        rmw.append(len(parts & seen))
+        seen |= parts
+    assert rmw[-1] > 0      # the failing read is the last unit's
+    c = Counters()
+    st_ = _NthReadFails(tempfile.mkdtemp(), "grad1", sum(rmw), counters=c,
+                        retry=_FAST_RETRY)
+    eng, cache, params = _build_engine(plan, st_, c, dims, depth=2,
+                                       budget_kb=4)
+    eng.initialize(Xr)
+    t0 = time.perf_counter()
+    with pytest.raises(StorageFullError) as ei:
+        eng.run_epoch(params, Yr)
+    assert time.perf_counter() - t0 < 30.0
+    assert ei.value is st_.raised
+    frames = [f.name for f in traceback.extract_tb(ei.value.__traceback__)]
+    assert "_scatter_unit" in frames and "drain_writes" in frames
+    assert "retire" not in frames
+    assert c.retired_scatter_units == len(units)
+    assert cache.total_pins == 0
+    gc.collect()
+    assert eng.fwd_runner._rt.pool.outstanding == 0
+    t0 = time.perf_counter()
+    try:
+        eng.close()
+    except StorageError as e:   # the I/O queue keeps the failed read's error
+        assert e is st_.raised
+    assert time.perf_counter() - t0 < 10.0
+    assert c.threads_leaked == 0
     st_.close()
 
 

@@ -8,6 +8,7 @@ in-flight bytes, pin/prefetch semantics, dirty-replacement flush, and plan
 lookahead.
 """
 import tempfile
+import threading
 import time
 
 import jax
@@ -37,7 +38,7 @@ def _setup(n_nodes=900, n_parts=5, d_in=16, seed=0):
 
 def _run(plan, Xr, Yr, dims, mode, depth, budget_kb=8192, epochs=1,
          gather_workers=1, transfer_stage=True, device_slots=2,
-         async_d2h=True, zero_copy_h2d=True, model="gcn"):
+         async_d2h=True, zero_copy_h2d=True, model="gcn", trace=None):
     spec = get_gnn(model)
     params = spec.init(jax.random.PRNGKey(0), dims[0], dims[1], dims[-1],
                        len(dims) - 1)
@@ -50,7 +51,7 @@ def _run(plan, Xr, Yr, dims, mode, depth, budget_kb=8192, epochs=1,
                                 transfer_stage=transfer_stage,
                                 device_slots=device_slots,
                                 async_d2h=async_d2h,
-                                zero_copy_h2d=zero_copy_h2d),
+                                zero_copy_h2d=zero_copy_h2d, trace=trace),
     )
     eng.initialize(Xr)
     for _ in range(epochs):
@@ -67,6 +68,11 @@ def _assert_trees_identical(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+def _scatter_units(plan, dims):
+    """Backward units that scatter ∇GA rows: every unit of layers l > 0."""
+    return len(plan.schedule) * (len(dims) - 2)
+
+
 # --------------------------------------------------------- engine equivalence
 MODELS = ["gcn", "sage", "gat"]
 
@@ -79,10 +85,13 @@ def test_pipelined_matches_serial_exactly(mode, depth, model):
     baseline is the same model's serial engine (depth 0)."""
     plan, Xr, Yr = _setup()
     dims = [16, 24, 8]
-    l0, g0, _ = _run(plan, Xr, Yr, dims, mode, depth=0, model=model)
+    l0, g0, c0 = _run(plan, Xr, Yr, dims, mode, depth=0, model=model)
     l1, g1, c1 = _run(plan, Xr, Yr, dims, mode, depth=depth, model=model)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
+    # the pipelined backward scatters on the retire thread, serial inline
+    assert c0.retired_scatter_units == 0
+    assert c1.retired_scatter_units == _scatter_units(plan, dims)
     if mode == "regather":
         # the pipeline stages really ran on workers
         assert c1.stage_busy_seconds.get("gather", 0.0) > 0.0
@@ -120,6 +129,9 @@ def test_degraded_grad_spill_bit_identical(depth):
                       budget_kb=4)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
+    # pipelined, the read-modify-writes run on the retire thread
+    assert c1.retired_scatter_units == (
+        _scatter_units(plan, dims) if depth else 0)
     assert c1.cache_bypass > 0          # puts really degraded
     assert c1.host_scatter_bytes > 0    # spill path still counts bytes
     assert c1.host_scatter_bytes == c0.host_scatter_bytes
@@ -135,6 +147,35 @@ def test_pipelined_matches_serial_under_thrash():
     assert l0 == l1
     _assert_trees_identical(g0, g1)
     assert c1.cache_evictions > 0  # it really did thrash
+    assert c1.retired_scatter_units == _scatter_units(plan, dims)
+
+
+def test_retired_scatter_bit_identical_under_fast_thread_switching():
+    """Stress: more gather workers than cores and a 10 us switch interval,
+    so the retire thread's scatters interleave with gathers, prefetches
+    and evictions at fine grain; two epochs of a tight cache must still
+    match serial bitwise."""
+    import os
+    import sys
+
+    plan, Xr, Yr = _setup()
+    dims = [16, 24, 24, 8]
+    l0, g0, _ = _run(plan, Xr, Yr, dims, "regather", depth=0, budget_kb=64,
+                     epochs=2)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        t0 = time.perf_counter()
+        l1, g1, c1 = _run(plan, Xr, Yr, dims, "regather", depth=3,
+                          budget_kb=64, epochs=2,
+                          gather_workers=(os.cpu_count() or 1) + 1)
+        assert time.perf_counter() - t0 < 120.0
+    finally:
+        sys.setswitchinterval(old)
+    assert l0 == l1
+    _assert_trees_identical(g0, g1)
+    assert c1.cache_evictions > 0
+    assert c1.retired_scatter_units == 2 * _scatter_units(plan, dims)
 
 
 def test_pipelined_multi_epoch_stable():
@@ -499,6 +540,8 @@ def test_transfer_stage_off_bit_identical(model):
     assert l0 == l1
     _assert_trees_identical(g0, g1)
     assert "h2d" not in c1.stage_busy_seconds
+    # inline-staged inputs: the scatter stays on the compute loop
+    assert c1.retired_scatter_units == 0
 
 
 @pytest.mark.parametrize("model", MODELS)
@@ -508,10 +551,12 @@ def test_transfer_stage_sync_d2h_bit_identical(model):
     plan, Xr, Yr = _setup(n_nodes=500, n_parts=4)
     dims = [16, 16, 8]
     l0, g0, _ = _run(plan, Xr, Yr, dims, "regather", depth=0, model=model)
-    l1, g1, _ = _run(plan, Xr, Yr, dims, "regather", depth=2,
-                     async_d2h=False, model=model)
+    l1, g1, c1 = _run(plan, Xr, Yr, dims, "regather", depth=2,
+                      async_d2h=False, model=model)
     assert l0 == l1
     _assert_trees_identical(g0, g1)
+    # synchronous copies: the scatter stays on the compute loop
+    assert c1.retired_scatter_units == 0
 
 
 @pytest.mark.parametrize("model", MODELS)
@@ -614,6 +659,139 @@ def test_retire_write_lands_and_drains(rng):
     assert c.d2h_bytes == x.nbytes
     rt.close()
     st_.close()
+
+
+def _retire_executor(device_slots=2):
+    from repro.runtime import PipelineExecutor
+
+    c = Counters()
+    st_ = StorageTier(tempfile.mkdtemp(), counters=c)
+    rt = PipelineExecutor(
+        PipelineConfig(depth=2, device_slots=device_slots), c, st_)
+    return rt, c, st_
+
+
+def test_retire_runs_callbacks_in_fifo_order_on_one_thread(rng):
+    """retire: each callback gets its own host copy of the device result,
+    in submission order, all on one thread that is not the caller's."""
+    rt, c, st_ = _retire_executor()
+    x = rng.standard_normal((64, 8)).astype(np.float32)
+    dev = jax.device_put(x)
+    got = []
+    for i in range(16):
+        sl = dev[i * 4 : (i + 1) * 4]
+        sl.copy_to_host_async()
+        rt.retire(sl, lambda a, i=i: got.append(
+            (i, threading.get_ident(), a.copy())))
+    rt.drain_writes()
+    assert [g[0] for g in got] == list(range(16))
+    assert len({g[1] for g in got}) == 1
+    assert got[0][1] != threading.get_ident()
+    np.testing.assert_array_equal(np.concatenate([g[2] for g in got]), x)
+    assert c.d2h_bytes == x.nbytes
+    assert c.stage_busy_seconds.get("d2h", 0.0) > 0.0
+    rt.close()
+    st_.close()
+
+
+def test_retire_backpressure_charges_the_named_stall():
+    """A caller over the cap (``max(2, 2 * device_slots)`` pending) waits
+    until the retire thread frees a place, charged to its own stall name."""
+    rt, c, st_ = _retire_executor(device_slots=1)   # cap 2
+    go = threading.Event()
+    done = []
+
+    def slow(a):
+        go.wait(5)
+        done.append(int(a[0]))
+
+    for i in range(2):
+        rt.retire(jax.device_put(np.full(4, i, np.float32)), slow,
+                  stall="retire_submit_test")
+    threading.Timer(0.2, go.set).start()
+    t0 = time.perf_counter()
+    rt.retire(jax.device_put(np.full(4, 2, np.float32)), slow,
+              stall="retire_submit_test")
+    assert time.perf_counter() - t0 >= 0.15
+    rt.drain_writes()
+    assert done == [0, 1, 2]
+    assert c.stage_stall_seconds["retire_submit_test"] >= 0.15
+    assert "d2h_submit" not in c.stage_stall_seconds
+    rt.close()
+    st_.close()
+
+
+def test_retire_error_surfaces_once_and_drops_the_retires_behind_it():
+    """The first failing callback drops the retires queued behind it; the
+    original exception is raised by the next drain, once, and the executor
+    still closes."""
+    rt, c, st_ = _retire_executor(device_slots=4)   # cap 8: nothing waits
+    go = threading.Event()
+    ran = []
+    err = ValueError("scatter failed")
+
+    def cb(a):
+        go.wait(5)
+        ran.append(int(a[0]))
+        if a[0] == 1:
+            raise err
+
+    for i in range(5):
+        rt.retire(jax.device_put(np.full(4, i, np.float32)), cb)
+    go.set()
+    with pytest.raises(ValueError) as ei:
+        rt.drain_writes()
+    assert ei.value is err
+    assert ran == [0, 1]
+    rt.drain_writes()                   # surfaced once
+    rt.close()
+    st_.close()
+
+
+def test_cancel_retires_waits_out_the_running_one_and_drops_the_rest():
+    """The unwind path: the running callback finishes, the queued ones never
+    run, and its error is forgotten (the caller raises its own)."""
+    rt, c, st_ = _retire_executor(device_slots=4)
+    started, go = threading.Event(), threading.Event()
+    ran = []
+
+    def cb(a):
+        started.set()
+        go.wait(5)
+        ran.append(int(a[0]))
+        raise ValueError("after the fault")
+
+    for i in range(4):
+        rt.retire(jax.device_put(np.full(4, i, np.float32)), cb)
+    assert started.wait(5)
+    threading.Timer(0.1, go.set).start()
+    rt.cancel_retires()
+    assert ran == [0]
+    rt.drain_writes()                   # nothing pending, no error
+    rt.close()
+    st_.close()
+
+
+def test_retired_scatter_spans_carry_their_unit(tmp_path):
+    """Traced: every backward layer's ``scatter`` span runs off the compute
+    thread, in unit order per layer, with the unit's stream/seq/layer/pass;
+    the loss layer's slice adds stay on the compute thread."""
+    plan, Xr, Yr = _setup()
+    dims = [16, 24, 24, 8]
+    _, _, c = _run(plan, Xr, Yr, dims, "regather", depth=2,
+                   trace=str(tmp_path / "trace.json"))
+    ev = [e for e in c.tracer.events()
+          if e["ph"] == "X" and e["name"] == "scatter"]
+    main = threading.get_ident()
+    n = len(plan.schedule)
+    loss = [e for e in ev if e["args"]["pass"] == "loss"]
+    bwd = [e for e in ev if e["args"]["pass"] == "bwd"]
+    assert len(loss) == n and all(e["tid"] == main for e in loss)
+    assert len(bwd) == c.retired_scatter_units == _scatter_units(plan, dims)
+    assert all(e["tid"] != main for e in bwd)
+    assert [(e["args"]["layer"], e["args"]["seq"]) for e in bwd] == (
+        [(2, s) for s in range(n)] + [(1, s) for s in range(n)])
+    assert len({(e["args"]["layer"], e["args"]["stream"]) for e in bwd}) == 2
 
 
 # --------------------------------------------------------------- buffer pool
